@@ -17,9 +17,11 @@ import os
 import time
 
 from real_time_helmet_detection_tpu.config import get_config
+from real_time_helmet_detection_tpu.runtime import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = get_config()
     tic = time.time()
     if cfg.train_flag:

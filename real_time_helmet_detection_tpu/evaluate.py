@@ -392,17 +392,9 @@ def _score_multihost(cfg: Config, dataset, results: Dict, txt_dir: str,
         nval[i] = n
 
     # (world, M, ...) stacked blocks, identical on every process
-    def _gather(x):
-        g = np.asarray(multihost_utils.process_allgather(x))
-        # jax-version drift: single-process process_allgather can return
-        # the input UNCHANGED (no leading world axis, observed on the r7
-        # box's jax 0.4.37) — g_ids[p, i] then indexes scalar bytes and
-        # every image id decodes empty, silently dropping the whole split
-        # from the score. Normalize; world > 1 always adds the axis.
-        return g if g.shape != x.shape else g[None]
-
     g_ids, g_boxes, g_classes, g_scores, g_nval = (
-        _gather(x) for x in (ids, boxes, classes, scores, nval))
+        np.asarray(multihost_utils.process_allgather(x))
+        for x in (ids, boxes, classes, scores, nval))
 
     id2ann = dict(zip(dataset.ids, dataset.annotations))
     det_b: Dict[str, np.ndarray] = {}

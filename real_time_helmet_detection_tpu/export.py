@@ -125,11 +125,7 @@ def export_predict(cfg: Config, out_dir: Optional[str] = None,
     # frame, with the cast + normalization baked into the program
     in_dtype = jnp.uint8 if cfg.export_raw_input else jnp.float32
     spec = jax.ShapeDtypeStruct((batch_size, imsize, imsize, 3), in_dtype)
-    # explicit submodule import: on this jax (0.4.37) the `jax.export`
-    # ATTRIBUTE raises (deprecation module-getattr) until the submodule
-    # has been imported, which broke the export CLI on a fresh process
-    from jax import export as jax_export
-    exported = jax_export.export(jax.jit(fn))(spec)
+    exported = jax.export.export(jax.jit(fn))(spec)
 
     # atomic (tmp + os.replace) like every other artifact write: the C++
     # runner and runner_drive.py trust any file they find at these paths,
@@ -142,13 +138,12 @@ def export_predict(cfg: Config, out_dir: Optional[str] = None,
 
     # serialized default CompileOptionsProto for the C++ PJRT runner
     # (PJRT_Client_Compile requires one; building the proto in C++ would
-    # drag in the whole schema)
-    try:
-        from jax._src.lib import xla_client as xc
-        atomic_write_bytes(os.path.join(out_dir, "compile_options.pb"),
-                           xc.CompileOptions().SerializeAsString())
-    except Exception as e:  # pragma: no cover - jaxlib internals may move
-        print("warning: could not write compile_options.pb:", e)
+    # drag in the whole schema). jaxlib's own class: jax has no public
+    # constructor for the default options.
+    from jax._src.lib import xla_client
+    compile_options = xla_client.CompileOptions().SerializeAsString()
+    atomic_write_bytes(os.path.join(out_dir, "compile_options.pb"),
+                       compile_options)
 
     # --export-serve: one artifact per serve bucket (ISSUE 8), the SAME
     # fused fn lowered at every batch shape the Python engine AOT-compiles
@@ -165,7 +160,7 @@ def export_predict(cfg: Config, out_dir: Optional[str] = None,
             bdir = os.path.join(out_dir, "serving", "b%d" % b)
             os.makedirs(bdir, exist_ok=True)
             bspec = jax.ShapeDtypeStruct((b, imsize, imsize, 3), in_dtype)
-            bexp = jax_export.export(jax.jit(fn))(bspec)
+            bexp = jax.export.export(jax.jit(fn))(bspec)
             atomic_write_bytes(os.path.join(bdir, "exported_predict.bin"),
                                bexp.serialize())
             atomic_write_bytes(
@@ -185,16 +180,9 @@ def export_predict(cfg: Config, out_dir: Optional[str] = None,
                 "infer_dtype": cfg.infer_dtype,
                 "serve_bucket": b,
             }, indent=2)
+            atomic_write_bytes(os.path.join(bdir, "compile_options.pb"),
+                               compile_options)
             serve_rel["b%d" % b] = os.path.relpath(bdir, out_dir)
-        try:
-            from jax._src.lib import xla_client as xc
-            for b in serve_buckets:
-                atomic_write_bytes(
-                    os.path.join(out_dir, serve_rel["b%d" % b],
-                                 "compile_options.pb"),
-                    xc.CompileOptions().SerializeAsString())
-        except Exception as e:  # pragma: no cover - jaxlib internals move
-            print("warning: could not write bucket compile_options.pb:", e)
 
     save_json(os.path.join(out_dir, "meta.json"), {
         "input_shape": [batch_size, imsize, imsize, 3],
@@ -229,7 +217,5 @@ def export_predict(cfg: Config, out_dir: Optional[str] = None,
 
 def load_exported(bin_path: str):
     """Round-trip a serialized artifact back to a callable (Python side)."""
-    from jax import export as jax_export  # see export_predict: the
-    # attribute path raises until the submodule import has run
     with open(bin_path, "rb") as f:
-        return jax_export.deserialize(f.read())
+        return jax.export.deserialize(f.read())

@@ -8,7 +8,7 @@ all — its loop prints averaged meters, ref train.py:140-160):
   the scanned train fn — fetched in the SAME single D2H as the loss.
 * `obs.spans` (stdlib): crash-safe JSONL span tracer for host-side phases
   (loader-wait/h2d/dispatch/fetch/checkpoint/compile/...).
-* `obs.context` (stdlib): loadavg + relay-liveness sampler.
+* `obs.context` (stdlib): host loadavg sampler.
 * `obs.metrics` (stdlib): the LIVE metrics plane — thread-safe counters/
   gauges/fixed-layout mergeable histograms with crash-safe periodic
   `obs-metrics-v1` snapshot export ($OBS_METRICS).

@@ -29,7 +29,7 @@ Span taxonomy (docs/ARCHITECTURE.md "Observability & flight recorder"):
 `loader-wait`, `h2d`, `dispatch`, `fetch`, `checkpoint`, `compile`,
 `calibrate`, `bench:*` section spans, `heartbeat` events (the runtime
 heartbeat mirrors every beat here when tracing is on), `recompile` events
-and `context` records (loadavg + relay liveness).
+and `context` records (host loadavg).
 
 Trace-context extension (ISSUE 14, obs/trace.py): every write method
 takes an optional `ctx` (a TraceContext — serialized as the optional
@@ -195,8 +195,8 @@ class SpanTracer:
         self._write(rec)
 
     def context(self, **extra) -> Optional[dict]:
-        """Sample host context (loadavg, relay liveness — obs/context.py)
-        into a `context` record; returns the sample (even when disabled,
+        """Sample host context (loadavg — obs/context.py) into a `context`
+        record; returns the sample (even when disabled,
         so callers can also embed it in their own JSON lines)."""
         from .context import sample_context
         sample = sample_context()

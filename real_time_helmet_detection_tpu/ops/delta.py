@@ -16,7 +16,7 @@ Design (all of it the repo's standing discipline):
 * **uint8 in, one tiny program.** `tile_delta_summary` casts to f32
   INSIDE the jit (a uint8 subtract would wrap) and reduces |cur - prev|
   per tile with one `reduce_window` (window == stride == tile dims, the
-  `peak_mask` idiom) — tunnel-friendly exactly like
+  `peak_mask` idiom) — light on the host<->device link exactly like
   `decode.confidence_summary`: uint8 ships H2D, one small f32 block
   comes back.
 * **Stitching is arithmetic, not model code.** Per-tile Detections ride

@@ -55,13 +55,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .partition import batch_parallel
+
 # Activations the fused epilogue supports. Everything on this list has a
 # cheap closed-form derivative recomputable from the pre-activation value
 # alone; the exotic activations (PReLU carries a param, CELU/Sigmoid are
 # not used after BN in this architecture) stay on the XLA path.
 FUSED_EPILOGUE_ACTIVATIONS = ("Mish", "ReLU", "Linear")
 
-_ROW_BLOCK_CAP = 1024  # sublane-axis block rows (f32: 512 KB at C=128)
+_BLOCK_ELEMS_CAP = 1024 * 128  # elements per row block: 512 KB in f32, so
+# the widest kernel (dx pass: three inputs, two outputs, double-buffered,
+# plus the Mish temporaries) stays well inside v5e's 16 MiB scoped VMEM at
+# any channel width — the row count shrinks as `increase_ch` widens C
+
+X, VEC, PART = "x", "vec", "part"  # operand kinds of `_rows_call`
 
 # Trace-time call-site registry (scripts/roofline.py's analytic counting
 # of the fused path off-TPU): every fused_bn_act/fused_bn_act_train call
@@ -121,37 +128,41 @@ def _act_grad(z: jax.Array, act: str) -> jax.Array:
                               % act)
 
 
-def _row_block(rows: int) -> int:
-    """Largest divisor of `rows` <= the cap, preferring sublane multiples
-    (16 covers the bf16 tile; f32 needs only 8)."""
-    cap = min(rows, _ROW_BLOCK_CAP)
-    best = 1
-    for r in range(cap, 0, -1):
+def _row_block(rows: int, c: int) -> int:
+    """Rows per block: the largest divisor of `rows` that is a multiple of
+    16 (the bf16 sublane tile; f32 needs 8) within the element cap. Mosaic
+    accepts a second-minor block dim only when it is tile-aligned or the
+    whole dim, so when `rows` has no such divisor (25 rows at the bottom
+    of a 320-px hourglass) the block is all of `rows`."""
+    cap = max(16, _BLOCK_ELEMS_CAP // c)
+    for r in range(min(rows, cap) // 16 * 16, 0, -16):
         if rows % r == 0:
-            if r % 16 == 0:
-                return r
-            if best == 1:
-                best = r  # largest divisor at all, if no 16-multiple
-    return best
+            return r
+    if rows * c > 4 * _BLOCK_ELEMS_CAP:
+        raise ValueError(
+            "fused BN kernels: %d rows x %d channels has no 16-aligned "
+            "row block and is too large for one block; use the xla "
+            "epilogue for this shape" % (rows, c))
+    return rows
 
 
 def _fwd_kernel(x_ref, a_ref, b_ref, o_ref, *, act: str):
-    x = x_ref[0].astype(jnp.float32)          # (R, C)
-    z = x * a_ref[0] + b_ref[0]               # (C,) broadcasts over rows
-    o_ref[0] = _act_fwd(z, act).astype(o_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)        # (R, C)
+    z = x * a_ref[...] + b_ref[...]           # (1, C) broadcasts over rows
+    o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
 
 
 def _bwd_kernel(x_ref, a_ref, b_ref, g_ref, dx_ref, da_ref, db_ref, *,
                 act: str):
     """Recompute z, emit dx in one pass + per-(sample, row-block) channel
     partials for d(eff_scale)/d(eff_bias)."""
-    x = x_ref[0].astype(jnp.float32)
-    a = a_ref[0]
-    z = x * a + b_ref[0]
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[0] = (dz * a).astype(dx_ref.dtype)
-    da_ref[0, 0] = jnp.sum(dz * x, axis=0)    # (C,)
-    db_ref[0, 0] = jnp.sum(dz, axis=0)
+    x = x_ref[...].astype(jnp.float32)
+    a = a_ref[...]
+    z = x * a + b_ref[...]
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    dx_ref[...] = (dz * a).astype(dx_ref.dtype)
+    da_ref[...] = _block_colsum(dz * x)             # (1, C)
+    db_ref[...] = _block_colsum(dz)
 
 
 @functools.lru_cache(maxsize=None)
@@ -176,35 +187,19 @@ def _make_fused(act: str, use_pallas: bool, interpret: bool):
         return dx, da, db
 
     def pallas_fwd(x3, a2, b2):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, _ = _specs(n, rows, c)
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec],
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-            interpret=interpret,
-        )(x3, a2, b2)
+        return _rows_call(
+            functools.partial(_fwd_kernel, act=act), "bn_act_fwd",
+            [(X, x3), (VEC, a2), (VEC, b2)], [(X, x3.dtype)], interpret)
 
     def pallas_bwd(x3, a2, b2, g):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
-        partial_shape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
-        dx, da_p, db_p = pl.pallas_call(
-            functools.partial(_bwd_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec],
-            out_specs=(x_spec, part, part),
-            out_shape=(jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-                       partial_shape, partial_shape),
-            interpret=interpret,
-        )(x3, a2, b2, g)
-        # the per-block channel partials are tiny ((N, nb, C) f32); their
-        # reduction is the epilogue's only XLA work in backward
-        return dx, jnp.sum(da_p, axis=(0, 1)).reshape(1, -1), \
-            jnp.sum(db_p, axis=(0, 1)).reshape(1, -1)
+        dx, da_p, db_p = _rows_call(
+            functools.partial(_bwd_kernel, act=act), "bn_act_bwd",
+            [(X, x3), (VEC, a2), (VEC, b2), (X, g)],
+            [(X, x3.dtype), (PART, jnp.float32), (PART, jnp.float32)],
+            interpret)
+        # the per-block channel partials are tiny; their reduction is the
+        # epilogue's only XLA work in backward
+        return dx, _total(da_p).reshape(1, -1), _total(db_p).reshape(1, -1)
 
     fwd_impl = pallas_fwd if use_pallas else jnp_fwd
     bwd_impl = pallas_bwd if use_pallas else jnp_bwd
@@ -323,68 +318,43 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
         return dx, dgamma, dbeta
 
     def pallas_fwd(x3, gamma2, beta2):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
-        pshape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
-        s, ss = pl.pallas_call(
-            _stats_kernel,
-            grid=grid,
-            in_specs=[x_spec],
-            out_specs=(part, part),
-            out_shape=(pshape, pshape),
-            interpret=interpret,
-        )(x3)
+        n, rows, _ = x3.shape
+        s, ss = _rows_call(
+            _stats_kernel, "bn_stats", [(X, x3)],
+            [(PART, jnp.float32), (PART, jnp.float32)], interpret)
         count = float(n * rows)
-        mean = jnp.sum(s, axis=(0, 1)) / count
-        var = jnp.maximum(jnp.sum(ss, axis=(0, 1)) / count
-                          - jnp.square(mean), 0.0)
+        mean = _total(s) / count
+        var = jnp.maximum(_total(ss) / count - jnp.square(mean), 0.0)
         a, b = coeffs(gamma2, beta2, mean, var)
-        out = pl.pallas_call(
-            functools.partial(_fwd_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec],
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-            interpret=interpret,
-        )(x3, a, b)
+        out = _rows_call(
+            functools.partial(_fwd_kernel, act=act), "bn_act_fwd",
+            [(X, x3), (VEC, a), (VEC, b)], [(X, x3.dtype)], interpret)
         return out, mean, var
 
     def pallas_bwd(x3, gamma2, beta2, mean, var, g):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
+        n, rows, _ = x3.shape
         count = float(n * rows)
         r2 = 1.0 / (var + eps)
         a = gamma2 * jnp.sqrt(r2)
         b = beta2 - mean * a
-        pshape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
         # pass 1: recompute dz from (x, g), emit S1/S2 partials only —
         # dz itself never touches HBM
-        s1_p, s2_p = pl.pallas_call(
-            functools.partial(_bwd_sums_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec],
-            out_specs=(part, part),
-            out_shape=(pshape, pshape),
-            interpret=interpret,
-        )(x3, a, b, g)
-        s1 = jnp.sum(s1_p, axis=(0, 1))
-        s2 = jnp.sum(s2_p, axis=(0, 1))
+        s1_p, s2_p = _rows_call(
+            functools.partial(_bwd_sums_kernel, act=act), "bn_act_bwd_sums",
+            [(X, x3), (VEC, a), (VEC, b), (X, g)],
+            [(PART, jnp.float32), (PART, jnp.float32)], interpret)
+        s1 = _total(s1_p)
+        s2 = _total(s2_p)
         ctr = s2 - mean * s1
         dgamma = (jnp.sqrt(r2) * ctr).reshape(1, -1)
         dbeta = s1.reshape(1, -1)
         k2 = (a * ctr * r2 / count).astype(jnp.float32)
         k1 = a * s1.reshape(1, -1) / count - k2 * mean
         # pass 2: recompute dz again, write dx in one pass
-        dx = pl.pallas_call(
-            functools.partial(_bwd_dx_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec, vec, vec],
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-            interpret=interpret,
-        )(x3, a, b, g, k1, k2)
+        dx = _rows_call(
+            functools.partial(_bwd_dx_kernel, act=act), "bn_act_bwd_dx",
+            [(X, x3), (VEC, a), (VEC, b), (X, g), (VEC, k1), (VEC, k2)],
+            [(X, x3.dtype)], interpret)
         return dx, dgamma, dbeta
 
     fwd_impl = pallas_fwd if use_pallas else jnp_fwd
@@ -409,40 +379,84 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
     return fused
 
 
-def _specs(n, rows, c):
-    r = _row_block(rows)
-    grid = (n, rows // r)
-    x_spec = pl.BlockSpec((1, r, c), lambda i, j: (i, j, 0),
-                          memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, c), lambda i, j: (0, 0),
-                       memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, 1, c), lambda i, j: (i, j, 0),
-                        memory_space=pltpu.VMEM)
-    return grid, x_spec, vec, part
+def _rows_call(kernel, name: str, ins, outs, interpret: bool):
+    """Run `kernel` over the (sample, row-block) grid of (N, R, C) arrays.
+
+    `ins` is a list of (kind, array): X = an activation-shaped (N, R, C)
+    operand, blocked (row-block, C) per program; VEC = a per-channel
+    (1, C) f32 vector, whole in every program. `outs` is a list of
+    (kind, dtype): X as above, PART = one (1, C) f32 partial per program,
+    returned as (N, row-blocks, 1, C) for XLA to sum — the singleton keeps
+    the block's last two dims equal to the array's, which Mosaic requires
+    of any block that is not (8, 128)-aligned. Both grid axes are
+    independent ("parallel"), and so is the batch across chips
+    (`batch_parallel`)."""
+    kinds = [k for k, _ in ins]
+
+    def call(*operands):
+        n, rows, c = operands[kinds.index(X)].shape
+        r = _row_block(rows, c)
+        nb = rows // r
+        specs = {
+            X: pl.BlockSpec((None, r, c), lambda i, j: (i, j, 0),
+                            memory_space=pltpu.VMEM),
+            VEC: pl.BlockSpec((1, c), lambda i, j: (0, 0),
+                              memory_space=pltpu.VMEM),
+            PART: pl.BlockSpec((None, None, 1, c),
+                               lambda i, j: (i, j, 0, 0),
+                               memory_space=pltpu.VMEM),
+        }
+        shapes = {X: (n, rows, c), PART: (n, nb, 1, c)}
+        return pl.pallas_call(
+            kernel,
+            grid=(n, nb),
+            in_specs=[specs[k] for k in kinds],
+            out_specs=tuple(specs[k] for k, _ in outs),
+            out_shape=tuple(jax.ShapeDtypeStruct(shapes[k], dt)
+                            for k, dt in outs),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+            name=name,
+        )(*operands)
+
+    out = batch_parallel(call, [k == X for k in kinds])(*(a for _, a in ins))
+    return out if len(outs) > 1 else out[0]
+
+
+def _total(part):
+    """(N, row-blocks, 1, C) kernel partials -> (C,) sum."""
+    return jnp.sum(part, axis=(0, 1, 2))
+
+
+def _block_colsum(m):
+    """Per-channel (1, C) sum over the rows of a (R, C) block."""
+    return jnp.sum(m, axis=0, keepdims=True)
 
 
 def _stats_kernel(x_ref, s_ref, ss_ref):
-    x = x_ref[0].astype(jnp.float32)
-    s_ref[0, 0] = jnp.sum(x, axis=0)
-    ss_ref[0, 0] = jnp.sum(x * x, axis=0)
+    x = x_ref[...].astype(jnp.float32)
+    s_ref[...] = _block_colsum(x)
+    ss_ref[...] = _block_colsum(x * x)
 
 
 def _bwd_sums_kernel(x_ref, a_ref, b_ref, g_ref, s1_ref, s2_ref, *,
                      act: str):
-    x = x_ref[0].astype(jnp.float32)
-    z = x * a_ref[0] + b_ref[0]
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    s1_ref[0, 0] = jnp.sum(dz, axis=0)
-    s2_ref[0, 0] = jnp.sum(dz * x, axis=0)
+    x = x_ref[...].astype(jnp.float32)
+    z = x * a_ref[...] + b_ref[...]
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    s1_ref[...] = _block_colsum(dz)
+    s2_ref[...] = _block_colsum(dz * x)
 
 
 def _bwd_dx_kernel(x_ref, a_ref, b_ref, g_ref, k1_ref, k2_ref, dx_ref, *,
                    act: str):
-    x = x_ref[0].astype(jnp.float32)
-    a = a_ref[0]
-    z = x * a + b_ref[0]
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[0] = (a * dz - k2_ref[0] * x - k1_ref[0]).astype(dx_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)
+    a = a_ref[...]
+    z = x * a + b_ref[...]
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    dx_ref[...] = (a * dz - k2_ref[...] * x
+                   - k1_ref[...]).astype(dx_ref.dtype)
 
 
 def fused_bn_act_train(x: jax.Array, gamma: jax.Array, beta: jax.Array,

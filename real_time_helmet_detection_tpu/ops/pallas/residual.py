@@ -49,10 +49,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .epilogue import (FUSED_EPILOGUE_ACTIVATIONS, _act_fwd, _act_grad,
-                       _resolve_pallas, _specs, _stats_kernel)
+from .epilogue import (FUSED_EPILOGUE_ACTIVATIONS, PART, VEC, X, _act_fwd,
+                       _act_grad, _block_colsum, _resolve_pallas,
+                       _rows_call, _stats_kernel, _total)
 
 __all__ = ["FUSED_EPILOGUE_ACTIVATIONS", "fused_bn_add_act",
            "fused_bn_add_act_train", "reset_site_registry",
@@ -89,9 +89,9 @@ def site_kernel_bytes(kind: str, elems: int, itemsize: int) -> float:
 
 
 def _fwd_add_kernel(x_ref, a_ref, b_ref, s_ref, o_ref, *, act: str):
-    x = x_ref[0].astype(jnp.float32)          # (R, C)
-    z = x * a_ref[0] + b_ref[0] + s_ref[0].astype(jnp.float32)
-    o_ref[0] = _act_fwd(z, act).astype(o_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)        # (R, C)
+    z = x * a_ref[...] + b_ref[...] + s_ref[...].astype(jnp.float32)
+    o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
 
 
 def _bwd_add_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, dx_ref, ds_ref,
@@ -99,33 +99,34 @@ def _bwd_add_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, dx_ref, ds_ref,
     """Eval backward: recompute z from (y, skip), emit (dy, dskip) in one
     pass + per-(sample, row-block) channel partials for d(eff_scale)/
     d(eff_bias)."""
-    x = x_ref[0].astype(jnp.float32)
-    a = a_ref[0]
-    z = x * a + b_ref[0] + s_ref[0].astype(jnp.float32)
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[0] = (dz * a).astype(dx_ref.dtype)
-    ds_ref[0] = dz.astype(ds_ref.dtype)
-    da_ref[0, 0] = jnp.sum(dz * x, axis=0)    # (C,)
-    db_ref[0, 0] = jnp.sum(dz, axis=0)
+    x = x_ref[...].astype(jnp.float32)
+    a = a_ref[...]
+    z = x * a + b_ref[...] + s_ref[...].astype(jnp.float32)
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    dx_ref[...] = (dz * a).astype(dx_ref.dtype)
+    ds_ref[...] = dz.astype(ds_ref.dtype)
+    da_ref[...] = _block_colsum(dz * x)       # (1, C)
+    db_ref[...] = _block_colsum(dz)
 
 
 def _bwd_add_sums_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, s1_ref,
                          s2_ref, *, act: str):
-    x = x_ref[0].astype(jnp.float32)
-    z = x * a_ref[0] + b_ref[0] + s_ref[0].astype(jnp.float32)
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    s1_ref[0, 0] = jnp.sum(dz, axis=0)
-    s2_ref[0, 0] = jnp.sum(dz * x, axis=0)
+    x = x_ref[...].astype(jnp.float32)
+    z = x * a_ref[...] + b_ref[...] + s_ref[...].astype(jnp.float32)
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    s1_ref[...] = _block_colsum(dz)
+    s2_ref[...] = _block_colsum(dz * x)
 
 
 def _bwd_add_dx_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, k1_ref, k2_ref,
                        dx_ref, ds_ref, *, act: str):
-    x = x_ref[0].astype(jnp.float32)
-    a = a_ref[0]
-    z = x * a + b_ref[0] + s_ref[0].astype(jnp.float32)
-    dz = g_ref[0].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[0] = (a * dz - k2_ref[0] * x - k1_ref[0]).astype(dx_ref.dtype)
-    ds_ref[0] = dz.astype(ds_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)
+    a = a_ref[...]
+    z = x * a + b_ref[...] + s_ref[...].astype(jnp.float32)
+    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
+    dx_ref[...] = (a * dz - k2_ref[...] * x
+                   - k1_ref[...]).astype(dx_ref.dtype)
+    ds_ref[...] = dz.astype(ds_ref.dtype)
 
 
 def _colsum(m2):
@@ -167,34 +168,19 @@ def _make_fused_add(act: str, use_pallas: bool, interpret: bool):
         return dx, da, db, ds
 
     def pallas_fwd(x3, a2, b2, s3):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, _ = _specs(n, rows, c)
-        return pl.pallas_call(
-            functools.partial(_fwd_add_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec],
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-            interpret=interpret,
-        )(x3, a2, b2, s3)
+        return _rows_call(
+            functools.partial(_fwd_add_kernel, act=act), "bn_add_act_fwd",
+            [(X, x3), (VEC, a2), (VEC, b2), (X, s3)], [(X, x3.dtype)],
+            interpret)
 
     def pallas_bwd(x3, a2, b2, s3, g):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
-        partial_shape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
-        dx, ds, da_p, db_p = pl.pallas_call(
-            functools.partial(_bwd_add_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec, x_spec],
-            out_specs=(x_spec, x_spec, part, part),
-            out_shape=(jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-                       jax.ShapeDtypeStruct(s3.shape, s3.dtype),
-                       partial_shape, partial_shape),
-            interpret=interpret,
-        )(x3, a2, b2, s3, g)
-        return dx, jnp.sum(da_p, axis=(0, 1)).reshape(1, -1), \
-            jnp.sum(db_p, axis=(0, 1)).reshape(1, -1), ds
+        dx, ds, da_p, db_p = _rows_call(
+            functools.partial(_bwd_add_kernel, act=act), "bn_add_act_bwd",
+            [(X, x3), (VEC, a2), (VEC, b2), (X, s3), (X, g)],
+            [(X, x3.dtype), (X, s3.dtype), (PART, jnp.float32),
+             (PART, jnp.float32)], interpret)
+        return dx, _total(da_p).reshape(1, -1), \
+            _total(db_p).reshape(1, -1), ds
 
     fwd_impl = pallas_fwd if use_pallas else jnp_fwd
     bwd_impl = pallas_bwd if use_pallas else jnp_bwd
@@ -286,69 +272,47 @@ def _make_fused_add_train(act: str, eps: float, use_pallas: bool,
         return dx, dgamma, dbeta, ds
 
     def pallas_fwd(x3, gamma2, beta2, s3):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
-        pshape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
-        s, ss = pl.pallas_call(
-            _stats_kernel,
-            grid=grid,
-            in_specs=[x_spec],
-            out_specs=(part, part),
-            out_shape=(pshape, pshape),
-            interpret=interpret,
-        )(x3)
+        n, rows, _ = x3.shape
+        s, ss = _rows_call(
+            _stats_kernel, "bn_stats", [(X, x3)],
+            [(PART, jnp.float32), (PART, jnp.float32)], interpret)
         count = float(n * rows)
-        mean = jnp.sum(s, axis=(0, 1)) / count
-        var = jnp.maximum(jnp.sum(ss, axis=(0, 1)) / count
-                          - jnp.square(mean), 0.0)
+        mean = _total(s) / count
+        var = jnp.maximum(_total(ss) / count - jnp.square(mean), 0.0)
         a, b = coeffs(gamma2, beta2, mean, var)
-        out = pl.pallas_call(
-            functools.partial(_fwd_add_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec],
-            out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-            interpret=interpret,
-        )(x3, a, b, s3)
+        out = _rows_call(
+            functools.partial(_fwd_add_kernel, act=act), "bn_add_act_fwd",
+            [(X, x3), (VEC, a), (VEC, b), (X, s3)], [(X, x3.dtype)],
+            interpret)
         return out, mean, var
 
     def pallas_bwd(x3, gamma2, beta2, s3, mean, var, g):
-        n, rows, c = x3.shape
-        grid, x_spec, vec, part = _specs(n, rows, c)
-        nb = grid[1]
+        n, rows, _ = x3.shape
         count = float(n * rows)
         r2 = 1.0 / (var + eps)
         a = gamma2 * jnp.sqrt(r2)
         b = beta2 - mean * a
-        pshape = jax.ShapeDtypeStruct((n, nb, c), jnp.float32)
         # pass 1: recompute dz from (y, skip, g), emit S1/S2 partials —
         # dz itself never touches HBM
-        s1_p, s2_p = pl.pallas_call(
+        s1_p, s2_p = _rows_call(
             functools.partial(_bwd_add_sums_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec, x_spec],
-            out_specs=(part, part),
-            out_shape=(pshape, pshape),
-            interpret=interpret,
-        )(x3, a, b, s3, g)
-        s1 = jnp.sum(s1_p, axis=(0, 1))
-        s2 = jnp.sum(s2_p, axis=(0, 1))
+            "bn_add_act_bwd_sums",
+            [(X, x3), (VEC, a), (VEC, b), (X, s3), (X, g)],
+            [(PART, jnp.float32), (PART, jnp.float32)], interpret)
+        s1 = _total(s1_p)
+        s2 = _total(s2_p)
         ctr = s2 - mean * s1
         dgamma = (jnp.sqrt(r2) * ctr).reshape(1, -1)
         dbeta = s1.reshape(1, -1)
         k2 = (a * ctr * r2 / count).astype(jnp.float32)
         k1 = a * s1.reshape(1, -1) / count - k2 * mean
         # pass 2: recompute dz again, write (dy, dskip) in one pass
-        dx, ds = pl.pallas_call(
+        dx, ds = _rows_call(
             functools.partial(_bwd_add_dx_kernel, act=act),
-            grid=grid,
-            in_specs=[x_spec, vec, vec, x_spec, x_spec, vec, vec],
-            out_specs=(x_spec, x_spec),
-            out_shape=(jax.ShapeDtypeStruct(x3.shape, x3.dtype),
-                       jax.ShapeDtypeStruct(s3.shape, s3.dtype)),
-            interpret=interpret,
-        )(x3, a, b, s3, g, k1, k2)
+            "bn_add_act_bwd_dx",
+            [(X, x3), (VEC, a), (VEC, b), (X, s3), (X, g), (VEC, k1),
+             (VEC, k2)],
+            [(X, x3.dtype), (X, s3.dtype)], interpret)
         return dx, dgamma, dbeta, ds
 
     fwd_impl = pallas_fwd if use_pallas else jnp_fwd

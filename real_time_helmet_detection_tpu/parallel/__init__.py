@@ -22,6 +22,7 @@ from .mesh import (
     make_mesh,
     replicated,
     shard_batch,
+    under_kernel_mesh,
 )
 
 __all__ = [
@@ -34,5 +35,6 @@ __all__ = [
     "make_mesh",
     "replicated",
     "shard_batch",
+    "under_kernel_mesh",
     "use_gloo_cpu_collectives",
 ]

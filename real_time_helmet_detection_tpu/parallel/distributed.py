@@ -8,18 +8,17 @@ every multi-process entry point (tests/distributed_worker.py, scaling.py's
 multi-process rows, a real pod launch) must share — they were folklore
 inlined in the test worker until ISSUE 11 promoted them to API:
 
-* `use_gloo_cpu_collectives()` — jax 0.4.37 creates the CPU client with NO
-  cross-process collectives unless the implementation is named explicitly;
-  without it every multi-process CPU compile dies with "Multiprocess
-  computations aren't implemented on the CPU backend".
+* `use_gloo_cpu_collectives()` — names the CPU client's cross-process
+  collective implementation explicitly (multi-process CPU runs: the test
+  suite and scaling.py's --cpu multiproc rows).
 * `init_process_group()` — the idempotent `jax.distributed.initialize`
   rendezvous (keeps the reference's tcp://host:port convention via
   `parallel.init_distributed`, which delegates here).
 * `coordination_barrier()` — the coordination-service barrier (gRPC). The
   PUBLIC `sync_global_devices` would create a fresh Gloo context with its
   own hard 30 s KeyValue-exchange deadline — exactly the failure this
-  barrier exists to avoid — so the private client is used, guarded so a
-  jax upgrade fails actionably. A barrier that times out (a dead/stuck
+  barrier exists to avoid — so jax's own coordination client is used
+  (jax has no public handle to it). A barrier that times out (a dead/stuck
   rank — the worker-death failure mode) raises a `DEADLINE_EXCEEDED:`-
   prefixed RuntimeError, which `runtime.errors.is_transient_backend_error`
   classifies TRANSIENT: the job supervisor requeues the run instead of the
@@ -47,19 +46,10 @@ _INITIALIZED = False
 DEFAULT_BARRIER_TIMEOUT_S = 15 * 60.0
 
 
-def use_gloo_cpu_collectives() -> bool:
+def use_gloo_cpu_collectives() -> None:
     """Select the Gloo CPU cross-process collective backend (call BEFORE
-    first backend use). Guarded: the option name is version-fragile, and a
-    missing flag should surface as this warning next to the eventual
-    compile error, not an opaque crash here. Returns True on success."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except (AttributeError, ValueError) as e:
-        print("warning: could not select gloo CPU collectives under jax "
-              "%s (%s); multi-process CPU compiles will likely fail"
-              % (jax.__version__, e), flush=True)
-        return False
+    first backend use)."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def init_process_group(coordinator_address: str, num_processes: int,
@@ -79,27 +69,18 @@ def init_process_group(coordinator_address: str, num_processes: int,
 
 
 def _coordination_client():
-    """The process's coordination-service client, or an actionable error.
+    """The process's coordination-service client.
 
-    PRIVATE jax API on purpose: the public sync_global_devices would
-    recreate the Gloo 30 s deadline this barrier works around (see module
-    docstring). Guarded so a jax upgrade that moves/renames it fails with
-    advice instead of an opaque AttributeError mid-rendezvous."""
-    try:
-        from jax._src import distributed
-        client = distributed.global_state.client
-        if client is None:
-            raise AttributeError("global_state.client is None")
-        return client
-    except (ImportError, AttributeError) as e:
+    jax's own (private) handle on purpose: the public sync_global_devices
+    would recreate the Gloo 30 s deadline this barrier works around (see
+    module docstring)."""
+    from jax._src import distributed
+    client = distributed.global_state.client
+    if client is None:
         raise RuntimeError(
-            "jax._src.distributed.global_state.client is unavailable under "
-            "jax %s (%s): this private API backs the compile/execute "
-            "barrier that keeps skewed per-rank compiles from tripping "
-            "Gloo's 30s first-execution deadline; find its new home in "
-            "this jax version (a public sync_global_devices is NOT a "
-            "substitute — it would recreate the Gloo deadline)"
-            % (jax.__version__, e)) from e
+            "no coordination client: jax.distributed.initialize has not "
+            "run in this multi-process job (parallel.init_process_group)")
+    return client
 
 
 def coordination_barrier(name: str,

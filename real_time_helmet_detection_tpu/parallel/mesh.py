@@ -86,6 +86,23 @@ def fit_data_mesh(batch_size: int, num_devices: int = 0,
     return data * spatial
 
 
+def under_kernel_mesh(fn, mesh: Mesh):
+    """`fn` with `mesh` named for the Pallas kernels traced inside it, so
+    each chip runs them on its own batch shard (ops/pallas/partition.py).
+    Wrap the function a mesh-sharded `jax.jit` is given; attributes the
+    step builders hang on their bodies (`sentinel`) carry over."""
+    import functools
+
+    from ..ops.pallas.partition import kernel_mesh
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with kernel_mesh(mesh, DATA_AXIS):
+            return fn(*args, **kwargs)
+
+    return traced
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     """Fully-replicated sharding (params, opt state, scalars)."""
     return NamedSharding(mesh, P())

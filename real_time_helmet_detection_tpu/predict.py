@@ -27,6 +27,16 @@ from .ops.decode import (CascadeDetections, Detections, confidence_summary,
                          decode_heatmap, decode_peak_scores)
 from .ops.nms import maxpool_nms_mask, nms_mask, soft_nms_mask
 from .ops.pallas import fused_peak_scores
+from .ops.pallas.partition import batch_parallel
+
+
+def resolve_peak_kernel(cfg) -> str:
+    """'fused' | 'xla' for this backend: the Pallas sigmoid+peak kernel
+    replaces the XLA reduce_window path on TPU unless `--no-use-pallas`;
+    off-TPU it would run in (slow) interpret mode, so the backend gates it
+    as it gates --loss-kernel/--epilogue/--block-fuse auto."""
+    on_tpu = jax.default_backend() == "tpu"
+    return "fused" if getattr(cfg, "use_pallas", True) and on_tpu else "xla"
 
 
 def make_predict_fn(model, cfg, normalize: str | None = None,
@@ -85,11 +95,7 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
     use_maxpool = cfg.nms == "maxpool"
     if cfg.nms not in ("nms", "soft-nms", "maxpool"):
         raise NotImplementedError("Not expected nms algorithm: %s" % cfg.nms)
-    # The fused Pallas sigmoid+peak kernel replaces the XLA reduce_window
-    # path on TPU; off-TPU it would run in (slow) interpret mode, so gate on
-    # the actual backend as well as the flag.
-    use_pallas = bool(getattr(cfg, "use_pallas", True)) and \
-        jax.default_backend() == "tpu"
+    use_pallas = resolve_peak_kernel(cfg) == "fused"
     imsize = int(cfg.imsize or 512)  # maxpool-NMS grid extent (static)
 
     infer_dtype = getattr(cfg, "infer_dtype", "bf16")
@@ -106,15 +112,23 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
         qmodel = make_quant_model(cfg, dtype=model.dtype, mode="int8")
         scales = jax.tree.map(jnp.asarray, quant_scales)
 
-    def decode_one(o: jax.Array) -> Detections:
-        """One stack of one image: (H, W, num_cls+4) raw -> Detections."""
+    def peak_scores(logits: jax.Array) -> jax.Array:
+        """(B, S, H, W, num_cls) heatmap logits -> masked sigmoid peak
+        scores, the fused kernel over every (image, stack) map — per
+        batch shard under a mesh."""
+        per_map = jax.vmap(jax.vmap(
+            lambda x: fused_peak_scores(x, pool_size=pool_size)))
+        return batch_parallel(per_map, [True])(logits)
+
+    def decode_one(o: jax.Array, peaks=None) -> Detections:
+        """One stack of one image: (H, W, num_cls+4) raw (and, on the
+        Pallas path, its (H, W, num_cls) peak scores) -> Detections."""
         offset = o[..., num_cls:num_cls + 2]
         wh = o[..., num_cls + 2:num_cls + 4]
         if normalized:
             offset = jax.nn.sigmoid(offset)
             wh = jax.nn.sigmoid(wh)
-        if use_pallas:
-            peaks = fused_peak_scores(o[..., :num_cls], pool_size=pool_size)
+        if peaks is not None:
             return decode_peak_scores(peaks, offset, wh,
                                       scale_factor=scale_factor, topk=topk,
                                       conf_th=conf_th, normalized=normalized)
@@ -162,7 +176,8 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
             out = model.apply(variables, images, train=False)
         # (B, S, H, W, C+4)
         b, s = out.shape[0], out.shape[1]
-        dets = jax.vmap(jax.vmap(decode_one))(out)          # (B, S, topk, ...)
+        peaks = peak_scores(out[..., :num_cls]) if use_pallas else None
+        dets = jax.vmap(jax.vmap(decode_one))(out, peaks)  # (B, S, topk, ...)
         boxes = dets.boxes.reshape(b, s * topk, 4)
         classes = dets.classes.reshape(b, s * topk)
         scores = dets.scores.reshape(b, s * topk)
@@ -179,7 +194,8 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
 
     if mesh is None:
         return jax.jit(predict_impl)
-    from .parallel import batch_sharding, replicated
+    from .parallel import batch_sharding, replicated, under_kernel_mesh
+    predict_impl = under_kernel_mesh(predict_impl, mesh)
     if cascade_summary:
         out_sh = CascadeDetections(boxes=batch_sharding(mesh, 3),
                                    classes=batch_sharding(mesh, 2),

@@ -160,11 +160,10 @@ def run_as_job(main_fn) -> None:
             write_job_status(False, error="exit code %d" % e.code,
                              error_class="permanent")
             raise
-        # string SystemExits here are acquire_backend's "backend
-        # unavailable" family: unreachable hardware is transient —
-        # retrying after the relay/claim recovers may well succeed
-        write_job_status(False, error=str(e.code), error_class="transient")
-        raise SystemExit(EXIT_TRANSIENT) from e
+        # string SystemExits are the scripts' own refusals (a bad flag
+        # value, a missing input): retrying changes nothing
+        write_job_status(False, error=str(e.code), error_class="permanent")
+        raise SystemExit(1) from e
     except Exception as e:  # noqa: BLE001 — classified, not swallowed
         klass = classify_exception(e)
         head = str(e).splitlines()[0] if str(e) else repr(e)

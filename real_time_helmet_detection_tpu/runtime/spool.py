@@ -27,7 +27,8 @@ Record kinds (one JSON object per line, `"v": 1`):
 State machine (ISSUE 3):
 
     queued -> claim-wait -> running -> done | failed | salvaged
-    claim-wait -> queued              (relay died / supervisor restart)
+    claim-wait -> queued              (supervisor restart; today's
+                                      supervisor never enters claim-wait)
     running -> queued                 (supervisor restart, process gone)
     salvaged -> queued | failed       (requeue with backoff | budget spent)
 

@@ -7,7 +7,7 @@ ONE artifact:
 
 * the flight-recorder span log(s)  (obs/spans.py JSONL: loader-wait/h2d/
   dispatch/fetch/checkpoint/compile spans, heartbeat events, host-context
-  samples with loadavg + relay liveness),
+  samples with loadavg),
 * the tpu_queue job journal        (artifacts/<round>/queue/jobs.jsonl:
   per-job state transitions, attempts, salvages),
 * bench JSON lines                 (BENCH_*_local.json under the round),
@@ -123,7 +123,7 @@ def _pctl(sorted_vals: List[float], q: float) -> float:
 
 def summarize_spans(paths: List[str]) -> Dict:
     """Roll every span log up into per-name duration stats + event counts
-    + the context-sample digest (loadavg spread, relay incidents)."""
+    + the context-sample digest (loadavg spread)."""
     spans: Dict[str, List[float]] = {}
     events: Dict[str, int] = {}
     contexts: List[dict] = []
@@ -157,12 +157,6 @@ def summarize_spans(paths: List[str]) -> Dict:
         ctx["load1_min"] = min(load1)
         ctx["load1_max"] = max(load1)
         ctx["load1_mean"] = round(sum(load1) / len(load1), 2)
-    relay_seen = [c for c in contexts
-                  if c.get("relay_process") is not None]
-    if relay_seen:
-        ctx["relay_down_samples"] = sum(
-            1 for c in relay_seen
-            if not (c["relay_process"] and c.get("relay_listening")))
     # recompile evidence: compile spans (one per backend compile when the
     # counter's tracer mirror is on) and any recompile-total closing event
     recompiles = {"compile_spans": by_name.get("compile", {}).get("count", 0),
@@ -742,11 +736,9 @@ def render_markdown(rep: Dict) -> str:
             "%s ×%d" % (k, v) for k, v in sorted(sp["events"].items()))]
     ctx = sp["context"]
     if ctx.get("samples"):
-        lines += ["", "Context: %d sample(s), load1 %s–%s (mean %s), "
-                  "relay-down samples: %s"
+        lines += ["", "Context: %d sample(s), load1 %s–%s (mean %s)"
                   % (ctx["samples"], ctx.get("load1_min", "?"),
-                     ctx.get("load1_max", "?"), ctx.get("load1_mean", "?"),
-                     ctx.get("relay_down_samples", 0))]
+                     ctx.get("load1_max", "?"), ctx.get("load1_mean", "?"))]
     lines += ["", "Recompiles: %d compile span(s), %.1f s total" % (
         sp["recompiles"]["compile_spans"],
         sp["recompiles"]["compile_total_s"]), ""]

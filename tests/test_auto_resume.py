@@ -47,7 +47,7 @@ def test_fault_injector_fires_once_at_target():
 
 def test_transient_error_classifier():
     assert is_transient_backend_error(InjectedBackendError("boom"))
-    assert is_transient_backend_error(RuntimeError("UNAVAILABLE: tunnel"))
+    assert is_transient_backend_error(RuntimeError("UNAVAILABLE: socket"))
     assert not is_transient_backend_error(RuntimeError("shape mismatch"))
     assert not is_transient_backend_error(ValueError("UNAVAILABLE"))
 
@@ -58,17 +58,26 @@ def test_transient_error_classifier_requires_status_prefix():
     backend evidence."""
     assert not is_transient_backend_error(
         RuntimeError("bad data-loader connection string: tcp://x"))
-    # INTERNAL needs the XLA status prefix AND the XlaRuntimeError type
     assert not is_transient_backend_error(
         RuntimeError("INTERNAL: assertion failed in user code"))
-
-    class XlaRuntimeError(RuntimeError):  # stand-in with the real type name
-        pass
-
+    from jax.errors import JaxRuntimeError
     assert is_transient_backend_error(
-        XlaRuntimeError("INTERNAL: stream did not block host until done"))
-    assert is_transient_backend_error(
-        XlaRuntimeError("UNAVAILABLE: TPU backend setup/compile error"))
+        JaxRuntimeError("UNAVAILABLE: TPU backend setup error"))
+
+
+def test_compile_failure_is_permanent():
+    """A kernel the compiler refuses is reported under XLA's INTERNAL
+    status; retrying it (--auto-resume, the engine's requeue, the job
+    supervisor's backoff) would recompile it to the same refusal."""
+    from jax.errors import JaxRuntimeError
+
+    from real_time_helmet_detection_tpu.runtime import (classify_error_text,
+                                                        classify_exception)
+    mosaic = ("INTERNAL: Mosaic failed to compile TPU kernel: Not "
+              "implemented: unsupported block shape")
+    assert not is_transient_backend_error(JaxRuntimeError(mosaic))
+    assert classify_exception(JaxRuntimeError(mosaic)) == "permanent"
+    assert classify_error_text(mosaic) == "permanent"
 
 
 def test_fault_injector_rejects_malformed_spec():

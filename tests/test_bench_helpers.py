@@ -619,3 +619,41 @@ def test_sweep_step_grid_block_fuse_cell_identity():
            rec_old.get("block_fuse", "xla"),
            rec_old.get("fwd_dtype", "bf16"))
     assert key == (16, "none", "xla", "fp32", "xla", "xla", "bf16")
+
+
+def test_bench_without_a_chip_exits_nonzero_and_prints_no_line(monkeypatch,
+                                                               capsys):
+    """No chip, no number: on the CPU backend (what this suite runs on)
+    bench.py neither re-runs itself on the CPU nor prints a result line —
+    not even an error line, there being nothing measured to report."""
+    import pytest
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code == 1
+    assert capsys.readouterr().out == ""
+
+
+def test_acquire_backend_cpu_is_a_request_not_a_fallback(monkeypatch):
+    """`--cpu` in argv is how the count-only scripts ASK for the CPU
+    backend; without it the same helper refuses the CPU."""
+    import pytest
+    monkeypatch.setattr(sys, "argv", ["roofline.py", "--cpu"])
+    _, devs = bench.acquire_backend()
+    assert devs[0].platform == "cpu"
+    monkeypatch.setattr(sys, "argv", ["tpu_sweep.py"])
+    with pytest.raises(SystemExit) as ei:
+        bench.acquire_backend()
+    assert ei.value.code == 1
+
+
+def test_chip_peaks_known_kind_or_error():
+    """A device_kind missing from the table is an error, never a default
+    (an assumed peak is how an MFU gets the wrong denominator)."""
+    import pytest
+    assert bench.chip_peaks("TPU v5 lite") == (1.97e14, 819e9)
+    assert bench.chip_peaks(bench.TARGET_CHIP) == (1.97e14, 819e9)
+    with pytest.raises(ValueError, match="not in bench.PEAK_BF16"):
+        bench.chip_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError):
+        bench.chip_peaks("cpu")

@@ -188,12 +188,26 @@ def test_resolve_block_fuse_auto_is_xla_off_tpu():
     assert resolve_block_fuse(tiny_cfg(block_fuse="xla")) == "xla"
 
 
-def _init_pair(variant="residual", act="Mish", dtype=None):
+# Train-mode comparisons of the WHOLE network need a batch whose deepest
+# BatchNorm is well-conditioned. At IMSIZE 64 the bottom of the hourglass
+# is a 1x1 map, so at batch 2 every channel there is normalized over TWO
+# samples: (x - mean) * rsqrt(var + eps) is then +-1 unless the two values
+# nearly coincide, where it amplifies their last-bit difference by up to
+# rsqrt(eps) ~ 316x — a chaotic comparison of any two reassociations of
+# the same math. Measured on CPU (jax 0.9.0, PR 21), fused vs xla tails,
+# fp32 train-mode logits: batch 2 -> max |diff| 0.19 (ReLU) / 0.022
+# (Mish); batch 8 -> 8e-5 / 6e-5. On the chip at full width (b16, 512^2,
+# w128, fp32 at HIGHEST matmul precision) chip_smoke.py's model parity
+# measured a relative L2 gap of 1.4e-5 (4 x TPU v5 lite, PR 21).
+TRAIN_BATCH = 8
+
+
+def _init_pair(variant="residual", act="Mish", dtype=None, batch=2):
     cfg_x = tiny_cfg(block_fuse="xla", variant=variant, activation=act)
     cfg_f = tiny_cfg(block_fuse="fused", variant=variant, activation=act)
     mx, mf = build_model(cfg_x, dtype=dtype), build_model(cfg_f, dtype=dtype)
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
-        (2, IMSIZE, IMSIZE, 3)).astype(np.float32))
+        (batch, IMSIZE, IMSIZE, 3)).astype(np.float32))
     variables = jax.jit(mx.init, static_argnames=("train",))(
         jax.random.key(0), x, train=False)
     return mx, mf, variables, x, cfg_x, cfg_f
@@ -206,7 +220,7 @@ def test_model_tree_identical_and_checkpoints_interchange(variant):
     the trees are identical INCLUDING leaf values (flax derives param
     RNGs from the module path), and the SAME variables produce allclose
     logits under either tail."""
-    mx, mf, variables, x, _, _ = _init_pair(variant)
+    mx, mf, variables, x, _, _ = _init_pair(variant, batch=TRAIN_BATCH)
     vf = jax.jit(mf.init, static_argnames=("train",))(
         jax.random.key(0), x, train=False)
     assert jax.tree.structure(variables) == jax.tree.structure(vf)
@@ -221,10 +235,10 @@ def test_model_tree_identical_and_checkpoints_interchange(variant):
 
     oxt, mutx = mx.apply(variables, x, train=True, mutable=["batch_stats"])
     oft, mutf = mf.apply(variables, x, train=True, mutable=["batch_stats"])
-    # train mode: per-layer moment reassociation amplified by downstream
-    # renormalization (the test_epilogue.py bound)
+    # train mode: per-layer moment reassociation, amplified by downstream
+    # renormalization — ~1e-4 at a well-conditioned batch (TRAIN_BATCH)
     np.testing.assert_allclose(np.asarray(oxt), np.asarray(oft),
-                               atol=1e-2, rtol=1e-2)
+                               atol=2e-3, rtol=2e-3)
     for a, b in zip(jax.tree.leaves(mutx["batch_stats"]),
                     jax.tree.leaves(mutf["batch_stats"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -236,10 +250,10 @@ def test_model_train_grads_agree(variant):
     """Sum-of-squares grads through the full train-mode stack, fused vs
     xla tails at fp32. The analytic backward reassociates the per-channel
     sums, and BN renormalization amplifies that through the stack — the
-    honest bound is relative to each leaf's own scale (observed ~2e-3 of
-    the global max for residual, ~1.5e-2 for depthwise), with the strict
-    per-element parity pinned at kernel level above."""
-    mx, mf, variables, x, _, _ = _init_pair(variant)
+    honest bound is relative to the tree-wide scale, with the strict
+    per-element parity pinned at kernel level above. Run at TRAIN_BATCH:
+    see its note for what batch 2 does to this comparison."""
+    mx, mf, variables, x, _, _ = _init_pair(variant, batch=TRAIN_BATCH)
 
     def loss(m):
         def f(params):

@@ -515,15 +515,7 @@ def test_worker_death_classified_transient_supervisor_requeues(
         heartbeat_timeout_s=500.0, max_attempts=3,
         backoff_base_s=0.1, backoff_cap_s=0.2, env=env))
 
-    class _InstantWaiter:
-        pid = 0
-
-        def poll(self):
-            return 0
-
-    sup = Supervisor(spool, relay_probe=lambda: True,
-                     waiter_factory=_InstantWaiter, poll_s=0.1,
-                     kill_grace_s=2.0)
+    sup = Supervisor(spool, poll_s=0.1, kill_grace_s=2.0)
     summary = sup.run()
     assert summary["jobs"]["train-dp"]["state"] == "done"
     assert summary["jobs"]["train-dp"]["attempt"] == 2, \

@@ -92,12 +92,22 @@ def test_resolve_epilogue_auto_is_xla_off_tpu():
     assert resolve_epilogue(tiny_cfg(epilogue="xla")) == "xla"
 
 
-def _init_pair(act="Mish", dtype=None):
+# Train-mode comparisons of the WHOLE network need a batch whose deepest
+# BatchNorm is well-conditioned: at IMSIZE 64 the hourglass bottom is a 1x1
+# map, so batch 2 normalizes every channel there over TWO samples and
+# amplifies last-bit differences by up to rsqrt(eps) ~ 316x (the note in
+# tests/test_block_fuse.py has the measured gaps: 0.035 max |diff| on the
+# ReLU logits at batch 2, 1.3e-4 at batch 8, 1.4e-5 relative L2 on the
+# chip at full width — chip_smoke.py, PR 21).
+TRAIN_BATCH = 8
+
+
+def _init_pair(act="Mish", dtype=None, batch=2):
     cfg_x = tiny_cfg(epilogue="xla", activation=act)
     cfg_f = tiny_cfg(epilogue="fused", activation=act)
     mx, mf = build_model(cfg_x, dtype=dtype), build_model(cfg_f, dtype=dtype)
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
-        (2, IMSIZE, IMSIZE, 3)).astype(np.float32))
+        (batch, IMSIZE, IMSIZE, 3)).astype(np.float32))
     variables = jax.jit(mx.init, static_argnames=("train",))(
         jax.random.key(0), x, train=False)
     return mx, mf, variables, x, cfg_x, cfg_f
@@ -109,7 +119,7 @@ def test_model_tree_identical_and_logits_allclose(act):
     param/stat trees, and the SAME variables produce allclose logits in
     both eval and train mode (fp32 atol 1e-4 — the fold algebra
     reassociates the normalize)."""
-    mx, mf, variables, x, _, _ = _init_pair(act)
+    mx, mf, variables, x, _, _ = _init_pair(act, batch=TRAIN_BATCH)
     vf = jax.jit(mf.init, static_argnames=("train",))(
         jax.random.key(0), x, train=False)
     assert jax.tree.structure(variables) == jax.tree.structure(vf)
@@ -121,10 +131,10 @@ def test_model_tree_identical_and_logits_allclose(act):
     oxt, mutx = mx.apply(variables, x, train=True, mutable=["batch_stats"])
     oft, mutf = mf.apply(variables, x, train=True, mutable=["batch_stats"])
     # train mode: per-layer moment reassociation (~1e-7 rel on var) gets
-    # amplified by every downstream renormalization — observed ~5e-3 max
-    # on the logits at fp32 through the full stack
+    # amplified by every downstream renormalization — ~1e-4 on the logits
+    # at fp32 through the full stack at a well-conditioned batch
     np.testing.assert_allclose(np.asarray(oxt), np.asarray(oft),
-                               atol=1e-2, rtol=1e-2)
+                               atol=2e-3, rtol=2e-3)
     # the running-stat streams must track each other (same moment
     # definitions; the Gram-dot E[x^2] reassociation shows up at ~1e-5
     # abs, which is ~1e-2 RELATIVE on near-zero variance channels)

@@ -56,11 +56,6 @@ AST_CASES = [
      "def main():\n"
      "    jax, devs = acquire_backend()\n"
      "run_as_job(main)\n"),
-    ("ast/env-platform-write", "scripts/x.py",
-     "import os\n"
-     "os.environ.setdefault('JAX_PLATFORMS', 'cpu')\n",
-     "import os\n"
-     "os.environ.setdefault('XLA_FLAGS', '')\n"),
     ("ast/raw-artifact-write", "scripts/x.py",
      "def w(path, data):\n"
      "    with open(path, mode='wb') as f:\n"
@@ -368,10 +363,10 @@ def test_trace_failure_on_boolean_filtering():
 
 
 def test_f64_leak_detected():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     x = np.ones((4,), np.float32)
-    with enable_x64():
+    with jax.enable_x64(True):
         bad = trace_audit.audit_entry(
             lambda v: jnp.asarray(v, jnp.float64) * 2.0, (x,), "fix",
             lower=False)
@@ -500,6 +495,11 @@ def test_repo_trace_audit_clean_vs_baseline():
         "%s %s %s" % (f.rule, f.context, f.message) for f in d["new"])
 
 
+@pytest.mark.slow  # 88 s at PR 21 --durations (a cold interpreter + every
+# layer's fixtures, models included): the tier-1 command is serial under a
+# fixed window, and the rules themselves are pinned in-process by the rest
+# of this file. Under jax 0.9 it had been failing fast at the removed
+# `jax.experimental.enable_x64`; repaired, it is the suite's longest test.
 def test_cli_selfcheck_subprocess():
     """`graftlint --selfcheck` proves every rule fires on seeded fixtures
     (mirrors tpu_queue.py --selfcheck), as a real subprocess, and keeps

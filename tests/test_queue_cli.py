@@ -32,7 +32,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _hard_timeout():
     def _fire(signum, frame):
         raise RuntimeError("test exceeded the hard timeout — something "
-                           "blocked (a real probe/waiter leaked in?)")
+                           "blocked")
 
     old = signal.signal(signal.SIGALRM, _fire)
     signal.alarm(300)  # selfcheck spawns ~5 interpreters on a slow box
@@ -78,11 +78,11 @@ def test_maybe_job_heartbeat_binds_env_path(tmp_path):
 
 def test_write_job_status_roundtrip(tmp_path):
     path = str(tmp_path / "status.json")
-    write_job_status(False, error="UNAVAILABLE: tunnel",
+    write_job_status(False, error="UNAVAILABLE: socket",
                      error_class="transient",
                      env={"TPU_QUEUE_STATUS": path})
     rec = read_heartbeat(path)
-    assert rec == {"ok": False, "error": "UNAVAILABLE: tunnel",
+    assert rec == {"ok": False, "error": "UNAVAILABLE: socket",
                    "error_class": "transient", "t": rec["t"],
                    "pid": os.getpid()}
     write_job_status(True, env={})  # no env: must be a silent no-op
@@ -111,7 +111,7 @@ def test_run_as_job_maps_outcomes(tmp_path, monkeypatch):
 
     with pytest.raises(SystemExit) as ei:
         run_as_job(lambda: (_ for _ in ()).throw(
-            RuntimeError("UNAVAILABLE: tunnel died")))
+            RuntimeError("UNAVAILABLE: socket closed")))
     assert ei.value.code == EXIT_TRANSIENT
     assert read_heartbeat(status)["error_class"] == "transient"
 
@@ -120,11 +120,12 @@ def test_run_as_job_maps_outcomes(tmp_path, monkeypatch):
     assert ei.value.code == 1
     assert read_heartbeat(status)["error_class"] == "permanent"
 
-    # acquire_backend's string SystemExit is a transient (backend) failure
+    # a script's own string refusal (bad flag value) is permanent
     with pytest.raises(SystemExit) as ei:
         run_as_job(lambda: (_ for _ in ()).throw(
-            SystemExit("TPU backend unavailable: probe timed out")))
-    assert ei.value.code == EXIT_TRANSIENT
+            SystemExit("--only: unknown mode(s) ['x']")))
+    assert ei.value.code == 1
+    assert read_heartbeat(status)["error_class"] == "permanent"
 
 
 # --------------------------------------------------------------------------
@@ -146,6 +147,23 @@ def test_cli_enqueue_and_status(tmp_path, capsys):
     assert payload["jobs"] == [{
         "job": "bench", "state": "queued", "attempt": 1,
         "not_before": None, "argv": "python bench.py"}]
+
+
+def test_cli_run_runs_a_queued_job_on_a_bare_machine(tmp_path, capsys):
+    """`tpu_queue.py run` with the real default seams: nothing but the
+    queue and a python — the job runs (it is the first and only process
+    the supervisor starts), it is not parked behind a health triage."""
+    cli = _load_cli()
+    qdir = str(tmp_path / "queue")
+    marker = str(tmp_path / "ran")
+    assert cli.main(["--queue-dir", qdir, "enqueue", "touch",
+                     "--heartbeat-timeout", "60", "--", sys.executable,
+                     "-c", "open(%r, 'w').write('1')" % marker]) == 0
+    capsys.readouterr()
+    assert cli.main(["--queue-dir", qdir, "run"]) == 0
+    summary = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert summary == {"jobs": {"touch": {"state": "done", "attempt": 1}}}
+    assert os.path.exists(marker)
 
 
 def test_cli_enqueue_rejects_duplicate_and_empty(tmp_path):

@@ -1,21 +1,18 @@
 """Fault-injection suite for the TPU job supervisor (ISSUE 3).
 
-Every recovery path the next outage will need runs HERE, on CPU, through
-the supervisor's injectable seams (probe/waiter/spawn/clock/heartbeat):
+Every recovery path runs HERE, on CPU, through the supervisor's injectable
+seams (spawn/clock/heartbeat):
 
-* relay-dead parks with ZERO waiters spawned;
-* claim-wedge spawns exactly ONE waiter and drains the queue after the
-  (simulated) claim clears;
+* a queued job is the FIRST process the supervisor starts — no probe, no
+  waiter touches the device before it (one process per chip);
 * a stale-heartbeat job is killed, its flushed partial artifacts are
   recorded as salvaged, and the job is requeued with backoff;
 * `kill -9` of the supervisor between ANY two state transitions loses no
   queued job on restart (journal-prefix replay — fsync order makes every
   prefix a legal on-disk state).
 
-No test may block on a real `jax.devices()`: nothing here imports jax,
-and a hard SIGALRM per test enforces it (the suite has no pytest-timeout
-plugin; a test that sneaks a real probe in would otherwise hang CI for
-the claim-wedge minutes this suite exists to avoid).
+Nothing here imports jax, and a hard SIGALRM per test bounds it (the
+suite has no pytest-timeout plugin).
 """
 
 import json
@@ -27,9 +24,6 @@ import pytest
 from real_time_helmet_detection_tpu.runtime import (JobSpec, Spool,
                                                     Supervisor)
 from real_time_helmet_detection_tpu.runtime import spool as spool_mod
-from real_time_helmet_detection_tpu.runtime.supervisor import (CLAIM_WEDGED,
-                                                               HEALTHY,
-                                                               RELAY_DEAD)
 
 TIMEOUT_S = 120  # hard per-test ceiling; every test is sub-second on CPU
 
@@ -38,8 +32,8 @@ TIMEOUT_S = 120  # hard per-test ceiling; every test is sub-second on CPU
 def _hard_timeout():
     def _fire(signum, frame):
         raise RuntimeError(
-            "test exceeded the %ds hard timeout — something blocked "
-            "(a real probe/waiter leaked in?)" % TIMEOUT_S)
+            "test exceeded the %ds hard timeout — something blocked"
+            % TIMEOUT_S)
 
     old = signal.signal(signal.SIGALRM, _fire)
     signal.alarm(TIMEOUT_S)
@@ -93,26 +87,8 @@ class FakeHandle:
         self.killed = True
 
 
-class FakeWaiter:
-    """THE claim waiter: clears (rc 0) at `clear_at`, or errors (rc)."""
-
-    pid = 77
-
-    def __init__(self, clock, clear_at=None, rc=0):
-        self.clock = clock
-        self.clear_at = clear_at
-        self.rc = rc
-
-    def poll(self):
-        if self.clear_at is None:
-            return self.rc
-        return self.rc if self.clock.t >= self.clear_at else None
-
-
-def make_sup(spool, clock, *, relay=True, waiters=None, spawner=None,
-             hb_age=None, **kw):
-    """Supervisor with every external effect faked. `waiters` is a list
-    factory calls pop from (asserting on exhaustion beats hanging)."""
+def make_sup(spool, clock, *, spawner=None, hb_age=None, **kw):
+    """Supervisor with every external effect faked."""
     spawned = []
 
     def spawn(spec, env, log_path):
@@ -120,20 +96,11 @@ def make_sup(spool, clock, *, relay=True, waiters=None, spawner=None,
         spawned.append((spec.job, h, env))
         return h
 
-    def waiter_factory():
-        assert waiters, "unexpected waiter spawn"
-        return waiters.pop(0)
-
     sup = Supervisor(
         spool,
-        relay_probe=(relay if callable(relay) else (lambda: relay)),
-        waiter_factory=waiter_factory,
         spawn=spawn,
         clock=clock, sleep=clock.sleep, rng=lambda: 0.0,
         heartbeat_age=hb_age or (lambda path, started: 0.0),
-        claim_grace_s=kw.pop("claim_grace_s", 5.0),
-        waiter_retry_s=kw.pop("waiter_retry_s", 10.0),
-        park_retry_s=kw.pop("park_retry_s", 10.0),
         kill_grace_s=kw.pop("kill_grace_s", 1.0),
         poll_s=kw.pop("poll_s", 0.5),
         log=lambda m: None, **kw)
@@ -244,70 +211,23 @@ def test_spool_rejects_duplicate_job_id(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# triage
-# --------------------------------------------------------------------------
-
-def test_triage_relay_dead_spawns_no_waiter(tmp_path):
-    clock = FakeClock()
-    sp = Spool(str(tmp_path / "q"))
-    sup = make_sup(sp, clock, relay=False, waiters=[])
-    assert sup.triage() == RELAY_DEAD
-    assert sup.waiters_spawned == 0
-    sp.close()
-
-
-def test_triage_healthy_when_waiter_clears_fast(tmp_path):
-    clock = FakeClock()
-    sp = Spool(str(tmp_path / "q"))
-    sup = make_sup(sp, clock, waiters=[FakeWaiter(clock, clear_at=None)])
-    assert sup.triage() == HEALTHY
-    assert sup.waiters_spawned == 1
-    sp.close()
-
-
-def test_triage_wedged_when_waiter_blocks_past_grace(tmp_path):
-    clock = FakeClock()
-    sp = Spool(str(tmp_path / "q"))
-    w = FakeWaiter(clock, clear_at=clock.t + 10_000)
-    sup = make_sup(sp, clock, waiters=[w], claim_grace_s=5.0)
-    assert sup.triage() == CLAIM_WEDGED
-    assert sup.waiters_spawned == 1
-    assert sup.waiter is w  # still parked, never killed
-    sp.close()
-
-
-# --------------------------------------------------------------------------
 # the acceptance scenarios, end to end through run()
 # --------------------------------------------------------------------------
 
-def test_relay_dead_parks_then_exits_with_queue_intact(tmp_path):
-    clock = FakeClock()
-    sp = Spool(str(tmp_path / "q"))
-    enqueue(sp, "j1")
-    sup = make_sup(sp, clock, relay=False, waiters=[])
-    summary = sup.run(park_exit_s=50.0)
-    assert summary["parked"] is True
-    assert sup.waiters_spawned == 0  # acceptance: zero waiters
-    assert sp.jobs["j1"].state == spool_mod.QUEUED  # nothing lost
-    sp.close()
-
-
-def test_claim_wedge_one_waiter_then_drains(tmp_path):
+def test_queued_job_is_the_first_process_started(tmp_path):
+    """One process per chip: on a machine with nothing but the chip, `run`
+    starts the queued job itself — no health probe, no claim waiter, no
+    parked state in front of it."""
     clock = FakeClock()
     sp = Spool(str(tmp_path / "q"))
     enqueue(sp, "j1")
     enqueue(sp, "j2")
-    # waiter blocks 300 fake-seconds (past the 5s grace), then clears
-    w = FakeWaiter(clock, clear_at=clock.t + 300.0)
-    sup = make_sup(sp, clock, waiters=[w])
+    sup = make_sup(sp, clock)
     summary = sup.run()
-    # acceptance: exactly ONE waiter; queue drains after the claim clears
-    assert sup.waiters_spawned == 1
+    assert [job for job, _, _ in sup.spawned] == ["j1", "j2"]
     assert summary["jobs"]["j1"]["state"] == "done"
     assert summary["jobs"]["j2"]["state"] == "done"
-    assert "claim-wait" in states_of(sp, "j1")  # chained behind the waiter
-    # j2 started after the claim cleared: straight to running
-    assert clock.t >= w.clear_at
+    assert states_of(sp, "j1") == ["queued", "running", "done"]
     sp.close()
 
 
@@ -332,7 +252,6 @@ def test_stale_heartbeat_kill_salvage_requeue_backoff(tmp_path):
         return h
 
     sup = make_sup(sp, clock, spawner=spawner,
-                   waiters=[FakeWaiter(clock), FakeWaiter(clock)],
                    hb_age=lambda path, started: clock.t - started)
     summary = sup.run()
 
@@ -356,7 +275,7 @@ def test_stale_heartbeat_kill_salvage_requeue_backoff(tmp_path):
 def test_backoff_is_capped_exponential(tmp_path):
     clock = FakeClock()
     sp = Spool(str(tmp_path / "q"))
-    sup = make_sup(sp, clock, waiters=[])
+    sup = make_sup(sp, clock)
     spec = JobSpec(job="x", argv=["true"], backoff_base_s=30.0,
                    backoff_cap_s=100.0)
     assert sup._backoff_s(1, spec) == 30.0
@@ -376,8 +295,7 @@ def test_transient_exit_code_requeues_then_succeeds(tmp_path):
     def spawner(spec):
         return FakeHandle(clock, rc=rcs.pop(0))
 
-    sup = make_sup(sp, clock, spawner=spawner,
-                   waiters=[FakeWaiter(clock), FakeWaiter(clock)])
+    sup = make_sup(sp, clock, spawner=spawner)
     summary = sup.run()
     assert summary["jobs"]["flaky"] == {"state": "done", "attempt": 2}
     assert clock.slept >= 5.0  # backoff actually waited
@@ -389,8 +307,7 @@ def test_permanent_failure_no_requeue(tmp_path):
     sp = Spool(str(tmp_path / "q"))
     enqueue(sp, "broken", max_attempts=5)
     sup = make_sup(sp, clock,
-                   spawner=lambda spec: FakeHandle(clock, rc=1),
-                   waiters=[FakeWaiter(clock)])
+                   spawner=lambda spec: FakeHandle(clock, rc=1))
     summary = sup.run()
     assert summary["jobs"]["broken"] == {"state": "failed", "attempt": 1}
     sp.close()
@@ -412,37 +329,14 @@ def test_status_file_error_class_wins_over_exit_code(tmp_path):
         path = sp.status_path("statusy", len(attempts))
         with open(path, "w") as f:
             json.dump({"ok": len(attempts) > 1,
-                       "error": "UNAVAILABLE: tunnel died",
+                       "error": "UNAVAILABLE: socket closed",
                        "error_class": "transient"}, f)
         return FakeHandle(clock, rc=1 if len(attempts) == 1 else 0)
 
-    sup = make_sup(sp, clock, spawner=spawner,
-                   waiters=[FakeWaiter(clock), FakeWaiter(clock)])
+    sup = make_sup(sp, clock, spawner=spawner)
     summary = sup.run()
     assert summary["jobs"]["statusy"] == {"state": "done", "attempt": 2}
     assert js.spec.max_attempts == 2
-    sp.close()
-
-
-def test_relay_death_during_claim_wait_requeues_job(tmp_path):
-    clock = FakeClock()
-    sp = Spool(str(tmp_path / "q"))
-    enqueue(sp, "j1")
-    relay_alive = {"v": True}
-    # waiter wedges; relay dies 50 fake-seconds in; park_exit ends the run
-    w = FakeWaiter(clock, clear_at=clock.t + 1e9)
-    die_at = clock.t + 50.0
-
-    def relay():
-        if clock.t >= die_at:
-            relay_alive["v"] = False
-        return relay_alive["v"]
-
-    sup = make_sup(sp, clock, relay=relay, waiters=[w])
-    summary = sup.run(park_exit_s=30.0)
-    assert summary["parked"] is True
-    assert states_of(sp, "j1")[-1] == "queued"  # back out of claim-wait
-    assert sup.waiters_spawned == 1
     sp.close()
 
 
@@ -458,7 +352,7 @@ def test_recover_requeues_interrupted_jobs(tmp_path):
     sp.close()
 
     sp2 = Spool(str(tmp_path / "q"))
-    sup = make_sup(sp2, clock, waiters=[])
+    sup = make_sup(sp2, clock)
     sup.recover()
     assert sp2.jobs["was-waiting"].state == spool_mod.QUEUED
     assert sp2.jobs["was-running"].state == spool_mod.QUEUED
@@ -478,8 +372,7 @@ def test_jobs_run_fifo_and_serially(tmp_path):
         order.append(spec.job)
         return FakeHandle(clock, rc=0, runtime=1.0)
 
-    sup = make_sup(sp, clock, spawner=spawner,
-                   waiters=[FakeWaiter(clock) for _ in range(3)])
+    sup = make_sup(sp, clock, spawner=spawner)
     sup.run()
     assert order == ["first", "second", "third"]
     sp.close()
@@ -490,7 +383,7 @@ def test_job_env_carries_heartbeat_and_status_paths(tmp_path, monkeypatch):
     clock = FakeClock()
     sp = Spool(str(tmp_path / "q"))
     enqueue(sp, "j1", env={"EXTRA": "1"})
-    sup = make_sup(sp, clock, waiters=[FakeWaiter(clock)])
+    sup = make_sup(sp, clock)
     sup.run()
     _, _, env = sup.spawned[0]
     assert env["TPU_QUEUE_HEARTBEAT"] == sp.heartbeat_path("j1")
@@ -508,7 +401,7 @@ def test_job_env_respects_explicit_span_log(tmp_path):
     clock = FakeClock()
     sp = Spool(str(tmp_path / "q"))
     enqueue(sp, "j1", env={"OBS_SPAN_LOG": "/custom/spans.jsonl"})
-    sup = make_sup(sp, clock, waiters=[FakeWaiter(clock)])
+    sup = make_sup(sp, clock)
     sup.run()
     _, _, env = sup.spawned[0]
     assert env["OBS_SPAN_LOG"] == "/custom/spans.jsonl"
