@@ -193,7 +193,7 @@ RAW_WRITE_ALLOW = {
 # under the serve:/fleet:/recover: prefixes belongs to ONE request (or a
 # batch of them) and must carry ctx= or links=.
 TRACE_LIFECYCLE_SPANS = {
-    "serve:compile", "serve:state", "serve:killed", "serve:degrade",
+    "serve:lower", "serve:compile", "serve:state", "serve:killed", "serve:degrade",
     "recover:reload",
     "fleet:rollout", "fleet:promote", "fleet:rollback",
     "fleet:replica-death", "fleet:respawn", "fleet:reload-timeout",

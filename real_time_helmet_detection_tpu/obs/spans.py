@@ -1,4 +1,4 @@
-"""Host span tracer: a crash-safe JSONL event log for the flight recorder.
+"""Host span tracer: an always-on in-memory ring, plus a crash-safe JSONL log.
 
 The reference has no observability tooling of any kind (its training loop
 prints averaged meters and nothing else, ref train.py:140-160); this module
@@ -11,7 +11,23 @@ Design rules, each load-bearing:
 
 * **stdlib only.** `runtime/` (the job supervisor, which must never build
   the ML stack) imports this module; so does `scripts/obs_report.py`.
-* **Durations from the monotonic clock**, wall time recorded alongside for
+* **One ring, always on.** Every span, `record()` and event of every
+  tracer in the process lands in ONE bounded in-memory ring as
+  `(name, t0, dur_s, meta)`, `t0` on `time.monotonic()` — the clock
+  `benchmark/run.py` stamps its `jit_bench_mark` marks with, so a reader
+  brings the spans onto a device trace's clock with the offset it already
+  has. `default_tracer()` is the process-wide file-less tracer;
+  `snapshot(since=)` reads the ring oldest first and returns `None` (never
+  a partial list) when the ring has overwritten part of what was asked
+  for; `dropped` counts what it overwrote. With no file configured a span
+  costs two clock reads and one tuple append: no record dict, no json, no
+  wall-clock read, no lock beyond the deque's own.
+* **Every span has a place on the clock.** `record(name, dur_s)` stamps
+  `t0 = now - dur_s` (the caller measured an interval that just ended);
+  an explicit `t0=` wins.
+* **The file is optional**, and `enabled` means exactly "a file is
+  configured" (the serving engine mints per-request trace contexts only
+  then). Wall time is recorded in the file alongside (`t`, `t0`) for
   joining with the tpu_queue journal and bench lines (wall can NTP-step;
   monotonic cannot).
 * **Crash-safe appends**: the log is opened O_APPEND and every record is
@@ -20,16 +36,13 @@ Design rules, each load-bearing:
   replay does (runtime/spool.py). No fsync per record — span logs are
   diagnostics, not the artifact of record, and per-iteration fsyncs would
   tax the loop being measured.
-* **Disabled == free.** `maybe_tracer()` with no path configured returns a
-  tracer whose `span()` still measures (callers read `sp.dur_s` for their
-  JSON artifacts) but writes nothing and whose `wrap()` returns the
-  function unchanged.
 
 Span taxonomy (docs/ARCHITECTURE.md "Observability & flight recorder"):
-`loader-wait`, `h2d`, `dispatch`, `fetch`, `checkpoint`, `compile`,
-`calibrate`, `bench:*` section spans, `heartbeat` events (the runtime
-heartbeat mirrors every beat here when tracing is on), `recompile` events
-and `context` records (host loadavg).
+`loader-wait`, `h2d`, `dispatch`, `step`, `fetch`, `checkpoint`, `compile`
+(one per jax compile stage, obs/telemetry.py), `calibrate`, `serve:*`
+(serving/engine.py), `bench:*` section spans, `heartbeat` events (the
+runtime heartbeat mirrors every beat here when tracing is on),
+`recompile` events and `context` records (host loadavg).
 
 Trace-context extension (ISSUE 14, obs/trace.py): every write method
 takes an optional `ctx` (a TraceContext — serialized as the optional
@@ -42,64 +55,120 @@ assembler needs the interval, not a point). `bind(**tags)` attaches
 process-constant fields (rank, world) to every subsequent record — the
 cross-process join key for train/scaling rank logs. All fields are
 OPTIONAL additions to obs-spans-v1: readers of pre-ISSUE logs see
-nothing new, pre-ISSUE readers of new logs ignore the extras.
+nothing new, pre-ISSUE readers of new logs ignore the extras. Contexts
+and links go to the file only; the ring keeps times.
 """
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import time
-from typing import Optional
+from typing import List, Optional, Tuple
 
 SPAN_SCHEMA = "obs-spans-v1"
 OBS_SPAN_ENV = "OBS_SPAN_LOG"
+# a 60 s window of the bulk serving cell: ~1,400 per-request records a
+# second plus a dozen per batch
+RING_CAPACITY = 1 << 17
+
+
+class SpanRing:
+    """The bounded in-memory store: `(name, t0, dur_s, meta)` in the order
+    the intervals ENDED (a span is appended when it closes). Appends are
+    one `deque.append` of one tuple; the sequence number in front is what
+    lets a reader count what the ring overwrote."""
+
+    def __init__(self, capacity: int = RING_CAPACITY):
+        self._items: collections.deque = collections.deque(
+            maxlen=max(1, int(capacity)))
+        self._seq = itertools.count()
+
+    def append(self, name: str, t0: float, dur_s: float, meta) -> None:
+        self._items.append((next(self._seq), name, t0, dur_s, meta))
+
+    def clear(self) -> None:
+        """Empty the ring and its `dropped` count."""
+        self._items.clear()
+        self._seq = itertools.count()
+
+    def _copy(self) -> list:
+        for _ in range(8):
+            try:
+                return list(self._items)
+            except RuntimeError:  # an append raced the copy: take it again
+                continue
+        return list(self._items.copy())
+
+    @property
+    def dropped(self) -> int:
+        """How many entries the ring has overwritten so far."""
+        try:
+            return self._items[0][0]  # the oldest kept entry's number
+        except IndexError:
+            return 0
+
+    def snapshot(self, since: Optional[float] = None
+                 ) -> Optional[List[Tuple]]:
+        """`[(name, t0, dur_s, meta)]`, oldest first; with `since`, those
+        that started at or after it. `None` when the ring has overwritten
+        an entry that ended after `since`: the window's start is gone, and
+        a partial list would read as a smaller sum."""
+        items = self._copy()
+        if since is None:
+            return [it[1:] for it in items]
+        if items and items[0][0] and items[0][2] + items[0][3] > since:
+            return None
+        return [it[1:] for it in items if it[2] >= since]
+
+
+_RING = SpanRing()
+
+
+def reset_ring() -> None:
+    """Empty the process-wide ring and its `dropped` count (tests only: a
+    prior test's spans must not leak into the next one's snapshot)."""
+    _RING.clear()
 
 
 class Span:
-    """One in-flight (or pre-measured) span. `dur_s` is set at close."""
+    """One in-flight span and its context manager. `t0` is the monotonic
+    start; `dur_s` is set at close."""
 
-    __slots__ = ("name", "meta", "t_wall", "_mono0", "dur_s")
+    __slots__ = ("name", "meta", "t0", "dur_s", "_t_wall", "_tracer",
+                 "_ctx", "_links")
 
-    def __init__(self, name: str, meta: dict):
+    def __init__(self, tracer: "SpanTracer", name: str, meta: dict,
+                 ctx=None, links=None):
         self.name = name
         self.meta = meta
-        self.t_wall = time.time()
-        self._mono0 = time.monotonic()
+        self._tracer = tracer
+        self._ctx = ctx
+        self._links = links
+        self._t_wall = time.time() if tracer.enabled else None
         self.dur_s: Optional[float] = None
+        self.t0 = time.monotonic()
 
     def close(self) -> float:
         if self.dur_s is None:
-            self.dur_s = time.monotonic() - self._mono0
+            self.dur_s = time.monotonic() - self.t0
         return self.dur_s
 
-
-class _SpanCM:
-    """Context manager wrapping one Span; writes the record on exit."""
-
-    __slots__ = ("_tracer", "_span", "_ctx", "_links")
-
-    def __init__(self, tracer: "SpanTracer", span: Span, ctx=None,
-                 links=None):
-        self._tracer = tracer
-        self._span = span
-        self._ctx = ctx
-        self._links = links
-
-    def __enter__(self) -> Span:
-        return self._span
+    def __enter__(self) -> "Span":
+        return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        sp = self._span
-        sp.close()
-        meta = dict(sp.meta)
+        self.close()
+        meta = self.meta
         if exc_type is not None:
-            meta["error"] = exc_type.__name__
-        rec = {"kind": "span", "name": sp.name,
-               "t": sp.t_wall, "dur_s": round(sp.dur_s, 6),
-               **({"meta": meta} if meta else {})}
-        _trace_fields(rec, self._ctx, self._links, t0=sp.t_wall)
-        self._tracer._write(rec)
+            meta = dict(meta, error=exc_type.__name__)
+        tracer = self._tracer
+        tracer._ring.append(self.name, self.t0, self.dur_s, meta or None)
+        if self._t_wall is not None:
+            tracer._write_span(self.name, self._t_wall, self._t_wall,
+                               self.dur_s, meta, self._ctx, self._links)
 
 
 def _trace_fields(rec: dict, ctx, links, t0: Optional[float] = None
@@ -119,19 +188,33 @@ def _trace_fields(rec: dict, ctx, links, t0: Optional[float] = None
 
 
 class SpanTracer:
-    """JSONL span/event writer (see module docstring).
+    """Span/event recorder (see module docstring): always the in-memory
+    ring, and the JSONL file as well when `path` is given.
 
-    `path=None` (or "") builds a DISABLED tracer: spans still time (so
-    callers can read `sp.dur_s`), nothing touches the filesystem.
+    `path=None` (or "") builds a file-less tracer (`enabled` False): spans
+    time and land in the ring, nothing touches the filesystem. `ring`
+    defaults to the process-wide one; tests hand in a small `SpanRing`.
     """
 
-    def __init__(self, path: Optional[str] = None):
+    def __init__(self, path: Optional[str] = None,
+                 ring: Optional[SpanRing] = None):
         self.path = path or None
         self._f = None
         self.enabled = self.path is not None
         self._bound: dict = {}
+        self._ring = ring if ring is not None else _RING
 
-    # ---- the write path --------------------------------------------------
+    # ---- the ring --------------------------------------------------------
+
+    def snapshot(self, since: Optional[float] = None):
+        """The ring's spans, oldest first (`SpanRing.snapshot`)."""
+        return self._ring.snapshot(since)
+
+    @property
+    def dropped(self) -> int:
+        return self._ring.dropped
+
+    # ---- the file --------------------------------------------------------
 
     def _write(self, rec: dict) -> None:
         if not self.enabled:
@@ -159,55 +242,69 @@ class SpanTracer:
             # failed once stays silent (half-dead appends help nobody)
             self.enabled = False
 
+    def _write_span(self, name: str, t_wall: float, t0_wall: float,
+                    dur_s: float, meta, ctx=None, links=None) -> None:
+        """One `span` line of the file: `t` the legacy stamp, `t0_wall`
+        the interval's wall-clock start (written only on traced records)."""
+        rec = {"kind": "span", "name": name, "t": t_wall,
+               "dur_s": round(float(dur_s), 6),
+               **({"meta": meta} if meta else {})}
+        _trace_fields(rec, ctx, links, t0=t0_wall)
+        self._write(rec)
+
     # ---- public API ------------------------------------------------------
 
     def bind(self, **tags) -> None:
         """Attach process-constant fields (rank, world) to every record
-        this tracer writes from now on — the cross-process join key for
-        per-rank span logs (ISSUE 14)."""
+        this tracer writes to its file from now on — the cross-process
+        join key for per-rank span logs (ISSUE 14)."""
         self._bound.update(tags)
 
-    def span(self, name: str, ctx=None, links=None, **meta) -> _SpanCM:
+    def span(self, name: str, ctx=None, links=None, **meta) -> Span:
         """`with tracer.span("compile", batch=16) as sp: ...` — times the
-        block (always), writes a span record on exit (when enabled), and
-        leaves the duration readable as `sp.dur_s`. `ctx`/`links` attach
-        the span to a trace (obs/trace.py)."""
-        return _SpanCM(self, Span(name, meta), ctx=ctx, links=links)
+        block, appends it to the ring on exit (and to the file, when one
+        is configured), and leaves the duration readable as `sp.dur_s`.
+        `ctx`/`links` attach the file record to a trace (obs/trace.py)."""
+        return Span(self, name, meta, ctx, links)
 
     def record(self, name: str, dur_s: float, ctx=None, links=None,
-               **meta) -> None:
+               t0: Optional[float] = None, **meta) -> None:
         """A span whose duration the caller already measured (the train/
-        eval segment meters): write it without re-timing. The write stamp
-        is the interval END; a traced record carries `t0 = t - dur_s` so
-        the waterfall assembler sees the interval."""
-        t = time.time()
-        rec = {"kind": "span", "name": name, "t": t,
-               "dur_s": round(float(dur_s), 6),
-               **({"meta": meta} if meta else {})}
-        _trace_fields(rec, ctx, links, t0=t - float(dur_s))
-        self._write(rec)
+        eval segment meters, the compile listener): keep it without
+        re-timing. `t0` is its start on `time.monotonic()`; left out, the
+        interval is taken to end now. In the file the write stamp `t` is
+        the interval END and a traced record carries `t0 = t - dur_s`."""
+        dur_s = float(dur_s)
+        self._ring.append(name, time.monotonic() - dur_s if t0 is None
+                          else float(t0), dur_s, meta or None)
+        if self.enabled:
+            t = time.time()
+            self._write_span(name, t, t - dur_s, dur_s, meta, ctx, links)
 
     def event(self, name: str, ctx=None, links=None, **meta) -> None:
         """Zero-duration marker (heartbeat, recompile, job transition)."""
-        rec = {"kind": "event", "name": name, "t": time.time(),
-               **({"meta": meta} if meta else {})}
-        _trace_fields(rec, ctx, links)
-        self._write(rec)
+        self._ring.append(name, time.monotonic(), 0.0, meta or None)
+        if self.enabled:
+            rec = {"kind": "event", "name": name, "t": time.time(),
+                   **({"meta": meta} if meta else {})}
+            _trace_fields(rec, ctx, links)
+            self._write(rec)
 
     def context(self, **extra) -> Optional[dict]:
         """Sample host context (loadavg — obs/context.py) into a `context`
-        record; returns the sample (even when disabled,
-        so callers can also embed it in their own JSON lines)."""
+        record; returns the sample (also without a file, so callers can
+        embed it in their own JSON lines)."""
         from .context import sample_context
         sample = sample_context()
         sample.update(extra)
+        self._ring.append("context", time.monotonic(), 0.0, sample)
         self._write({"kind": "context", "name": "context",
                      "t": time.time(), "sample": sample})
         return sample
 
     def wrap(self, name: str, fn, **meta):
-        """Timed wrapper emitting one span per call; identity when the
-        tracer is disabled (the H2D stage hook must cost nothing off)."""
+        """Timed wrapper emitting one span per call; identity without a
+        file (callers whose callee records its own span lose nothing)."""
         if not self.enabled:
             return fn
 
@@ -226,14 +323,28 @@ class SpanTracer:
             self._f = None
 
 
+_DEFAULT: Optional[SpanTracer] = None
+
+
+def default_tracer() -> SpanTracer:
+    """The process-wide file-less tracer over the process-wide ring (as
+    `obs.metrics.default_registry()` is for metrics): where code with no
+    tracer handed to it records, and where readers take snapshots."""
+    global _DEFAULT
+    if _DEFAULT is None:
+        _DEFAULT = SpanTracer(None)
+    return _DEFAULT
+
+
 def maybe_tracer(path: Optional[str] = None,
                  env: Optional[dict] = None) -> SpanTracer:
     """The one construction point: explicit `path` wins, else
-    $OBS_SPAN_LOG, else a disabled tracer. Mirrors
+    $OBS_SPAN_LOG, else `default_tracer()`. With a path the tracer feeds
+    the same process-wide ring and writes the JSONL as well. Mirrors
     `runtime.maybe_job_heartbeat`'s env-based wiring so every instrumented
     script shares one line."""
     p = path or (env if env is not None else os.environ).get(OBS_SPAN_ENV)
-    return SpanTracer(p)
+    return SpanTracer(p) if p else default_tracer()
 
 
 def read_spans(path: str) -> list:

@@ -18,20 +18,33 @@ With `--telemetry` off nothing here is traced: the step program is the
 PRE-PR program and the loss is bit-identical (pinned by
 tests/test_obs.py on the 8-device mesh).
 
-Also home to the runtime recompile counter: `jax.monitoring` listeners on
-XLA's backend-compile event. Caveats (docs/ARCHITECTURE.md): the count is
-per-process and includes every backend compile jax performs (internal
-jits — `jnp.copy` helpers, donation snapshots — count too). A program
-found in the persistent compile cache still fires the event (its duration
-is then the retrieval time) and is counted in `cache_hits` as well, so
-`count - cache_hits` is what XLA actually compiled.
+Also home to the process's ONE compile listener: `jax.monitoring`
+listeners on jax's three compile stages (tracing to a jaxpr, lowering to
+MLIR, XLA's backend compile). Each event lands in the default tracer's
+ring (obs/spans.py) as a `compile` span with `stage=trace|lower|backend`,
+`cache_hit` and `fun`, stamped `t0 = now - dur`: a compile inside a
+measured window is a span with a time, in every entry point. It is
+installed (idempotently) by `train.make_step_runner` and
+`ServingEngine.__init__`; `install_recompile_counter()` is a view over it
+that counts backend compiles from the call on. Caveats
+(docs/ARCHITECTURE.md): the count is per-process and includes every
+backend compile jax performs (internal jits — `jnp.copy` helpers,
+donation snapshots — count too). A program found in the persistent
+compile cache still fires the event (its duration is then the retrieval
+time) and is counted in `cache_hits` as well, so `count - cache_hits` is
+what XLA actually compiled.
 """
 
 from __future__ import annotations
 
+import threading
+import time
+import weakref
 from typing import Dict, Mapping, Optional, Sequence
 
 import jax.numpy as jnp
+
+from .spans import default_tracer
 
 # The scalars the ring carries, in row order. The first four mirror
 # LossLog.KEYS (ops/loss.py); the last three are the in-jit norms.
@@ -92,49 +105,113 @@ def ring_to_host(ring_host: Mapping,
 
 
 # ---------------------------------------------------------------------------
-# runtime recompile counter
+# the compile listener and its counter views
 
-class RecompileCounter:
-    """Count of backend-compile events observed since `install` (see the
-    module docstring's caveats). `last_dur_s` is the most recent compile's
-    duration; `cache_hits` of them were served by the persistent cache."""
+_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _CompileListener:
+    """Process-wide totals of the backend-compile events, fed by the two
+    `jax.monitoring` callbacks below. jax fires the cache-hit event inside
+    the backend compile it belongs to, on the compiling thread, before
+    that compile's duration event: a thread-local flag pairs them."""
 
     def __init__(self):
         self.count = 0
         self.cache_hits = 0
         self.total_s = 0.0
         self.last_dur_s: Optional[float] = None
+        self.file_tracers = weakref.WeakSet()  # mirror backend compiles
+        self._thread = threading.local()
 
-    def _on_event(self, dur_s: float) -> None:
+    def on_event(self, name: str, **kw) -> None:
+        if name == _CACHE_HIT_EVENT:
+            self.cache_hits += 1
+            self._thread.hit = True
+
+    def on_duration(self, name: str, dur_s: float, **kw) -> None:
+        stage = _STAGE_OF_EVENT.get(name)
+        if stage is None:
+            return
+        dur_s = float(dur_s)
+        if stage != "backend":
+            default_tracer().record("compile", dur_s, stage=stage,
+                                    fun=kw.get("fun_name"))
+            return
+        hit = getattr(self._thread, "hit", False)
+        self._thread.hit = False
         self.count += 1
-        self.total_s += float(dur_s)
-        self.last_dur_s = float(dur_s)
+        self.total_s += dur_s
+        self.last_dur_s = dur_s
+        default_tracer().record("compile", dur_s, stage=stage,
+                                cache_hit=hit, fun=kw.get("fun_name"),
+                                seq=self.count)
+        for tracer in list(self.file_tracers):
+            # the span log keeps one `compile` line a backend compile,
+            # as before the ring (obs_report counts them)
+            tracer._write_span("compile", time.time(), None, dur_s,
+                               {"seq": self.count})
 
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_LISTENER: Optional[_CompileListener] = None  # guarded-by: _INSTALL_LOCK
+_INSTALL_LOCK = threading.Lock()
+
+
+def install_compile_listener() -> _CompileListener:
+    """Register the process's compile listener with `jax.monitoring`, once
+    however often this is called (jax keeps every registered callback for
+    the life of the process)."""
+    global _LISTENER
+    with _INSTALL_LOCK:
+        if _LISTENER is None:
+            import jax.monitoring as monitoring
+            listener = _CompileListener()
+            monitoring.register_event_duration_secs_listener(
+                listener.on_duration)
+            monitoring.register_event_listener(listener.on_event)
+            _LISTENER = listener
+        return _LISTENER
+
+
+class RecompileCounter:
+    """Backend-compile events observed since this counter was made (see
+    the module docstring's caveats): a view over the one listener.
+    `last_dur_s` is the most recent compile's duration; `cache_hits` of
+    them were served by the persistent cache."""
+
+    def __init__(self, listener: _CompileListener):
+        self._listener = listener
+        self._count0 = listener.count
+        self._hits0 = listener.cache_hits
+        self._total0 = listener.total_s
+
+    @property
+    def count(self) -> int:
+        return self._listener.count - self._count0
+
+    @property
+    def cache_hits(self) -> int:
+        return self._listener.cache_hits - self._hits0
+
+    @property
+    def total_s(self) -> float:
+        return self._listener.total_s - self._total0
+
+    @property
+    def last_dur_s(self) -> Optional[float]:
+        return self._listener.last_dur_s if self.count else None
 
 
 def install_recompile_counter(tracer=None) -> RecompileCounter:
-    """Register a jax.monitoring listener counting backend compiles; when
-    `tracer` is an enabled SpanTracer each compile also lands as a
-    `compile` span (the flight recorder's recompile evidence). Returns the
-    live counter. Each call installs an independent counter (jax has no
-    public unregister; listeners are tiny)."""
-    import jax.monitoring as monitoring
-    counter = RecompileCounter()
-
-    def listen(name: str, dur_s: float, **kw) -> None:
-        if name != _COMPILE_EVENT:
-            return
-        counter._on_event(dur_s)
-        if tracer is not None and getattr(tracer, "enabled", False):
-            tracer.record("compile", dur_s, seq=counter.count)
-
-    def listen_hit(name: str, **kw) -> None:
-        if name == _CACHE_HIT_EVENT:
-            counter.cache_hits += 1
-
-    monitoring.register_event_duration_secs_listener(listen)
-    monitoring.register_event_listener(listen_hit)
-    return counter
+    """A counter of backend compiles from now on. When `tracer` writes a
+    span log, each backend compile is also mirrored there as a `compile`
+    line (the flight recorder's recompile evidence)."""
+    listener = install_compile_listener()
+    if tracer is not None and getattr(tracer, "enabled", False):
+        listener.file_tracers.add(tracer)
+    return RecompileCounter(listener)

@@ -20,12 +20,13 @@ flags the two hard-error shapes:
   bug). Unclosed traces are reported as orphans, not double-counted as
   broken — their dangling parents are the same defect.
 
-Fan-in semantics: a batch-stage span (`serve:h2d`/`serve:compute`/
-`serve:d2h`/`serve:batch-form`) carries `links` naming every member
-request's context instead of a parent. The assembler attaches it to each
-linked trace, so one slow compute surfaces in all N member waterfalls —
-which is the honest attribution: those N requests DID wait on that one
-compute.
+Fan-in semantics: a batch-stage span (`serve:batch-form`/`serve:h2d`/
+`serve:dispatch`/`serve:device-wait`/`serve:d2h`) carries `links` naming
+every member request's context instead of a parent. The assembler
+attaches it to each linked trace, so one slow batch surfaces in all N
+member waterfalls — which is the honest attribution: those N requests
+DID wait on that one batch. (`serve:dispatch` is the asynchronous call of
+the compiled bucket, host time; the device's time is `serve:device-wait`.)
 
 Interval convention: traced span records carry `t0` (interval start,
 obs/spans.py) next to the legacy write stamp `t`; the waterfall orders

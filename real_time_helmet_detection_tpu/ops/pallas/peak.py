@@ -89,6 +89,7 @@ def _fused_chw(logits_chw: jax.Array, interpret: bool = False,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((c, h, w), jnp.float32),
         interpret=interpret,
+        name="peak_scores",  # the device event's name: what a trace keys on
     )(logits_chw.astype(jnp.float32))
 
 

@@ -160,9 +160,14 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
         return keep, scores
 
     def predict_impl(variables, images: jax.Array) -> Detections:
+        # `jax.named_scope`s: flax names the network's modules; these name
+        # the plain functions around it, so that the compiled program's
+        # metadata says which layer owns each instruction
+        # (obs/hlo_scopes.py)
         if normalize is not None:
-            images = (images.astype(jnp.float32) / 255.0 - norm_mean) \
-                / norm_std
+            with jax.named_scope("normalize"):
+                images = (images.astype(jnp.float32) / 255.0 - norm_mean) \
+                    / norm_std
         if infer_dtype == "int8":
             # BN fold + per-channel weight quantization run INSIDE the
             # program from the training checkpoint (O(params) once per
@@ -176,14 +181,18 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
             out = model.apply(variables, images, train=False)
         # (B, S, H, W, C+4)
         b, s = out.shape[0], out.shape[1]
-        peaks = peak_scores(out[..., :num_cls]) if use_pallas else None
-        dets = jax.vmap(jax.vmap(decode_one))(out, peaks)  # (B, S, topk, ...)
-        boxes = dets.boxes.reshape(b, s * topk, 4)
-        classes = dets.classes.reshape(b, s * topk)
-        scores = dets.scores.reshape(b, s * topk)
-        valid = dets.valid.reshape(b, s * topk)
-        keep, scores = jax.vmap(suppress)(boxes, scores, valid)
-        valid = keep & valid
+        with jax.named_scope("peak"):
+            peaks = peak_scores(out[..., :num_cls]) if use_pallas else None
+        with jax.named_scope("decode"):
+            dets = jax.vmap(jax.vmap(decode_one))(out, peaks)
+            # (B, S, topk, ...)
+            boxes = dets.boxes.reshape(b, s * topk, 4)
+            classes = dets.classes.reshape(b, s * topk)
+            scores = dets.scores.reshape(b, s * topk)
+            valid = dets.valid.reshape(b, s * topk)
+        with jax.named_scope("nms"):
+            keep, scores = jax.vmap(suppress)(boxes, scores, valid)
+            valid = keep & valid
         if cascade_summary:
             conf = jax.vmap(confidence_summary)(scores, valid)
             return CascadeDetections(boxes=boxes, classes=classes,

@@ -79,18 +79,25 @@ Design rules, each load-bearing:
   replays seeded schedules of device-loss/hung-fetch/slow-batch against
   the engine and asserts zero acknowledged requests are lost and every
   survivor is bit-identical to one-shot predict.
-* **Flight-recorder spans.** `serve:queue-wait` / `serve:batch-form` /
-  `serve:h2d` / `serve:compute` (async dispatch walls) / `serve:d2h`
-  (the fetch — where un-hidden device time surfaces, exactly like
-  eval's `fetch` span) / `serve:e2e` per request; `$OBS_SPAN_LOG` is
-  honored via `obs.spans.maybe_tracer`.
+* **Flight-recorder spans.** `serve:lower` / `serve:compile` per bucket
+  at construction; `serve:queue-wait` per request; per batch, on the
+  dispatcher thread `serve:batch-form` / `serve:h2d` / `serve:dispatch`
+  (the ASYNCHRONOUS call of the compiled bucket: host time, not device
+  time), on the fetcher `serve:inflight-wait` / `serve:device-wait`
+  (`block_until_ready`: the batch period as the host sees it) /
+  `serve:d2h` (the `device_get` of a finished batch alone);
+  `serve:e2e` per request. They go to the process's in-memory ring
+  (obs/spans.py) and, when `$OBS_SPAN_LOG` or `tracer=` names a file,
+  to the span log. The engine calls nothing of its tracer but
+  `span`/`record`/`event`/`enabled`: the benchmark hands in its own.
 * **Trace contexts (ISSUE 14).** With tracing enabled, every request
   carries a `TraceContext` (obs/trace.py): `submit(ctx=...)` accepts
   one from the FleetRouter, else the engine mints a root itself.
   Per-request spans (`serve:queue-wait`/`serve:e2e`/`serve:shed`)
   carry the context; batch-level spans (`serve:batch-form`/`h2d`/
-  `compute`/`d2h`) and the `recover:*` events carry fan-in `links`
-  naming every member request's context — one slow compute explains N
+  `dispatch`/`device-wait`/`d2h`) and the `recover:*` events carry
+  fan-in `links`
+  naming every member request's context — one slow batch explains N
   tails (obs/traceview.py reassembles the waterfalls). CLOSURE
   OWNERSHIP: the root minter accounts for the request's end — when the
   engine minted the root it emits the root-closure `serve:e2e` (or a
@@ -103,8 +110,9 @@ Design rules, each load-bearing:
 * **Live metrics plane (ISSUE 10).** Every admission decision, batch
   outcome and pipeline stage also lands in an `obs.metrics` registry:
   `serve.*` counters (submitted/completed/shed/retried/requeued/
-  failed), queue-depth + per-bucket fill gauges, and per-stage
-  h2d/compute/d2h/e2e latency histograms — all HOST-side bookkeeping
+  failed), queue-depth + per-bucket fill gauges, and the `serve.e2e_ms`
+  latency histogram (the per-stage times are the spans above, exact and
+  with a place on the clock) — all HOST-side bookkeeping
   (the executed programs are bit-identical with metrics on or off, and
   the per-batch D2H stays the only fetch). `health()` folds the
   digested registry in; `$OBS_METRICS` arms crash-safe periodic
@@ -118,6 +126,7 @@ Design rules, each load-bearing:
 from __future__ import annotations
 
 import collections
+import gc
 import queue
 import threading
 import time
@@ -322,6 +331,7 @@ class ServingEngine:
 
         from ..obs import metrics as metrics_mod
         from ..obs.spans import maybe_tracer
+        from ..obs.telemetry import install_compile_listener
 
         self._buckets = tuple(sorted({int(b) for b in buckets}))
         if not self._buckets or self._buckets[0] < 1:
@@ -352,21 +362,27 @@ class ServingEngine:
         self._mg_queue = mm.gauge("serve.queue_depth")
         self._mg_retry = mm.gauge("serve.retry_depth")
         self._mg_inflight = mm.gauge("serve.inflight_batches")
-        self._mh = {name: mm.histogram("serve.%s_ms" % name) for name in (
-            "queue_wait", "batch_form", "h2d", "compute", "d2h", "e2e")}
+        self._mh_e2e = mm.histogram("serve.e2e_ms")
         self._mg_fill = {b: mm.gauge("serve.fill.b%d" % b)
                          for b in self._buckets}
 
         self._variables = self._commit_variables(variables)
         # AOT: one compile per bucket, at construction, from the SAME
         # predict program — the serve path never traces again
+        # (every jax compile stage also lands as a `compile` span in the
+        # process's ring: obs/telemetry.py)
+        install_compile_listener()
         self._compiled: Dict[int, object] = {}
         for b in self._buckets:
             spec = jax.ShapeDtypeStruct((b,) + self._image_shape,
                                         self._image_dtype)
+            with self._tracer.span("serve:lower", b=b):
+                lowered = predict.lower(self._variables, spec)
             with self._tracer.span("serve:compile", b=b):
-                self._compiled[b] = predict.lower(
-                    self._variables, spec).compile()
+                self._compiled[b] = lowered.compile()
+        # (n, links) of the batch the fetcher thread is about to `_fetch`;
+        # its watchdog worker reads it before the fetcher moves on
+        self._fetch_meta = (0, None)  # lock-free: the fetcher thread's own
 
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1,
                                                          int(queue_capacity)))
@@ -630,6 +646,19 @@ class ServingEngine:
     def buckets(self) -> Tuple[int, ...]:
         return self._buckets
 
+    def scope_maps(self) -> Dict[int, Dict[str, str]]:
+        """bucket -> {HLO instruction: layer} of the bucket's compiled
+        executable (obs/hlo_scopes.py): what turns the instruction names
+        of a device-only profiler trace (`fusion.15`, `copy.681`) into the
+        layers that own them. Only the engine holds the executables.
+        (The names are those of the compile that produced an executable:
+        jax's persistent cache keys on the program without its metadata,
+        so a cache hit carries the scope names of whichever commit filled
+        the entry — `scripts/layer_trace.py` compiles with the cache off.)
+        """
+        from ..obs.hlo_scopes import scope_map
+        return {b: scope_map(c.as_text()) for b, c in self._compiled.items()}
+
     @property
     def metrics(self):
         """This engine's MetricsRegistry — the canary watchdog's read
@@ -871,7 +900,7 @@ class ServingEngine:
             blinks = links_of([r.ctx for r in live]) or None
             with self._dispatch_mutex:
                 with self._tracer.span("serve:batch-form", links=blinks,
-                                       n=len(live)) as sp_form:
+                                       n=len(live)):
                     b = self._pick_bucket(len(live))
                     # a fresh buffer per batch: the async H2D of the
                     # previous dispatch may still be reading its buffer
@@ -879,33 +908,30 @@ class ServingEngine:
                                    self._image_dtype)
                     for i, r in enumerate(live):
                         buf[i] = r.image
-                self._mh["batch_form"].observe(sp_form.dur_s * 1e3)
                 now = time.monotonic()
                 for r in live:
                     self._tracer.record("serve:queue-wait",
                                         now - r.future.t_submit,
                                         ctx=(r.ctx.child() if r.ctx
                                              else None))
-                    self._mh["queue_wait"].observe(
-                        (now - r.future.t_submit) * 1e3)
                 try:
                     if self._injector is not None:
                         self._injector.fire("serve:dispatch", b=b)
-                    with self._tracer.span("serve:h2d", b=b,
-                                           links=blinks) as sp_h2d:
+                    with self._tracer.span("serve:h2d", b=b, links=blinks):
                         dev = (jax.device_put(buf, self._sharding)
                                if self._sharding is not None
                                else jax.device_put(buf))
-                    with self._tracer.span("serve:compute", b=b,
-                                           links=blinks) as sp_comp:
+                    # the call returns once the batch is enqueued: host
+                    # time. The device's time shows in the fetcher's
+                    # `serve:device-wait`.
+                    with self._tracer.span("serve:dispatch", b=b,
+                                           links=blinks):
                         out = self._compiled[b](self._variables, dev)
                 except Exception as e:  # noqa: BLE001 — requeue, serve on
                     self._requeue_or_fail(live, e, stage="dispatch", b=b)
                     with self._lock:
                         self._dispatch_busy = False
                     continue
-                self._mh["h2d"].observe(sp_h2d.dur_s * 1e3)
-                self._mh["compute"].observe(sp_comp.dur_s * 1e3)
                 with self._lock:
                     self._stats["batches"] += 1
                     self._stats["padded_slots"] += b - len(live)
@@ -930,23 +956,38 @@ class ServingEngine:
     # ---- fetcher ---------------------------------------------------------
 
     def _fetch(self, out, b: int):
-        """The batch D2H, under the hang watchdog when configured. The
-        fetch runs in a short-lived worker thread ONLY so a hang can be
-        abandoned (the thread is daemonic; a late result is discarded —
-        futures are first-wins); without a watchdog it runs inline."""
+        """Wait for the batch, then its D2H, under the hang watchdog when
+        configured: `serve:device-wait` (`block_until_ready`: until the
+        device has finished the batch) and `serve:d2h` (the `device_get`
+        of the finished Detections block alone). No extra sync: the fetch
+        blocked on the batch anyway. The pull runs in a short-lived worker
+        thread ONLY so a hang can be abandoned (the thread is daemonic; a
+        late result is discarded — futures are first-wins); without a
+        watchdog it runs inline. `(out, b)` is the whole signature (fault
+        tests wrap it); the spans' `n`/`links` come from `_fetch_meta`,
+        set by the fetch loop, this method's one caller."""
         import jax
+        n, links = self._fetch_meta
+
+        def pull():
+            with self._tracer.span("serve:device-wait", b=b, links=links):
+                if self._injector is not None:
+                    self._injector.fire("serve:fetch", b=b)
+                jax.block_until_ready(out)
+            with self._tracer.span("serve:d2h", b=b, n=n, links=links):
+                # the ONE sanctioned batched fetch (graftlint
+                # ast/device-get-in-serving-loop polices per-request
+                # fetches; this one D2H serves the whole batch)
+                return jax.device_get(out)
+
         if self._hang_timeout_s is None:
-            if self._injector is not None:
-                self._injector.fire("serve:fetch", b=b)
-            return jax.device_get(out)
+            return pull()
         box: Dict = {}
         done = threading.Event()
 
         def _d2h():
             try:
-                if self._injector is not None:
-                    self._injector.fire("serve:fetch", b=b)
-                box["v"] = jax.device_get(out)
+                box["v"] = pull()
             except BaseException as e:  # noqa: BLE001 — surfaced below
                 box["e"] = e
             finally:
@@ -976,33 +1017,40 @@ class ServingEngine:
                                 time.monotonic() - t_inq, b=b,
                                 links=flinks)
             try:
-                with self._tracer.span("serve:d2h", b=b, n=len(live),
-                                       links=flinks) as sp_d2h:
-                    # the ONE sanctioned batched fetch (graftlint
-                    # ast/device-get-in-serving-loop polices per-request
-                    # fetches; this one D2H serves the whole batch)
-                    host = self._fetch(out, b)
+                self._fetch_meta = (len(live), flinks)
+                host = self._fetch(out, b)
             except Exception as e:  # noqa: BLE001 — requeue, serve on
                 self._requeue_or_fail(live, e, stage="fetch", b=b)
                 with self._lock:
                     self._inflight_batches -= 1
                 continue
-            self._mh["d2h"].observe(sp_d2h.dur_s * 1e3)
             with self._lock:
                 self._stats["completed"] += len(live)
             self._mc["completed"].inc(len(live))
-            for i, r in enumerate(live):
-                # completion stamps come from the future itself (_set
-                # records t_done), so the e2e record is pure arithmetic
-                # over stored clocks — client-visible latency, not a
-                # device-timing claim (bench.py owns those)
-                r.future._set(type(host)(*(np.asarray(leaf[i])
-                                           for leaf in host)))
-                self._tracer.record(
-                    "serve:e2e", r.future.t_done - r.future.t_submit,
-                    ctx=self._req_ctx(r), b=b)
-                self._mh["e2e"].observe(
-                    (r.future.t_done - r.future.t_submit) * 1e3)
+            # The cyclic collector stays out of the delivery loop: the
+            # loop allocates a result per request, so a full collection
+            # (60-130 ms beside a process that keeps 10^4 futures: PERF.md
+            # section 6, PR 25) would otherwise start in the middle of it
+            # and hold the rest of the batch's answers that long. Deferred,
+            # it runs in the wait for the next batch.
+            collecting = gc.isenabled()
+            gc.disable()
+            try:
+                for i, r in enumerate(live):
+                    # completion stamps come from the future itself (_set
+                    # records t_done), so the e2e record is pure arithmetic
+                    # over stored clocks — client-visible latency, not a
+                    # device-timing claim (bench.py owns those)
+                    r.future._set(type(host)(*(np.asarray(leaf[i])
+                                               for leaf in host)))
+                    self._tracer.record(
+                        "serve:e2e", r.future.t_done - r.future.t_submit,
+                        ctx=self._req_ctx(r), b=b)
+                    self._mh_e2e.observe(
+                        (r.future.t_done - r.future.t_submit) * 1e3)
+            finally:
+                if collecting:
+                    gc.enable()
             with self._lock:
                 self._inflight_batches -= 1
                 inflight = self._inflight_batches

@@ -270,19 +270,29 @@ def loss_fn(params, batch_stats, model, images, gt_heat, gt_off, gt_wh, mask,
     kw = dict(hm_weight=cfg.hm_weight, offset_weight=cfg.offset_weight,
               size_weight=cfg.size_weight, focal_alpha=cfg.focal_alpha,
               focal_beta=cfg.focal_beta)
-    if resolve_loss_kernel(cfg) == "fused":
-        from .ops.pallas import fused_detection_loss
-        totals = fused_detection_loss(
-            out, gt_heat, gt_off, gt_wh, mask,
-            normalized_coord=cfg.normalized_coord, **kw)
-    else:
-        totals = stacked_detection_loss(
-            out, gt_heat, gt_off, gt_wh, mask, num_cls=cfg.num_cls,
-            normalized_coord=cfg.normalized_coord, **kw)
-    if distill is not None:
-        soft = distill.soft_losses(out, images, mask, cfg)
-        totals["distill"] = soft["total"]
-        totals["total"] = totals["total"] + distill.alpha * soft["total"]
+    # the scope names the layer in the compiled program's metadata
+    # (obs/hlo_scopes.py). It also keeps the loss kernels' device events
+    # under their own `name=`: XLA names an instruction after the LAST
+    # element of its op_name, and jax writes the transform it traces under
+    # around the outermost scope beneath it — with no scope here that was
+    # the kernel's own (`jvp(detection_loss_fwd)` -> `jvp_detection_loss_
+    # fwd_`), with it `jvp(loss)/detection_loss_fwd`, as the BN tails get
+    # `jvp(StackedHourglass)/.../bn_act_fwd` from flax's module scopes.
+    with jax.named_scope("loss"):
+        if resolve_loss_kernel(cfg) == "fused":
+            from .ops.pallas import fused_detection_loss
+            totals = fused_detection_loss(
+                out, gt_heat, gt_off, gt_wh, mask,
+                normalized_coord=cfg.normalized_coord, **kw)
+        else:
+            totals = stacked_detection_loss(
+                out, gt_heat, gt_off, gt_wh, mask, num_cls=cfg.num_cls,
+                normalized_coord=cfg.normalized_coord, **kw)
+        if distill is not None:
+            soft = distill.soft_losses(out, images, mask, cfg)
+            totals["distill"] = soft["total"]
+            totals["total"] = (totals["total"]
+                               + distill.alpha * soft["total"])
     return totals["total"], (mutated.get("batch_stats", batch_stats), totals)
 
 
@@ -310,18 +320,22 @@ def _optimizer_update(state: TrainState, tx, cfg: Config, grads,
     stream (when --ema-decay is on) + step counter. One implementation so
     the host, device-augment and cached input paths cannot drift."""
     from .optim import MasterOptimizer
-    if isinstance(tx, MasterOptimizer):
-        # --param-policy bf16-compute: the wrapper returns the new bf16
-        # params directly (params := bf16(updated fp32 master) — the cast
-        # fuses into the Adam pass; see optim.with_fp32_master)
-        params, opt_state = tx.update(grads, state.opt_state, state.params)
-    else:
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-    ema = state.ema_params
-    if cfg.ema_decay > 0 and ema is not None:
-        d = cfg.ema_decay
-        ema = jax.tree.map(lambda e, p: d * e + (1.0 - d) * p, ema, params)
+    with jax.named_scope("optimizer"):
+        if isinstance(tx, MasterOptimizer):
+            # --param-policy bf16-compute: the wrapper returns the new bf16
+            # params directly (params := bf16(updated fp32 master) — the
+            # cast fuses into the Adam pass; see optim.with_fp32_master)
+            params, opt_state = tx.update(grads, state.opt_state,
+                                          state.params)
+        else:
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+        ema = state.ema_params
+        if cfg.ema_decay > 0 and ema is not None:
+            d = cfg.ema_decay
+            ema = jax.tree.map(lambda e, p: d * e + (1.0 - d) * p, ema,
+                               params)
     return state.replace(step=state.step + 1, params=params,
                          batch_stats=batch_stats, opt_state=opt_state,
                          ema_params=ema)
@@ -664,7 +678,8 @@ def make_device_step_body(model, tx, cfg: Config, target: int,
             color_multiply=tuple(cfg.color_multiply),
             translate_percent=cfg.translate_percent,
             affine_scale=tuple(cfg.affine_scale))
-        img = (img / 255.0 - mean) / std
+        with jax.named_scope("normalize"):
+            img = (img / 255.0 - mean) / std
         return img, heat, off, wh, mask
 
     if not getattr(cfg, "sentinel", False):
@@ -1074,7 +1089,7 @@ def make_snapshot_fn(model, cfg: Config, mesh):
 
 
 def make_step_runner(cfg: Config, mesh, model, tx, cache=None,
-                     sentinel_scale=None, distill=None):
+                     sentinel_scale=None, distill=None, tracer=None):
     """Build `runner(state, batch, step_idx) -> (state, losses)` for the
     configured input path.
 
@@ -1099,6 +1114,17 @@ def make_step_runner(cfg: Config, mesh, model, tx, cache=None,
     The cached path has no stage (its per-step wire is a B-int32 vector).
     """
     from .data import StagedBatch
+    from .obs.spans import default_tracer
+    from .obs.telemetry import install_compile_listener
+
+    # flight recorder (obs/spans.py): `h2d` around every sharded transfer
+    # and `dispatch` around every call of the jitted step, into the
+    # process's ring whoever drives the runner (and into `tracer`'s span
+    # log when train() hands one in); a compile that lands in a step shows
+    # as a `compile` span beside it
+    if tracer is None:
+        tracer = default_tracer()
+    install_compile_listener()
 
     sentinel = bool(getattr(cfg, "sentinel", False))
     scale_of = sentinel_scale if sentinel_scale is not None else (lambda: 1.0)
@@ -1112,16 +1138,32 @@ def make_step_runner(cfg: Config, mesh, model, tx, cache=None,
         step = make_train_step(model, tx, cfg, mesh, distill=distill)
 
         def stage(batch):
-            return shard_batch(
-                mesh, (batch.image, batch.heatmap, batch.offset, batch.wh,
-                       batch.mask), spatial_dims=[1] * 5)
+            with tracer.span("h2d"):
+                return shard_batch(
+                    mesh, (batch.image, batch.heatmap, batch.offset,
+                           batch.wh, batch.mask), spatial_dims=[1] * 5)
 
         def runner(state, batch, step_idx):
             arrays = (batch.arrays if isinstance(batch, StagedBatch)
                       else stage(batch))
-            return step(state, *arrays, *scale_args())
+            with tracer.span("dispatch", step=step_idx):
+                return step(state, *arrays, *scale_args())
+
+        def scope_map(state, staged):
+            """HLO instruction -> layer of the compiled step
+            (obs/hlo_scopes.py), for reading a device trace by layer. On
+            request only, never on the step path: `lower().compile()`
+            traces and lowers again even where the executable is cached
+            (and a persistent-cache hit carries the scope names of the
+            commit that filled the entry: ServingEngine.scope_maps)."""
+            from .obs.hlo_scopes import scope_map as of_text
+            lowered = step.lower(state, *staged, *scale_args())
+            # read as text, never executed: no collective starts here
+            # graftlint: off=unbarriered-collective-start
+            return of_text(lowered.compile().as_text())
 
         runner.stage = stage
+        runner.scope_map = scope_map
         return runner
 
     sizes = (list(range(cfg.multiscale[0], cfg.multiscale[1],
@@ -1177,9 +1219,10 @@ def make_step_runner(cfg: Config, mesh, model, tx, cache=None,
             return steps[target]
 
         def runner(state, idx_batch, step_idx):
-            return get_step(pick_target(step_idx))(
-                state, base_key, np.int32(step_idx),
-                np.asarray(idx_batch, np.int32), *scale_args())
+            step = get_step(pick_target(step_idx))
+            with tracer.span("dispatch", step=step_idx):
+                return step(state, base_key, np.int32(step_idx),
+                            np.asarray(idx_batch, np.int32), *scale_args())
 
         runner.prewarm = lambda state: prewarm(
             state, lambda st, target: get_step(target)(
@@ -1195,16 +1238,18 @@ def make_step_runner(cfg: Config, mesh, model, tx, cache=None,
         return steps[target]
 
     def stage(batch):
-        return shard_batch(
-            mesh, (batch.image, batch.boxes, batch.labels, batch.valid))
+        with tracer.span("h2d"):
+            return shard_batch(
+                mesh, (batch.image, batch.boxes, batch.labels, batch.valid))
 
     def runner(state, batch, step_idx):
         arrays = (batch.arrays if isinstance(batch, StagedBatch)
                   else stage(batch))
         images, boxes, labels, valid = arrays
-        return get_step(pick_target(step_idx))(
-            state, base_key, np.int32(step_idx), images, boxes, labels,
-            valid, *scale_args())
+        step = get_step(pick_target(step_idx))
+        with tracer.span("dispatch", step=step_idx):
+            return step(state, base_key, np.int32(step_idx), images, boxes,
+                        labels, valid, *scale_args())
 
     def _dummy_call(st, target):
         canvas = cfg.multiscale[1]
@@ -1518,11 +1563,11 @@ def train_epoch(cfg: Config, epoch: int, loader: BatchLoader, step_runner,
                 chaos=None, mwriter=None, slo=None) -> TrainState:
     """One epoch of the hot loop (≡ ref train.py:86-162 `train_step`).
 
-    `tracer` (obs/spans.py, optional): when span tracing is enabled the
-    loop's phases land in the flight-recorder log — `loader-wait` (host
-    batch production), `step` (async dispatch + any un-hidden device
-    wait), `fetch` (the deferred loss flush, i.e. the real completion
-    barrier) and `h2d` (the prefetcher's sharded device_put) — so a slow
+    `tracer` (obs/spans.py; default the process's ring alone): the loop's
+    phases land in the flight recorder — `loader-wait` (host batch
+    production), `step` (async dispatch + any un-hidden device wait) and
+    `fetch` (the deferred loss flush, i.e. the real completion barrier);
+    the step runner adds `h2d` and `dispatch` from inside — so a slow
     epoch is attributable after the fact instead of folklore.
 
     `monitor` (`--sentinel`): consumes each flush window's fetched
@@ -1539,9 +1584,9 @@ def train_epoch(cfg: Config, epoch: int, loader: BatchLoader, step_runner,
     is host bookkeeping over ALREADY-measured values — the traced
     programs and the single-fetch D2H contract are untouched."""
     from .obs.metrics import default_registry
-    from .obs.spans import SpanTracer
+    from .obs.spans import default_tracer
     if tracer is None:
-        tracer = SpanTracer(None)  # disabled: wrap() is identity
+        tracer = default_tracer()  # the ring alone, no file
     mreg = default_registry()
     mh_step = mreg.histogram("train.step_ms")
     mh_wait = mreg.histogram("train.loader_wait_ms")
@@ -1594,8 +1639,8 @@ def train_epoch(cfg: Config, epoch: int, loader: BatchLoader, step_runner,
         # the next `device_prefetch` batches while the current step runs.
         # The cached input path has no stage (its wire is B int32 indices).
         from .data import DevicePrefetcher
-        iterator = DevicePrefetcher(loader,
-                                    tracer.wrap("h2d", step_runner.stage),
+        # (`stage` records its own `h2d` span: one record a transfer)
+        iterator = DevicePrefetcher(loader, step_runner.stage,
                                     depth=cfg.device_prefetch)
     from .data import StagedBatch
     tic = time.time()
@@ -1631,8 +1676,8 @@ def train_epoch(cfg: Config, epoch: int, loader: BatchLoader, step_runner,
             from .obs.trace import step_context
             sctx = step_context(epoch_base_step + i, epoch=epoch,
                                 rank=int(getattr(cfg, "rank", 0) or 0))
-            tracer.record("loader-wait", data_t, ctx=sctx.child(),
-                          epoch=epoch, it=i)
+        tracer.record("loader-wait", data_t,
+                      ctx=sctx.child() if sctx else None, epoch=epoch, it=i)
 
         if profile_this_epoch and is_chief and i == 2:
             # steps 0-1 include compiles; trace a few steady-state steps
@@ -1657,11 +1702,10 @@ def train_epoch(cfg: Config, epoch: int, loader: BatchLoader, step_runner,
             # drift on the same host wall the meter records; an alert is
             # an alert:train-step-drift event in the span log
             slo.observe("train.step_ms", step_t * 1e3)
-        if tracer.enabled:
-            # async-dispatch time (+ the flush barrier's device wait when
-            # this was a flush iteration) — same semantics as the meter
-            tracer.record("step", step_t, ctx=sctx.child(),
-                          epoch=epoch, it=i)
+        # async-dispatch time (+ the flush barrier's device wait when
+        # this was a flush iteration) — same semantics as the meter
+        tracer.record("step", step_t, ctx=sctx.child() if sctx else None,
+                      epoch=epoch, it=i)
 
         if profiling and i >= 7:
             flush_losses()  # completion barrier: the trace must contain
@@ -1805,15 +1849,19 @@ def train(cfg: Config, chaos=None) -> TrainState:
             print("%s: resumed from %s (epoch %d)"
                   % (timestamp(), cfg.model_load, ckpt_epoch), flush=True)
 
+    # Flight recorder (obs/): the in-memory ring is always on; a span log
+    # is written as well when --span-log names a path (or $OBS_SPAN_LOG is
+    # exported, e.g. by the job supervisor).
+    from .obs.spans import maybe_tracer
+    tracer = maybe_tracer(cfg.span_log or None)
     # --sentinel: the monitor is the host half of the self-healing loop;
-    # the runner reads its loss scale per call (tracer attached below,
-    # once the flight recorder exists)
+    # the runner reads its loss scale per call
     monitor = SentinelMonitor(cfg) if cfg.sentinel else None
     distill = make_distiller(cfg)
     runner = make_step_runner(
         cfg, mesh, model, tx, cache=cache,
         sentinel_scale=monitor.scale_value if monitor else None,
-        distill=distill)
+        distill=distill, tracer=tracer)
     if cfg.prewarm:
         if hasattr(runner, "prewarm"):
             if is_chief:
@@ -1861,12 +1909,8 @@ def train(cfg: Config, chaos=None) -> TrainState:
     # When running under scripts/tpu_queue.py the supervisor exports a
     # heartbeat path: the watchdog's beats double as the job's liveness
     # signal, so a wedged step trips the supervisor's kill-salvage too.
-    # Flight recorder (obs/): span tracing is on when --span-log names a
-    # path (or $OBS_SPAN_LOG is exported, e.g. by the job supervisor);
-    # disabled it costs nothing. The recompile counter turns "why was this
-    # epoch slow" answerable when a shape change silently retraced.
-    from .obs.spans import maybe_tracer
-    tracer = maybe_tracer(cfg.span_log or None)
+    # The recompile counter turns "why was this epoch slow" answerable
+    # when a shape change silently retraced.
     if monitor is not None and tracer.enabled:
         monitor._tracer = tracer  # recover:* events join the span log
     recompiles = None
@@ -2065,7 +2109,7 @@ def train(cfg: Config, chaos=None) -> TrainState:
                 runner = make_step_runner(
                     cfg, mesh, model, tx, cache=cache,
                     sentinel_scale=monitor.scale_value if monitor else None,
-                    distill=distill)
+                    distill=distill, tracer=tracer)
                 # only checkpoints written by THIS run are trusted: a
                 # reused save_path can hold a previous run's (possibly
                 # later-epoch) checkpoints, which would silently replace

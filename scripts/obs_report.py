@@ -171,7 +171,7 @@ def summarize_spans(paths: List[str]) -> Dict:
 def summarize_serving(paths: List[str]) -> Optional[Dict]:
     """The serving-engine section (ISSUE 8): p50/p99 joined from the
     engine's span taxonomy (serve:e2e per request, serve:queue-wait,
-    the serve:batch-form/h2d/compute/d2h stages, serve:shed events).
+    the serve:batch-form/h2d/dispatch/device-wait/d2h stages, serve:shed events).
     Returns None when the round recorded no serving activity."""
     e2e: List[float] = []
     qwait: List[float] = []
@@ -1133,8 +1133,9 @@ def selfcheck() -> int:
         for i in range(2):
             tracer.record("serve:batch-form", 0.001, n=2)
             tracer.record("serve:h2d", 0.001, b=2)
-            tracer.record("serve:compute", 0.0005, b=2)
-            tracer.record("serve:d2h", 0.008, b=2, n=2)
+            tracer.record("serve:dispatch", 0.0005, b=2)
+            tracer.record("serve:device-wait", 0.007, b=2)
+            tracer.record("serve:d2h", 0.001, b=2, n=2)
         tracer.event("serve:shed", reason="queue-full")
         # fault/recovery taxonomy (ISSUE 9): injections + what healed —
         # the Faults section's joins
@@ -1191,7 +1192,7 @@ def selfcheck() -> int:
         tr2 = trace_mod.new_root()
         tracer.record("serve:queue-wait", 0.004, ctx=tr1.child(), b=2)
         tracer.record("serve:queue-wait", 0.002, ctx=tr2.child(), b=2)
-        tracer.record("serve:compute", 0.006,
+        tracer.record("serve:dispatch", 0.006,
                       links=trace_mod.links_of([tr1, tr2]), b=2)
         tracer.event("fault:device-loss", site="serve:dispatch",
                      ctx=tr1.child())
@@ -1346,8 +1347,8 @@ def selfcheck() -> int:
         check("schema tagged", rep["schema"] == SCHEMA)
         sp = rep["spans"]
         check("torn span tail dropped, all real records read",
-              sp["records"] == 72)  # meta + 4 steps + ckpt + hb + ctx
-        # + 16 serve spans + shed event + 7 fault/recover events +
+              sp["records"] == 74)  # meta + 4 steps + ckpt + hb + ctx
+        # + 18 serve spans + shed event + 7 fault/recover events +
         # reload span + 2 alert events + 4 scale spans + 10 fleet events
         # + 10 trace-fixture records + 6 cascade records + 4 stream
         # records + frame-gap event + log2's meta + rank-1 step (both
@@ -1369,7 +1370,7 @@ def selfcheck() -> int:
               srv["e2e"]["p50_ms"] == 20.0 and srv["e2e"]["p99_ms"] == 40.0
               and srv["queue_wait"]["count"] == 8)
         check("serving stage digests + fill",
-              set(srv["stages"]) == {"batch-form", "h2d", "compute", "d2h"}
+              set(srv["stages"]) == {"batch-form", "h2d", "dispatch", "device-wait", "d2h"}
               and srv["mean_batch_fill"] == 2.0)
         flt = rep["faults"]
         check("faults section joined", flt is not None
@@ -1467,7 +1468,7 @@ def selfcheck() -> int:
               trc["waterfalls"]
               and trc["waterfalls"][0]["e2e_ms"] == 20.0
               and trc["waterfalls"][0]["critical_path"][
-                  "dominant_stage"] == "serve:compute"
+                  "dominant_stage"] == "serve:dispatch"
               and any(r["fan_in"] for r in
                       trc["waterfalls"][0]["waterfall"])
               and trc["events_in_traces"].get("fault:device-loss") == 1
@@ -1526,7 +1527,7 @@ def selfcheck() -> int:
               and "tenant penalty boxes: bulk ×1" in md)
         check("markdown carries traces section",
               "## Traces" in md and "HARD ERRORS" in md
-              and "dominant stage serve:compute" in md
+              and "dominant stage serve:dispatch" in md
               and "fleet:redispatch ×1" in md)
         check("markdown carries cascade subsection",
               "### Cascade" in md and "2 escalated (rate 66.7%)" in md

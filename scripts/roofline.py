@@ -15,7 +15,7 @@ into a roofline attributor: it compiles the production scanned train step
    through `calls=`d fused computations; elementwise/reduce ops counted at
    1 FLOP/element and labeled approximate),
 2. optionally executes the program under `jax.profiler` and joins the
-   device trace's per-op durations (trace_summary.op_durations) by exact
+   device trace's per-op durations (`op_durations` below) by exact
    instruction name,
 3. classifies every op against the v5e roofline: arithmetic intensity
    (FLOPs/byte) vs the ridge point peak_flops / hbm_bw (~241 FLOP/byte on
@@ -359,6 +359,41 @@ def attribute(comps, fusion_bodies, appliers):
                 row["src"] = src
             rows.append(row)
     return rows
+
+
+
+def find_traces(root: str):
+    """The Chrome trace-event JSON files (`*.trace.json[.gz]`) the
+    profiler wrote under `root`."""
+    out = []
+    for dirpath, _, files in os.walk(root):
+        out += [os.path.join(dirpath, f) for f in files
+                if f.endswith(".trace.json.gz") or f.endswith(".trace.json")]
+    return out
+
+
+def load_events(path: str):
+    import gzip
+    op = gzip.open if path.endswith(".gz") else open
+    with op(path, "rt") as f:
+        data = json.load(f)
+    return data.get("traceEvents", [])
+
+
+def op_durations(events):
+    """RAW-name per-op total durations: {name: [total_us, count]} — names
+    exactly as emitted (`fusion.123`, `convolution.1293`), so they join
+    the compiled HLO's instruction names. Only duration events
+    (ph == 'X') count; track attribution is dropped (the join is by
+    instruction name, which XLA keeps module-unique)."""
+    out = {}
+    for e in events:
+        if e.get("ph") != "X":
+            continue
+        rec = out.setdefault(e.get("name", ""), [0.0, 0])
+        rec[0] += float(e.get("dur", 0.0))
+        rec[1] += 1
+    return out
 
 
 def classify(rows, peak: float, hbm: float, durations=None, steps: int = 1):
@@ -883,8 +918,6 @@ def main() -> None:
     durations = None
     trace_note = "disabled (--no-trace)"
     if not args.no_trace:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from trace_summary import find_traces, load_events, op_durations
         import tempfile
         tdir = tempfile.mkdtemp(prefix="roofline_trace_")
         try:

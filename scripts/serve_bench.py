@@ -1708,9 +1708,9 @@ def selfcheck() -> int:
         spans = read_spans(span_path)
         names = {r.get("name") for r in spans}
         check("serve spans recorded",
-              {"serve:compile", "serve:batch-form", "serve:h2d",
-               "serve:compute", "serve:d2h", "serve:queue-wait",
-               "serve:e2e"} <= names)
+              {"serve:lower", "serve:compile", "serve:batch-form",
+               "serve:h2d", "serve:dispatch", "serve:device-wait",
+               "serve:d2h", "serve:queue-wait", "serve:e2e"} <= names)
         check("shed events recorded",
               sum(1 for r in spans if r.get("name") == "serve:shed") == 3)
 
@@ -1932,7 +1932,7 @@ def selfcheck() -> int:
               <= max(0.5 * cp["e2e_ms"], 40.0)
               and (cp["attributed_frac"] or 0) >= 0.5)
         check("traces: compute dominates the fixed-service exemplar",
-              cp.get("dominant_stage") == "serve:compute")
+              cp.get("dominant_stage") == "serve:dispatch")
 
         tpath2 = os.path.join(tmp, "trace_fleet.jsonl")
         ttr2 = maybe_tracer(tpath2)

@@ -175,9 +175,10 @@ def test_submit_validates_shape_and_dtype(parts, engine):
 
 
 def test_spans_cover_the_taxonomy(parts, tmp_path):
-    """The engine's flight-recorder contract: compile spans per bucket at
-    construction, then queue-wait/batch-form/h2d/compute/d2h per batch
-    and e2e per request ($OBS_SPAN_LOG honored via maybe_tracer)."""
+    """The engine's flight-recorder contract: lower + compile spans per
+    bucket at construction, then queue-wait/batch-form/h2d/dispatch/
+    device-wait/d2h per batch and e2e per request ($OBS_SPAN_LOG honored
+    via maybe_tracer)."""
     from real_time_helmet_detection_tpu.obs.spans import (maybe_tracer,
                                                           read_spans)
     _, predict, variables, pool, _ = parts
@@ -191,9 +192,10 @@ def test_spans_cover_the_taxonomy(parts, tmp_path):
     tracer.close()
     recs = read_spans(path)
     names = {r.get("name") for r in recs}
-    assert {"serve:compile", "serve:queue-wait", "serve:batch-form",
-            "serve:h2d", "serve:compute", "serve:d2h",
-            "serve:e2e"} <= names
+    assert {"serve:lower", "serve:compile", "serve:queue-wait",
+            "serve:batch-form", "serve:h2d", "serve:dispatch",
+            "serve:device-wait", "serve:d2h", "serve:e2e"} <= names
+    assert "serve:compute" not in names
     assert sum(1 for r in recs if r.get("name") == "serve:compile") == 2
     assert sum(1 for r in recs if r.get("name") == "serve:e2e") == 3
 

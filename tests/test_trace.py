@@ -173,11 +173,12 @@ def test_router_engine_batch_fanin_roundtrip(tmp_path):
         assert all(r["parent"] == f.ctx.span_id for r in t.records
                    if r.get("parent") is not None)
         linked_names = {r.get("name") for r in t.linked}
-        assert {"serve:compute", "serve:d2h"} <= linked_names
+        assert {"serve:dispatch", "serve:device-wait",
+                "serve:d2h"} <= linked_names
     # fan-in: the 4 requests were co-batched (paused fleet, bucket 4),
-    # so ONE compute span links all member traces
+    # so ONE dispatch span links all member traces
     computes = [r for t in traces.values() for r in t.linked
-                if r.get("name") == "serve:compute"]
+                if r.get("name") == "serve:dispatch"]
     assert any(len(r.get("links", [])) == 4 for r in computes)
 
 
