@@ -396,8 +396,8 @@ def audit_repo_entry_points(lower: bool = True) -> List[Finding]:
     fp32-master state restructure, ISSUE 7), under --block-fuse fused
     and --fwd-dtype int8 (the residual-tail custom_vjp pass and the STE
     int8 forward, ISSUE 20), the jitted predict fn
-    (eval), its --epilogue fused twin (the custom_vjp BN+activation
-    epilogue), its --block-fuse fused twin, the donating predict chain
+    (eval), its --epilogue fused twin (the plain eval tail XLA fuses
+    into the conv), its --block-fuse fused twin, the donating predict chain
     (bench), the quantized int8
     predict + its donating chain (--infer-dtype int8, ops/quant.py — the
     program tpu_sweep's int8 section times), the raw-uint8-wire predict
@@ -513,9 +513,9 @@ def audit_repo_entry_points(lower: bool = True) -> List[Finding]:
 
     try:
         # the fused-epilogue predict (--epilogue fused, ISSUE 7): the
-        # custom_vjp epilogue replaces every BN+activation tail — its
-        # trace must stay as clean as the plain predict (off-TPU this
-        # audits the jnp recompute twin, the same program roofline counts)
+        # fold-algebra eval tail (a plain expression, no kernel) replaces
+        # every BN+activation tail — its trace must stay as clean as the
+        # plain predict
         predict_e, variables_e, images_e = _tiny_predict_parts(
             epilogue="fused")
         findings += audit_entry(
@@ -569,9 +569,9 @@ def audit_repo_entry_points(lower: bool = True) -> List[Finding]:
                        (str(e).splitlines() or ["?"])[0][:200])))
 
     try:
-        # the block-fused predict (ISSUE 20): the eval-mode fused pass
-        # folds running stats into eff-scale/bias before the one-pass
-        # add+act — same cleanliness bar as predict_epilogue_fused
+        # the block-fused predict (ISSUE 20): the eval-mode tail folds
+        # running stats into eff-scale/bias before the add+act — same
+        # cleanliness bar as predict_epilogue_fused
         predict_b, variables_b, images_b = _tiny_predict_parts(
             block_fuse="fused")
         findings += audit_entry(

@@ -30,7 +30,7 @@ import flax.linen as nn
 
 from ..ops.pallas.epilogue import (FUSED_EPILOGUE_ACTIVATIONS, fused_bn_act,
                                    fused_bn_act_train)
-from ..ops.pallas.residual import fused_bn_add_act, fused_bn_add_act_train
+from ..ops.pallas.residual import fused_bn_add_act_train
 from ..ops.quant import (make_ste_conv, quantize_activations,
                          quantize_weights)
 
@@ -332,7 +332,9 @@ class STEConv(nn.Module):
 
 class FusedBNAct(nn.Module):
     """BatchNorm + activation with the normalize+activation chain collapsed
-    into ONE pointwise pass (ops/pallas/epilogue.py; `--epilogue fused`).
+    into ONE pointwise pass (ops/pallas/epilogue.py; `--epilogue fused`):
+    a Pallas custom_vjp family in the train step, a plain expression that
+    XLA fuses into the conv at eval.
 
     Param and batch_stats trees are IDENTICAL to
     `nn.BatchNorm(momentum=0.9, epsilon=1e-5)` and the block instantiates
@@ -345,9 +347,11 @@ class FusedBNAct(nn.Module):
     momentum running update); only the pointwise tail leaves it:
     `eff_scale = gamma * rsqrt(var + eps)`, `eff_bias = beta - mean *
     eff_scale` — the PR 5 BN-fold algebra (ops/quant.py) applied at
-    train time to the batch statistics and at eval time to the running
-    statistics — feed `fused_bn_act`, whose custom_vjp recomputes the
-    backward instead of saving post-BN residuals."""
+    train time to the batch statistics (`fused_bn_act_train`, whose
+    custom_vjp recomputes the backward instead of saving post-BN
+    residuals) and at eval time to the running statistics
+    (`fused_bn_act`: constants per channel, so no kernel and no
+    custom_vjp — selected by `train`, nothing else)."""
     activation: str = "Mish"
     momentum: float = 0.9
     epsilon: float = 1e-5
@@ -383,7 +387,8 @@ class FusedBNAct(nn.Module):
                 ra_var.value = m * ra_var.value + (1.0 - m) * var
             return out
         # eval: running statistics fold into the per-channel affine (the
-        # PR 5 fold algebra) feeding the one-pass pointwise epilogue
+        # PR 5 fold algebra); the tail is a plain pointwise expression
+        # that XLA fuses into the conv that produced x
         eff_scale = scale * jax.lax.rsqrt(ra_var.value + self.epsilon)
         eff_bias = bias - ra_mean.value * eff_scale
         return fused_bn_act(x, eff_scale, eff_bias,
@@ -405,7 +410,9 @@ class FusedBNAddAct(nn.Module):
     x ALONE — the skip never enters the statistics, exactly as in the
     unfused composition — and the custom_vjp's analytic backward carries
     the skip's pass-through gradient, so XLA never materializes the
-    normalized tensor, the sum, or backward-through-stats chains."""
+    normalized tensor, the sum, or backward-through-stats chains. At eval
+    (`train=False`) the tail is `fused_bn_act` with the skip: a plain
+    expression XLA fuses into the conv, as in `FusedBNAct`."""
     activation: str = "Mish"
     momentum: float = 0.9
     epsilon: float = 1e-5
@@ -436,8 +443,8 @@ class FusedBNAddAct(nn.Module):
             return out
         eff_scale = scale * jax.lax.rsqrt(ra_var.value + self.epsilon)
         eff_bias = bias - ra_mean.value * eff_scale
-        return fused_bn_add_act(x, eff_scale, eff_bias, skip,
-                                activation=self.activation)
+        return fused_bn_act(x, eff_scale, eff_bias, skip,
+                            activation=self.activation)
 
 
 class Convolution(nn.Module):

@@ -1,43 +1,48 @@
-"""Fused BatchNorm-normalize + activation epilogue (Pallas TPU kernel).
+"""BatchNorm + activation tail of every conv: Pallas kernels for training,
+a plain expression at eval.
 
 Every conv in this architecture is followed by `BatchNorm -> activation`
 (models/hourglass.py `Convolution`, ref /root/reference/hourglass.py:94-108
-`Convolution`: conv -> BN -> act). The r07 roofline byte table showed that
-chain — NOT the loss — is where the recoverable non-conv HBM traffic
-lives: the XLA lowering materializes f32<->bf16 converts around the
-normalize (the `convert_convert`/`convert_select` fusion rows, ~30% of
-step bytes under `--amp`), and autodiff saves post-BN intermediates
-(tanh/softplus/sigmoid values for Mish, compare masks for ReLU) in the
-forward to re-read in the backward.
+`Convolution`: conv -> BN -> act). Both modes fold the statistics into a
+per-channel affine, `eff_scale = gamma * rsqrt(var + eps)` and `eff_bias =
+beta - mean * eff_scale` (the BN-fold algebra of ops/quant.fold_batchnorm),
+and compute `act(x * eff_scale + eff_bias)` in f32.
 
-Here the whole post-reduction chain collapses into ONE pointwise pass per
-direction over the conv output:
+**Eval** (`fused_bn_act`): the running statistics make `eff_scale` /
+`eff_bias` constants, so the tail is a pointwise epilogue of the conv
+that produced x. It is a plain `jax.numpy` expression, never a
+`pallas_call` or a `custom_vjp`: XLA fuses it into the convolution's
+output, and no separate pass over HBM is made. A Pallas custom call there
+was a fusion barrier — the conv wrote x, the kernel read it and wrote it
+again, and XLA put whole-activation layout copies between the conv's
+layout and the kernel's (N, H*W, C) tiles (PERF.md section 6, PR 26).
 
-* the batch statistics (train) / running statistics (eval) stay in XLA —
-  they are reductions, not pointwise work — and are folded into
-  per-channel `eff_scale = gamma * rsqrt(var + eps)` and `eff_bias =
-  beta - mean * eff_scale` (exactly the PR 5 BN-fold algebra of
-  ops/quant.fold_batchnorm, reused at train time);
-* the forward kernel computes `act(x * eff_scale + eff_bias)` reading x
-  once and writing the activation once — all f32 math lives in
-  VMEM/registers, no materialized converts, no saved residuals;
-* a `jax.custom_vjp` backward RECOMPUTES the forward terms from the same
-  inputs (the ops/pallas/loss.py pattern) and emits d(x) in one pass plus
-  per-channel partial sums for d(eff_scale)/d(eff_bias) — tiny (C,)
-  vectors whose epilogue XLA folds into the BN-parameter gradients;
-* layout: `(N, H, W, C) -> (N, H*W, C)` is a FREE bitcast (adjacent
-  row-major dims); rows block over the sublane axis, channels sit on the
-  128-wide lane axis — C=128 (the flagship width) fills v5e tiles
-  exactly.
+**Train** (`fused_bn_act_train`): the batch statistics are a reduction
+over x, a real barrier, so the chain is one `jax.custom_vjp` family:
+
+* the forward computes batch moments in f32, then `act(x * eff_scale +
+  eff_bias)` reading x once and writing the activation once — all f32
+  math lives in VMEM/registers, no materialized converts, no saved
+  residuals;
+* the backward is the ANALYTIC BatchNorm+activation gradient
+  (`_make_fused_train`), recomputing the forward terms from the same
+  inputs (the ops/pallas/loss.py pattern): one pass for the per-channel
+  sums, one for d(x);
+* layout: `(N, H, W, C) -> (N, H*W, C)`; rows block over the sublane
+  axis, channels sit on the 128-wide lane axis — C=128 (the flagship
+  width) fills v5e tiles exactly. (The reshape is free in row-major
+  terms; on the chip XLA may still copy between a conv's layout and the
+  kernel's — measured at the stem's 256² tails, PERF.md section 7.)
 
 Off-TPU, `interpret=None` (the production default) selects a pure-jnp
-custom_vjp twin built from the SAME math helpers instead of Pallas
-interpret mode: identical semantics and identical recompute structure, so
-CPU tests run fast and scripts/roofline.py's operand+result counting model
-sees the real traffic shape of the fused path (the interpret lowering's
-dynamic-slice machinery would be counted as garbage — the same honesty
-problem loss_subprogram_cost solves analytically). Pass interpret=True to
-force the Pallas kernel in interpret mode (the parity tests do).
+custom_vjp twin of the train family built from the SAME math helpers
+instead of Pallas interpret mode: identical semantics and identical
+recompute structure, so CPU tests run fast and scripts/roofline.py's
+operand+result counting model sees the real traffic shape of the fused
+path (the interpret lowering's dynamic-slice machinery would be counted
+as garbage — the same honesty problem loss_subprogram_cost solves
+analytically). Pass interpret=True to force the Pallas kernel in
+interpret mode (the parity tests do).
 
 Selection is `--epilogue {auto,fused,xla}` (config.py), auto = fused on
 TPU only, mirroring `--loss-kernel`; eligibility rules live in
@@ -71,10 +76,10 @@ _BLOCK_ELEMS_CAP = 1024 * 128  # elements per row block: 512 KB in f32, so
 X, VEC, PART = "x", "vec", "part"  # operand kinds of `_rows_call`
 
 # Trace-time call-site registry (scripts/roofline.py's analytic counting
-# of the fused path off-TPU): every fused_bn_act/fused_bn_act_train call
-# appends (kind, elems, itemsize) while tracing. Appending is a pure
-# host-side side effect — the traced program (and so the graftlint
-# retrace signature) is unaffected.
+# of the fused train path off-TPU): every fused_bn_act_train call appends
+# (elems, itemsize) while tracing. Appending is a pure host-side side
+# effect — the traced program (and so the graftlint retrace signature) is
+# unaffected.
 _TRACE_SITES: list = []
 
 
@@ -83,22 +88,19 @@ def reset_site_registry() -> None:
 
 
 def traced_sites() -> list:
-    """[(kind 'train'|'eval', n_elements, itemsize_bytes), ...] of every
-    epilogue call traced since the last reset."""
+    """[(n_elements, itemsize_bytes), ...] of every train-mode epilogue
+    call traced since the last reset."""
     return list(_TRACE_SITES)
 
 
-def site_kernel_bytes(kind: str, elems: int, itemsize: int) -> float:
+def site_kernel_bytes(elems: int, itemsize: int) -> float:
     """Operand+result HBM bytes of the REAL kernel sequence for one
-    epilogue site (the same counting rule scripts/roofline.py applies to
-    every other op; C-sized vectors/partials are negligible and ignored).
-
-    train: stats pass reads x; fwd pass reads x, writes out; backward
-    sums pass reads (x, g); backward dx pass reads (x, g), writes dx
-    -> 8 activation-sized transfers. eval: the fwd pointwise pass only
-    -> 2 transfers."""
-    p = float(elems) * itemsize
-    return (8.0 if kind == "train" else 2.0) * p
+    train-mode epilogue site (the same counting rule scripts/roofline.py
+    applies to every other op; C-sized vectors/partials are negligible
+    and ignored): stats pass reads x; fwd pass reads x, writes out;
+    backward sums pass reads (x, g); backward dx pass reads (x, g),
+    writes dx -> 8 activation-sized transfers."""
+    return 8.0 * elems * itemsize
 
 
 def _act_fwd(z: jax.Array, act: str) -> jax.Array:
@@ -150,74 +152,6 @@ def _fwd_kernel(x_ref, a_ref, b_ref, o_ref, *, act: str):
     x = x_ref[...].astype(jnp.float32)        # (R, C)
     z = x * a_ref[...] + b_ref[...]           # (1, C) broadcasts over rows
     o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
-
-
-def _bwd_kernel(x_ref, a_ref, b_ref, g_ref, dx_ref, da_ref, db_ref, *,
-                act: str):
-    """Recompute z, emit dx in one pass + per-(sample, row-block) channel
-    partials for d(eff_scale)/d(eff_bias)."""
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...]
-    z = x * a + b_ref[...]
-    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[...] = (dz * a).astype(dx_ref.dtype)
-    da_ref[...] = _block_colsum(dz * x)             # (1, C)
-    db_ref[...] = _block_colsum(dz)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_fused(act: str, use_pallas: bool, interpret: bool):
-    """custom_vjp'd (x3 (N, R*, C), a (1, C) f32, b (1, C) f32) -> act(x*a+b).
-
-    Static knobs baked per cache entry (the ops/pallas/loss.py pattern) so
-    the custom_vjp function takes arrays only, and so the SAME function
-    object is reused across traces (retrace-stable, graftlint layer 1)."""
-
-    def jnp_fwd(x3, a2, b2):
-        z = x3.astype(jnp.float32) * a2 + b2
-        return _act_fwd(z, act).astype(x3.dtype)
-
-    def jnp_bwd(x3, a2, b2, g):
-        xf = x3.astype(jnp.float32)
-        z = xf * a2 + b2
-        dz = g.astype(jnp.float32) * _act_grad(z, act)
-        dx = (dz * a2).astype(x3.dtype)
-        da = jnp.sum(dz * xf, axis=(0, 1)).reshape(1, -1)
-        db = jnp.sum(dz, axis=(0, 1)).reshape(1, -1)
-        return dx, da, db
-
-    def pallas_fwd(x3, a2, b2):
-        return _rows_call(
-            functools.partial(_fwd_kernel, act=act), "bn_act_fwd",
-            [(X, x3), (VEC, a2), (VEC, b2)], [(X, x3.dtype)], interpret)
-
-    def pallas_bwd(x3, a2, b2, g):
-        dx, da_p, db_p = _rows_call(
-            functools.partial(_bwd_kernel, act=act), "bn_act_bwd",
-            [(X, x3), (VEC, a2), (VEC, b2), (X, g)],
-            [(X, x3.dtype), (PART, jnp.float32), (PART, jnp.float32)],
-            interpret)
-        # the per-block channel partials are tiny; their reduction is the
-        # epilogue's only XLA work in backward
-        return dx, _total(da_p).reshape(1, -1), _total(db_p).reshape(1, -1)
-
-    fwd_impl = pallas_fwd if use_pallas else jnp_fwd
-    bwd_impl = pallas_bwd if use_pallas else jnp_bwd
-
-    @jax.custom_vjp
-    def fused(x3, a2, b2):
-        return fwd_impl(x3, a2, b2)
-
-    def fused_fwd(x3, a2, b2):
-        # residuals are the ALREADY-materialized inputs — nothing extra
-        # crosses HBM for autodiff
-        return fwd_impl(x3, a2, b2), (x3, a2, b2)
-
-    def fused_bwd(res, g):
-        return bwd_impl(*res, g)
-
-    fused.defvjp(fused_fwd, fused_bwd)
-    return fused
 
 
 def _resolve_pallas(interpret: bool | None):
@@ -469,8 +403,11 @@ def fused_bn_act_train(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     consumed under `stop_gradient` (the backward treats their cotangents
     as structurally zero, exactly like flax BatchNorm's buffers).
 
-    Differentiable w.r.t. x, gamma, beta. `interpret` semantics match
-    `fused_bn_act`."""
+    Differentiable w.r.t. x, gamma, beta. interpret=None (production):
+    the Pallas kernels on TPU, the pure-jnp custom_vjp twin elsewhere
+    (same math, same recompute structure — see module docstring).
+    interpret=True/False forces the Pallas path in that mode (tests pin
+    kernel parity with interpret=True)."""
     if activation not in FUSED_EPILOGUE_ACTIVATIONS:
         raise NotImplementedError(
             "fused epilogue supports %s, got %r"
@@ -485,46 +422,24 @@ def fused_bn_act_train(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     x3 = x.reshape(lead, rows, c)
     g2 = gamma.astype(jnp.float32).reshape(1, c)
     b2 = beta.astype(jnp.float32).reshape(1, c)
-    _TRACE_SITES.append(("train", int(x.size),
-                         int(jnp.dtype(x.dtype).itemsize)))
+    _TRACE_SITES.append((int(x.size), int(jnp.dtype(x.dtype).itemsize)))
     fn = _make_fused_train(str(activation), float(eps), use_pallas, interp)
     out, mean, var = fn(x3, g2, b2)
     return out.reshape(x.shape), mean, var
 
 
 def fused_bn_act(x: jax.Array, eff_scale: jax.Array, eff_bias: jax.Array,
-                 *, activation: str = "Mish",
-                 interpret: bool | None = None) -> jax.Array:
-    """One-pass `act(x * eff_scale + eff_bias)` with a recompute backward.
+                 skip: jax.Array | None = None, *,
+                 activation: str = "Mish") -> jax.Array:
+    """Eval-mode BN tail `act(x * eff_scale + eff_bias [+ skip])`, f32
+    inside, x's dtype out — the arithmetic of `_fwd_kernel` /
+    residual.py's `_fwd_add_kernel`, as a plain expression that XLA fuses
+    into the convolution producing x (see module docstring).
 
-    x: (..., C) conv output (any float dtype; math is f32 internally);
-    eff_scale/eff_bias: (C,) — the BN-fold algebra's per-channel affine
-    (ops/quant.fold_batchnorm), from batch stats (train) or running stats
-    (eval). Differentiable w.r.t. all three.
-
-    interpret=None (production): the Pallas kernel on TPU, the pure-jnp
-    custom_vjp twin elsewhere (same math, same recompute structure — see
-    module docstring). interpret=True/False forces the Pallas path in
-    that mode (tests pin kernel parity with interpret=True).
-    """
-    if activation not in FUSED_EPILOGUE_ACTIVATIONS:
-        raise NotImplementedError(
-            "fused epilogue supports %s, got %r"
-            % (FUSED_EPILOGUE_ACTIVATIONS, activation))
-    c = x.shape[-1]
-    if eff_scale.shape != (c,) or eff_bias.shape != (c,):
-        raise ValueError(
-            "eff_scale/eff_bias must be (%d,), got %s/%s"
-            % (c, eff_scale.shape, eff_bias.shape))
-    use_pallas, interp = _resolve_pallas(interpret)
-    # (N, H, W, C) -> (N, H*W, C): merging adjacent row-major dims is a
-    # free bitcast, never an HBM copy
-    lead = x.shape[0] if x.ndim >= 3 else 1
-    rows = x.size // (lead * c)
-    x3 = x.reshape(lead, rows, c)
-    a2 = eff_scale.astype(jnp.float32).reshape(1, c)
-    b2 = eff_bias.astype(jnp.float32).reshape(1, c)
-    _TRACE_SITES.append(("eval", int(x.size),
-                         int(jnp.dtype(x.dtype).itemsize)))
-    fn = _make_fused(str(activation), use_pallas, interp)
-    return fn(x3, a2, b2).reshape(x.shape)
+    x: (..., C) conv output; eff_scale/eff_bias: (C,) — the BN-fold
+    algebra's per-channel affine from the running statistics; skip: the
+    residual block's other branch, same shape as x. Plain autodiff."""
+    z = x.astype(jnp.float32) * eff_scale + eff_bias
+    if skip is not None:
+        z = z + skip.astype(jnp.float32)
+    return _act_fwd(z, activation).astype(x.dtype)

@@ -1,18 +1,19 @@
-"""Fused residual-block tail: BatchNorm + skip-add + activation (Pallas).
+"""Fused residual-block tail for training: BatchNorm + skip-add +
+activation (Pallas).
 
 Every Residual block in this architecture ends with the same three-step
 tail (models/hourglass.py `Residual`, ref /root/reference/hourglass.py:
 111-131 `Residual`: body conv -> BN -> (+ skip) -> act): the body's last
 conv feeds a BatchNorm, the skip branch is ADDED, and Mish closes the
 block. The ISSUE-7 epilogue (ops/pallas/epilogue.py) already fused
-BN+act per conv, but the block tail still pays the skip-add round trip:
-XLA materializes the normalized tensor, re-reads it with the skip for
-the add, and re-reads the sum for the activation — with f32<->bf16
-converts between each under `--amp`. The r07+ rooflines put that
-per-block traffic (add/activation/convert rows) among the largest
-remaining non-conv byte movers.
+BN+act per conv, but in the train step the block tail still pays the
+skip-add round trip: XLA materializes the normalized tensor, re-reads it
+with the skip for the add, and re-reads the sum for the activation —
+with f32<->bf16 converts between each under `--amp`.
 
-Here the whole tail collapses into ONE pass family per direction:
+At eval the tail has no reduction and is `epilogue.fused_bn_act` with a
+`skip`: a plain expression XLA fuses into the conv. In the train step
+the whole tail collapses into ONE pass family per direction:
 
 * batch moments are of the BN INPUT y alone — the skip never enters the
   statistics (identical to the unfused composition, where BatchNorm sees
@@ -26,8 +27,8 @@ Here the whole tail collapses into ONE pass family per direction:
   and (dy, dgamma, dbeta) keep the exact S1/S2 channel-sum formulas
   (S1 = sum(dz), S2 = sum(dz*y)) — the add contributes no new
   statistics terms because it is affine in both operands;
-* layout is the epilogue's: (N, H, W, C) -> (N, H*W, C) free bitcast,
-  row blocks on the sublane axis, channels on the 128-wide lane axis.
+* layout is the epilogue's: (N, H, W, C) -> (N, H*W, C), row blocks on
+  the sublane axis, channels on the 128-wide lane axis.
 
 Off-TPU, `interpret=None` (the production default) selects a pure-jnp
 custom_vjp twin computing f32 end to end with the same Gram-dot
@@ -54,9 +55,8 @@ from .epilogue import (FUSED_EPILOGUE_ACTIVATIONS, PART, VEC, X, _act_fwd,
                        _act_grad, _block_colsum, _resolve_pallas,
                        _rows_call, _stats_kernel, _total)
 
-__all__ = ["FUSED_EPILOGUE_ACTIVATIONS", "fused_bn_add_act",
-           "fused_bn_add_act_train", "reset_site_registry",
-           "traced_sites", "site_kernel_bytes"]
+__all__ = ["FUSED_EPILOGUE_ACTIVATIONS", "fused_bn_add_act_train",
+           "reset_site_registry", "traced_sites", "site_kernel_bytes"]
 
 # Trace-time call-site registry, separate from the epilogue's so
 # scripts/roofline.py can substitute each kernel family at its own
@@ -70,43 +70,25 @@ def reset_site_registry() -> None:
 
 
 def traced_sites() -> list:
-    """[(kind 'train'|'eval', n_elements, itemsize_bytes), ...] of every
-    fused block-tail call traced since the last reset."""
+    """[(n_elements, itemsize_bytes), ...] of every train-mode fused
+    block-tail call traced since the last reset."""
     return list(_TRACE_SITES)
 
 
-def site_kernel_bytes(kind: str, elems: int, itemsize: int) -> float:
+def site_kernel_bytes(elems: int, itemsize: int) -> float:
     """Operand+result HBM bytes of the REAL kernel sequence for one
-    fused block-tail site (the roofline counting rule; C-sized
-    vectors/partials negligible).
-
-    train: stats pass reads y; fwd pass reads (y, skip), writes out;
-    backward sums pass reads (y, skip, g); backward dx pass reads
-    (y, skip, g), writes (dy, dskip) -> 12 activation-sized transfers.
-    eval: the fwd pass only -> 3 transfers."""
-    p = float(elems) * itemsize
-    return (12.0 if kind == "train" else 3.0) * p
+    train-mode fused block-tail site (the roofline counting rule; C-sized
+    vectors/partials negligible): stats pass reads y; fwd pass reads
+    (y, skip), writes out; backward sums pass reads (y, skip, g);
+    backward dx pass reads (y, skip, g), writes (dy, dskip) -> 12
+    activation-sized transfers."""
+    return 12.0 * elems * itemsize
 
 
 def _fwd_add_kernel(x_ref, a_ref, b_ref, s_ref, o_ref, *, act: str):
     x = x_ref[...].astype(jnp.float32)        # (R, C)
     z = x * a_ref[...] + b_ref[...] + s_ref[...].astype(jnp.float32)
     o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
-
-
-def _bwd_add_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, dx_ref, ds_ref,
-                    da_ref, db_ref, *, act: str):
-    """Eval backward: recompute z from (y, skip), emit (dy, dskip) in one
-    pass + per-(sample, row-block) channel partials for d(eff_scale)/
-    d(eff_bias)."""
-    x = x_ref[...].astype(jnp.float32)
-    a = a_ref[...]
-    z = x * a + b_ref[...] + s_ref[...].astype(jnp.float32)
-    dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
-    dx_ref[...] = (dz * a).astype(dx_ref.dtype)
-    ds_ref[...] = dz.astype(ds_ref.dtype)
-    da_ref[...] = _block_colsum(dz * x)       # (1, C)
-    db_ref[...] = _block_colsum(dz)
 
 
 def _bwd_add_sums_kernel(x_ref, a_ref, b_ref, s_ref, g_ref, s1_ref,
@@ -143,62 +125,6 @@ def _inner_cols(m2, n2):
     gram = jax.lax.dot_general(m2, n2, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
     return jnp.diagonal(gram)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_fused_add(act: str, use_pallas: bool, interpret: bool):
-    """custom_vjp'd eval tail (y3 (N, R, C), a (1, C) f32, b (1, C) f32,
-    s3 (N, R, C)) -> act(y*a + b + s).
-
-    Static knobs baked per cache entry so the SAME function object is
-    reused across traces (retrace-stable, graftlint layer 1)."""
-
-    def jnp_fwd(x3, a2, b2, s3):
-        z = x3.astype(jnp.float32) * a2 + b2 + s3.astype(jnp.float32)
-        return _act_fwd(z, act).astype(x3.dtype)
-
-    def jnp_bwd(x3, a2, b2, s3, g):
-        xf = x3.astype(jnp.float32)
-        z = xf * a2 + b2 + s3.astype(jnp.float32)
-        dz = g.astype(jnp.float32) * _act_grad(z, act)
-        dx = (dz * a2).astype(x3.dtype)
-        ds = dz.astype(s3.dtype)
-        da = jnp.sum(dz * xf, axis=(0, 1)).reshape(1, -1)
-        db = jnp.sum(dz, axis=(0, 1)).reshape(1, -1)
-        return dx, da, db, ds
-
-    def pallas_fwd(x3, a2, b2, s3):
-        return _rows_call(
-            functools.partial(_fwd_add_kernel, act=act), "bn_add_act_fwd",
-            [(X, x3), (VEC, a2), (VEC, b2), (X, s3)], [(X, x3.dtype)],
-            interpret)
-
-    def pallas_bwd(x3, a2, b2, s3, g):
-        dx, ds, da_p, db_p = _rows_call(
-            functools.partial(_bwd_add_kernel, act=act), "bn_add_act_bwd",
-            [(X, x3), (VEC, a2), (VEC, b2), (X, s3), (X, g)],
-            [(X, x3.dtype), (X, s3.dtype), (PART, jnp.float32),
-             (PART, jnp.float32)], interpret)
-        return dx, _total(da_p).reshape(1, -1), \
-            _total(db_p).reshape(1, -1), ds
-
-    fwd_impl = pallas_fwd if use_pallas else jnp_fwd
-    bwd_impl = pallas_bwd if use_pallas else jnp_bwd
-
-    @jax.custom_vjp
-    def fused(x3, a2, b2, s3):
-        return fwd_impl(x3, a2, b2, s3)
-
-    def fused_fwd(x3, a2, b2, s3):
-        # residuals are the ALREADY-materialized inputs — nothing extra
-        # crosses HBM for autodiff
-        return fwd_impl(x3, a2, b2, s3), (x3, a2, b2, s3)
-
-    def fused_bwd(res, g):
-        return bwd_impl(*res, g)
-
-    fused.defvjp(fused_fwd, fused_bwd)
-    return fused
 
 
 @functools.lru_cache(maxsize=None)
@@ -345,8 +271,9 @@ def _prep(x, skip, gamma, beta):
     if skip.shape != x.shape:
         raise ValueError("skip must match the BN input shape %s, got %s"
                          % (x.shape, skip.shape))
-    # (N, H, W, C) -> (N, H*W, C): merging adjacent row-major dims is a
-    # free bitcast, never an HBM copy
+    # (N, H, W, C) -> (N, H*W, C) merges adjacent row-major dims; whether
+    # the chip copies between the conv's layout and this one is XLA's
+    # choice (PERF.md section 7)
     lead = x.shape[0] if x.ndim >= 3 else 1
     rows = x.size // (lead * c)
     return (x.reshape(lead, rows, c), skip.reshape(lead, rows, c),
@@ -366,44 +293,15 @@ def fused_bn_add_act_train(x: jax.Array, gamma: jax.Array,
     `stop_gradient`.
 
     Differentiable w.r.t. x, gamma, beta AND skip. `interpret` semantics
-    match `fused_bn_add_act`."""
+    match `epilogue.fused_bn_act_train`."""
     if activation not in FUSED_EPILOGUE_ACTIVATIONS:
         raise NotImplementedError(
             "fused block tail supports %s, got %r"
             % (FUSED_EPILOGUE_ACTIVATIONS, activation))
     use_pallas, interp = _resolve_pallas(interpret)
     x3, s3, g2, b2 = _prep(x, skip, gamma, beta)
-    _TRACE_SITES.append(("train", int(x.size),
-                         int(jnp.dtype(x.dtype).itemsize)))
+    _TRACE_SITES.append((int(x.size), int(jnp.dtype(x.dtype).itemsize)))
     fn = _make_fused_add_train(str(activation), float(eps), use_pallas,
                                interp)
     out, mean, var = fn(x3, g2, b2, s3)
     return out.reshape(x.shape), mean, var
-
-
-def fused_bn_add_act(x: jax.Array, eff_scale: jax.Array,
-                     eff_bias: jax.Array, skip: jax.Array, *,
-                     activation: str = "Mish",
-                     interpret: bool | None = None) -> jax.Array:
-    """One-pass `act(x * eff_scale + eff_bias + skip)` with a recompute
-    backward.
-
-    x: (..., C) the block body's last conv output; skip: same shape (the
-    identity or 1x1-projected branch); eff_scale/eff_bias: (C,) — the
-    BN-fold algebra's per-channel affine, from batch stats (train) or
-    running stats (eval). Differentiable w.r.t. all four.
-
-    interpret=None (production): the Pallas kernel on TPU, the pure-jnp
-    custom_vjp twin elsewhere (same math, same recompute structure — see
-    module docstring). interpret=True/False forces the Pallas path in
-    that mode (tests pin kernel parity with interpret=True)."""
-    if activation not in FUSED_EPILOGUE_ACTIVATIONS:
-        raise NotImplementedError(
-            "fused block tail supports %s, got %r"
-            % (FUSED_EPILOGUE_ACTIVATIONS, activation))
-    use_pallas, interp = _resolve_pallas(interpret)
-    x3, s3, a2, b2 = _prep(x, skip, eff_scale, eff_bias)
-    _TRACE_SITES.append(("eval", int(x.size),
-                         int(jnp.dtype(x.dtype).itemsize)))
-    fn = _make_fused_add(str(activation), use_pallas, interp)
-    return fn(x3, a2, b2, s3).reshape(x.shape)

@@ -867,8 +867,8 @@ def _selfcheck_trace(check) -> None:
 
     # the ISSUE-7 entry points: the bf16 param-policy scanned step (fp32
     # master inside the optimizer state — the donation surface every
-    # mistake class loves) and the fused-epilogue predict (custom_vjp
-    # epilogue in every conv tail) must audit clean like the surfaces
+    # mistake class loves) and the fused-epilogue predict (the fold-algebra
+    # eval tail in every conv tail) must audit clean like the surfaces
     # they replace — donation/f64/dynamic-shape included (full audit_entry
     # incl. lowering)
     # the serve bucket set (ISSUE 8): every bucket the engine AOT-compiles

@@ -16,6 +16,8 @@ leaves under `--out`:
     <cell>.window.json   {"window_ns": [a, b], "window_s", "steps"|"images",
                           "compile_spans_in_window"}
     <cell>.layers.txt    scripts/trace_summary.py's table over that window
+    <cell>.layers.json   the same summary whole: seconds of every layer and
+                         of every instruction, not the table's top 40
 
     python scripts/layer_trace.py --workload flagship-train-b32 --seed 7 \
         --seconds 20 --out chiprun_out/layers
@@ -114,6 +116,7 @@ def record(workload: str, seed: int, seconds: float, out: str,
             tuple(note["window_ns"]) if "window_ns" in note else None)
         text = trace_summary.render(summary, scopes[program], top=40)
         atomic_write_bytes(base + ".layers.txt", (text + "\n").encode())
+        save_json(base + ".layers.json", summary)
         print(text)
     save_json(base + ".window.json", note)
     shutil.rmtree(trace_dir, ignore_errors=True)

@@ -484,8 +484,7 @@ def build_step(jax, args, loss_kernel: str):
     arrs = tuple(jnp.asarray(a) for a in synthetic_target_batch(
         args.batch, args.imsize, pos_rate=0.01))
     train_n = make_scanned_train_fn(body, args.steps)
-    # site registries: capture ONLY the timed program's fused-kernel
-    # calls (model.init above also traces the module, in eval mode) —
+    # site registries: the timed program's train-mode fused-kernel calls —
     # epilogue.py's BN+act tails and residual.py's BN+add+act tails each
     # keep their own registry (different per-site transfer counts)
     from real_time_helmet_detection_tpu.ops.pallas import epilogue as _epi
@@ -599,9 +598,9 @@ def substitute_epilogue_analytic(rows, sites, residual_sites=()):
     model's documented basis for Pallas paths), each twin's rows —
     identified by their HLO `source_file` metadata — are replaced by the
     REAL kernel sequence's operand+result bytes per traced call site
-    (`epilogue.site_kernel_bytes`: train = 8 activation-sized transfers,
-    eval = 2; `residual.site_kernel_bytes`: train = 12, eval = 3 — the
-    skip tensor rides every pass). Twin rows whose fusion roots carry
+    (`epilogue.site_kernel_bytes`: 8 activation-sized transfers a train
+    tail; `residual.site_kernel_bytes`: 12 — the skip tensor rides every
+    pass). Twin rows whose fusion roots carry
     other source metadata stay counted (conservative: overcounts the
     candidate). Returns (rows, info|None); info rides in the artifact as
     `epilogue_counting` — aggregate fields keep the r09 shape, and
@@ -622,7 +621,7 @@ def substitute_epilogue_analytic(rows, sites, residual_sites=()):
         if not twin or not fam_sites:
             continue
         kept = [r for r in kept if r.get("src") != src_name]
-        for i, (kind, elems, itemsize) in enumerate(fam_sites):
+        for i, (elems, itemsize) in enumerate(fam_sites):
             kept.append({
                 "name": "%s.%d" % (label, i), "opcode": "custom-call",
                 "class": "elementwise", "src": src_name,
@@ -630,12 +629,12 @@ def substitute_epilogue_analytic(rows, sites, residual_sites=()):
                 # recompute; +skip add for residual); byte-bound either way
                 "flops": (22.0 if src_name == "residual.py" else 20.0)
                          * elems,
-                "bytes": kernel_bytes(kind, elems, itemsize)})
+                "bytes": kernel_bytes(elems, itemsize)})
         per_family[label] = {
             "twin_rows_dropped": len(twin),
             "twin_rows_bytes": sum(r["bytes"] for r in twin),
             "kernel_bytes_analytic": sum(
-                kernel_bytes(k, e, s) for k, e, s in fam_sites),
+                kernel_bytes(e, s) for e, s in fam_sites),
             "sites": len(fam_sites)}
     if not per_family:
         return rows, None

@@ -3,10 +3,12 @@
 Three layers of parity, mirroring the fused-epilogue suite
 (tests/test_epilogue.py):
 
-* kernel level — `fused_bn_add_act_train` / `fused_bn_add_act` (jnp
-  twin AND Pallas interpret) against the plain XLA chain
-  BN(x) -> +skip -> act, forward AND grads (w.r.t. x, scale, bias AND
-  the skip's pass-through), fp32 and bf16;
+* kernel level — `fused_bn_add_act_train` (jnp twin AND Pallas
+  interpret) against the plain XLA chain BN(x) -> +skip -> act, forward
+  AND grads (w.r.t. x, scale, bias AND the skip's pass-through), fp32
+  and bf16; the eval tail (`FusedBNAddAct` at `train=False`: a plain
+  expression XLA fuses into the conv, PR 26) against nn.BatchNorm ->
+  +skip -> Activation on the same variables;
 * model level — `--block-fuse fused` vs `xla` on the full hourglass
   for BOTH eligible variants (residual, depthwise): identical
   param/stat trees (checkpoints interchange), allclose logits/grads;
@@ -26,11 +28,12 @@ import pytest
 from real_time_helmet_detection_tpu.config import Config
 from real_time_helmet_detection_tpu.models import build_model
 from real_time_helmet_detection_tpu.models.hourglass import (
-    resolve_block_fuse)
+    FusedBNAddAct, resolve_block_fuse)
 from real_time_helmet_detection_tpu.ops.pallas.epilogue import (
-    FUSED_EPILOGUE_ACTIVATIONS, _act_fwd)
+    FUSED_EPILOGUE_ACTIVATIONS, _act_fwd, fused_bn_act)
 from real_time_helmet_detection_tpu.ops.pallas.residual import (
-    fused_bn_add_act, fused_bn_add_act_train)
+    fused_bn_add_act_train)
+from test_epilogue import assert_tail_parity, bn_variables, xla_eval_tail
 
 IMSIZE = 64
 EPS = 1e-5
@@ -56,11 +59,6 @@ def _ref_train_chain(x, gamma, beta, skip, act):
     b = beta - mean * a
     z = xf * a + b + skip.astype(jnp.float32)
     return _act_fwd(z, act).astype(x.dtype), mean, var
-
-
-def _ref_eval_chain(x, a, b, skip, act):
-    z = (x.astype(jnp.float32) * a + b + skip.astype(jnp.float32))
-    return _act_fwd(z, act).astype(x.dtype)
 
 
 def _rand_args(dt, seed=0):
@@ -133,50 +131,26 @@ def test_train_kernel_fwd_grad_parity(act, dt):
 @pytest.mark.parametrize("act", FUSED_EPILOGUE_ACTIVATIONS)
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
 def test_eval_kernel_fwd_grad_parity(act, dt):
-    """fused_bn_add_act (eval tail, folded affine) vs act(x*a+b+skip):
-    forward + grads w.r.t. all four operands."""
-    x, a, b, skip = _rand_args(dt, seed=1)
-
-    def loss_of(fn):
-        return lambda x, a, b, s: jnp.sum(
-            fn(x, a, b, s).astype(jnp.float32) ** 2)
-
-    ref = lambda x, a, b, s: _ref_eval_chain(x, a, b, s, act)  # noqa: E731
-    fused = lambda x, a, b, s: fused_bn_add_act(  # noqa: E731
-        x, a, b, s, activation=act)
-    pallas = lambda x, a, b, s: fused_bn_add_act(  # noqa: E731
-        x, a, b, s, activation=act, interpret=True)
-
-    ftol = 1e-5 if dt == jnp.float32 else 3e-2
-    np.testing.assert_allclose(
-        np.asarray(ref(x, a, b, skip), np.float32),
-        np.asarray(fused(x, a, b, skip), np.float32),
-        atol=ftol, rtol=ftol)
-    np.testing.assert_allclose(
-        np.asarray(fused(x, a, b, skip), np.float32),
-        np.asarray(pallas(x, a, b, skip), np.float32),
-        rtol=1e-5, atol=1e-5)
-
-    g_ref = jax.grad(loss_of(ref), argnums=(0, 1, 2, 3))(x, a, b, skip)
-    g_f = jax.grad(loss_of(fused), argnums=(0, 1, 2, 3))(x, a, b, skip)
-    g_p = jax.grad(loss_of(pallas), argnums=(0, 1, 2, 3))(x, a, b, skip)
-    gtol = 1e-4 if dt == jnp.float32 else 1.5e-1
-    ptol = 1e-4 if dt == jnp.float32 else 1e-2
-    for r, f, p, name in zip(g_ref, g_f, g_p,
-                             ("x", "scale", "bias", "skip")):
-        np.testing.assert_allclose(
-            np.asarray(r, np.float32), np.asarray(f, np.float32),
-            rtol=gtol, atol=gtol, err_msg="%s vs ref" % name)
-        np.testing.assert_allclose(
-            np.asarray(f, np.float32), np.asarray(p, np.float32),
-            rtol=ptol, atol=ptol, err_msg="%s pallas vs jnp" % name)
+    """The eval block tail (no kernel since PR 26: `FusedBNAddAct` at
+    train=False is the plain `fused_bn_act` expression with a skip) vs
+    nn.BatchNorm -> +skip -> Activation on the same variables: forward +
+    grads w.r.t. (x, bias, scale, skip)."""
+    x, _, _, skip = _rand_args(dt, seed=1)
+    variables = bn_variables(np.random.default_rng(1))
+    stats = {"batch_stats": variables["batch_stats"]}
+    module = FusedBNAddAct(activation=act, dtype=dt)
+    assert_tail_parity(
+        lambda x, p, s: xla_eval_tail({"params": p, **stats}, x, act, dt,
+                                      skip=s),
+        lambda x, p, s: module.apply({"params": p, **stats}, x, s,
+                                     train=False),
+        (x, variables["params"], skip), ("x", "bias", "scale", "skip"), dt)
 
 
 def test_kernel_rejects_unsupported_activation_and_shapes():
     x = jnp.zeros((1, 4, 4, 8))
     with pytest.raises(NotImplementedError):
-        fused_bn_add_act(x, jnp.ones(8), jnp.zeros(8), x,
-                         activation="CELU")
+        fused_bn_act(x, jnp.ones(8), jnp.zeros(8), x, activation="CELU")
     with pytest.raises(ValueError, match="skip"):
         fused_bn_add_act_train(x, jnp.ones(8), jnp.zeros(8),
                                jnp.zeros((1, 4, 4, 4)))

@@ -3,10 +3,13 @@
 Three layers of parity, mirroring the fused-loss suite
 (tests/test_pallas_loss.py):
 
-* kernel level — `fused_bn_act`'s Pallas (interpret-mode) path and its
-  jnp custom_vjp twin against the plain XLA chain `act(x*a+b)`, forward
-  AND grads (w.r.t. x, scale, bias), fp32 and bf16, every supported
-  activation;
+* tail level — the eval tail (`FusedBNAct` at `train=False`: the plain
+  `fused_bn_act` expression XLA fuses into the conv, PR 26) against the
+  `nn.BatchNorm(use_running_average=True)` -> `Activation` chain on the
+  SAME variables, forward AND grads (w.r.t. x, scale, bias), fp32 and
+  bf16, every supported activation; and that an eval-mode model holds no
+  `pallas_call` and no `custom_vjp_call` while the train-mode model keeps
+  every one it had;
 * model level — `--epilogue fused` vs `--epilogue xla` on the full
   hourglass: identical param/stat trees (checkpoints interchange),
   allclose logits/grads/batch-stats at fp32 and bf16;
@@ -15,6 +18,9 @@ Three layers of parity, mirroring the fused-loss suite
   untouched by the epilogue refactor.
 """
 
+import collections
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,9 +28,11 @@ import pytest
 
 from real_time_helmet_detection_tpu.config import Config
 from real_time_helmet_detection_tpu.models import build_model
-from real_time_helmet_detection_tpu.models.hourglass import resolve_epilogue
+from real_time_helmet_detection_tpu.models.hourglass import (
+    Activation, FusedBNAct, resolve_epilogue)
+from real_time_helmet_detection_tpu.ops.pallas import epilogue, residual
 from real_time_helmet_detection_tpu.ops.pallas.epilogue import (
-    FUSED_EPILOGUE_ACTIVATIONS, _act_fwd, fused_bn_act)
+    FUSED_EPILOGUE_ACTIVATIONS, fused_bn_act)
 
 IMSIZE = 64
 
@@ -35,55 +43,119 @@ def tiny_cfg(**kw):
     return Config(**base)
 
 
-def _ref_chain(x, a, b, act):
-    return _act_fwd(x.astype(jnp.float32) * a + b, act).astype(x.dtype)
+def bn_variables(rng, c=16):
+    """One BatchNorm's variables with non-trivial running statistics; the
+    tree `nn.BatchNorm`, `FusedBNAct` and `FusedBNAddAct` all share."""
+    f32 = lambda a: jnp.asarray(a.astype(np.float32))  # noqa: E731
+    return {"params": {"scale": f32(rng.standard_normal(c) * 0.5 + 1),
+                       "bias": f32(rng.standard_normal(c))},
+            "batch_stats": {"mean": f32(rng.standard_normal(c) * 0.3),
+                            "var": f32(rng.uniform(0.5, 2.0, c))}}
+
+
+def xla_eval_tail(variables, x, act, dt, skip=None):
+    """The `--epilogue xla` / `--block-fuse xla` eval chain of
+    `Convolution` / `Residual`: nn.BatchNorm on running statistics,
+    (+ skip), Activation."""
+    y = nn.BatchNorm(use_running_average=True, momentum=0.9, epsilon=1e-5,
+                     dtype=dt).apply(variables, x)
+    if skip is not None:
+        y = y + skip
+    return Activation(act).apply({}, y)
+
+
+def assert_tail_parity(ref, tail, operands, names, dt):
+    """Forward and sum-of-squares grads of `tail` against `ref` over
+    `operands`. fp32 tolerance is op-reordering ULPs (the fold algebra
+    reassociates the normalize); bf16 is the format's quantum — the XLA
+    chain rounds to bf16 after the normalize and after the add, the tail
+    once at its end."""
+    ftol = 1e-5 if dt == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(ref(*operands), np.float32),
+                               np.asarray(tail(*operands), np.float32),
+                               atol=ftol, rtol=ftol)
+
+    def loss_of(fn):
+        return lambda *ops: jnp.sum(fn(*ops).astype(jnp.float32) ** 2)
+
+    argnums = tuple(range(len(operands)))
+    g_ref = jax.tree.leaves(jax.grad(loss_of(ref), argnums)(*operands))
+    g_tail = jax.tree.leaves(jax.grad(loss_of(tail), argnums)(*operands))
+    gtol = 1e-4 if dt == jnp.float32 else 1.5e-1
+    assert len(g_ref) == len(g_tail) == len(names)
+    for r, t, name in zip(g_ref, g_tail, names):
+        np.testing.assert_allclose(
+            np.asarray(r, np.float32), np.asarray(t, np.float32),
+            rtol=gtol, atol=gtol, err_msg="%s vs ref" % name)
 
 
 @pytest.mark.parametrize("act", FUSED_EPILOGUE_ACTIVATIONS)
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
 def test_kernel_fwd_grad_parity(act, dt):
-    """fused_bn_act (jnp twin AND Pallas interpret) vs the XLA chain:
-    forward + grads w.r.t. (x, scale, bias). fp32 tolerance is
-    op-reordering ULPs; bf16 is the format's quantum."""
+    """The eval BN+act tail (no kernel since PR 26: `FusedBNAct` at
+    train=False is the plain `fused_bn_act` expression) vs the
+    nn.BatchNorm -> Activation chain on the same variables: forward +
+    grads w.r.t. (x, bias, scale)."""
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, 8, 8, 16)) * 2, dt)
-    a = jnp.asarray((rng.standard_normal(16) * 0.5 + 1).astype(np.float32))
-    b = jnp.asarray(rng.standard_normal(16).astype(np.float32))
-
-    def loss_of(fn):
-        return lambda x, a, b: jnp.sum(
-            fn(x, a, b).astype(jnp.float32) ** 2)
-
-    fused = lambda x, a, b: fused_bn_act(x, a, b, activation=act)  # noqa: E731
-    pallas = lambda x, a, b: fused_bn_act(  # noqa: E731
-        x, a, b, activation=act, interpret=True)
-
-    ftol = 1e-5 if dt == jnp.float32 else 3e-2
-    o_ref = np.asarray(_ref_chain(x, a, b, act), np.float32)
-    o_f = np.asarray(fused(x, a, b), np.float32)
-    o_p = np.asarray(pallas(x, a, b), np.float32)
-    np.testing.assert_allclose(o_ref, o_f, atol=ftol, rtol=ftol)
-    # the two fused implementations share the same math helpers: ULPs only
-    np.testing.assert_allclose(o_f, o_p, rtol=1e-5, atol=1e-5)
-
-    g_ref = jax.grad(loss_of(lambda *ar: _ref_chain(*ar, act)),
-                     argnums=(0, 1, 2))(x, a, b)
-    g_f = jax.grad(loss_of(fused), argnums=(0, 1, 2))(x, a, b)
-    g_p = jax.grad(loss_of(pallas), argnums=(0, 1, 2))(x, a, b)
-    gtol = 1e-4 if dt == jnp.float32 else 1.5e-1
-    for r, f, p, name in zip(g_ref, g_f, g_p, ("x", "scale", "bias")):
-        np.testing.assert_allclose(
-            np.asarray(r, np.float32), np.asarray(f, np.float32),
-            rtol=gtol, atol=gtol, err_msg="%s vs ref" % name)
-        np.testing.assert_allclose(
-            np.asarray(f, np.float32), np.asarray(p, np.float32),
-            rtol=1e-4, atol=1e-4, err_msg="%s pallas vs jnp" % name)
+    variables = bn_variables(rng)
+    stats = {"batch_stats": variables["batch_stats"]}
+    module = FusedBNAct(activation=act, dtype=dt)
+    assert_tail_parity(
+        lambda x, p: xla_eval_tail({"params": p, **stats}, x, act, dt),
+        lambda x, p: module.apply({"params": p, **stats}, x, train=False),
+        (x, variables["params"]), ("x", "bias", "scale"), dt)
 
 
 def test_kernel_rejects_unsupported_activation():
     x = jnp.zeros((1, 4, 4, 8))
     with pytest.raises(NotImplementedError):
         fused_bn_act(x, jnp.ones(8), jnp.zeros(8), activation="CELU")
+
+
+def count_primitives(jaxpr, acc=None):
+    """Counter of primitive names over a jaxpr and every jaxpr nested in
+    its equations' parameters."""
+    acc = collections.Counter() if acc is None else acc
+    for eqn in jaxpr.eqns:
+        acc[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    count_primitives(sub, acc)
+    return acc
+
+
+# (custom_vjp_call, pallas_call) in the train-mode network, counted on the
+# parent of PR 26: one custom_vjp a BN tail, two kernels (stats, fwd) in it
+@pytest.mark.parametrize("fields,train_counts", [
+    (dict(num_stack=1, hourglass_inch=128), (37, 74)),   # the flagship
+    (dict(num_stack=2, hourglass_inch=16), (67, 134)),   # two-stack toy
+], ids=["flagship", "two-stack"])
+def test_eval_has_no_kernel_train_keeps_every_one(monkeypatch, fields,
+                                                  train_counts):
+    """Selection is by `train` alone: with both levers `fused` and the
+    kernels selected as on the chip, the jaxpr of an eval-mode apply
+    holds no `pallas_call` and no `custom_vjp_call` (XLA gets plain
+    pointwise tails it fuses into the convs), and the train-mode jaxpr
+    holds exactly what it held before."""
+    on_chip = lambda interpret: (True, False)  # noqa: E731
+    monkeypatch.setattr(epilogue, "_resolve_pallas", on_chip)
+    monkeypatch.setattr(residual, "_resolve_pallas", on_chip)
+    model = build_model(tiny_cfg(epilogue="fused", block_fuse="fused",
+                                 **fields), dtype=jnp.bfloat16)
+    x = jnp.zeros((2, IMSIZE, IMSIZE, 3), jnp.float32)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), x, train=False))
+    found = {}
+    for train in (False, True):
+        jaxpr = jax.make_jaxpr(lambda v, x: model.apply(
+            v, x, train=train, mutable=["batch_stats"]))(variables, x)
+        prims = count_primitives(jaxpr.jaxpr)
+        found[train] = (prims["custom_vjp_call"], prims["pallas_call"])
+    assert found[False] == (0, 0)
+    assert found[True] == train_counts
 
 
 def test_resolve_epilogue_auto_is_xla_off_tpu():
