@@ -3,4 +3,4 @@ from benchmark.metrics_lib import bn_tail_roofline
 
 
 def read(rec):
-    return bn_tail_roofline(rec, rec.window.get("images"), train=True)
+    return bn_tail_roofline(rec, rec.window.get("images"))

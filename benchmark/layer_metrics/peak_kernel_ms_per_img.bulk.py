@@ -1,8 +1,2 @@
-"""Device milliseconds per image answered in the `peak_scores` Pallas kernel (sigmoid + 3x3 peak test). `in`, not `==`: the kernel runs under vmap and batch_parallel, and jax may decorate such names. The reference has no such metric."""
-from benchmark.metrics_lib import kernel_ms
-
-
-def read(rec):
-    ms, images = kernel_ms(rec, lambda n: "peak_scores" in n), \
-        rec.window.get("images")
-    return ms / images if ms and images else None
+"""Device milliseconds per image answered in the `peak_scores` Pallas kernel (sigmoid + 3x3 peak test). The reference has no such metric."""
+from benchmark.metrics_lib import peak_kernel_ms_per_image as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""Device milliseconds per image answered in the `peak_scores` Pallas kernel (sigmoid + 3x3 peak test). In the cell whose pace the host sets (`serve_img_per_s.hostbound`): `peak_kernel_ms_per_img.bulk` read there. The reference has no such metric."""
+from benchmark.metrics_lib import peak_kernel_ms_per_image as read  # noqa: F401

@@ -66,16 +66,16 @@ def kernel_ms(rec, match) -> Optional[float]:
     return float(np.sum(hits)) if hits else None
 
 
-def bn_tail_roofline(rec, images: Optional[float], train: bool
-                     ) -> Optional[float]:
-    """Share of the HBM roofline the BN-tail kernels reached, %: the bytes
-    every BatchNorm(+add)+activation tail has to move (work/count.py) at the
-    chip's bandwidth, over the device time of those kernels. Bound by
-    bandwidth: a tail does a handful of operations per byte."""
+def bn_tail_roofline(rec, images: Optional[float]) -> Optional[float]:
+    """Share of the HBM roofline the train step's BN-tail kernels reached, %:
+    the bytes every BatchNorm(+add)+activation tail has to move forward and
+    backward (work/count.py) at the chip's bandwidth, over the device time of
+    those kernels. Bound by bandwidth: a tail does a handful of operations
+    per byte."""
     ms = kernel_ms(rec, is_bn_tail_kernel)
     if not ms or not images or not rec.peaks:
         return None
-    need = count.bn_tail_bytes_per_image(rec.config, _imsize(rec), train)
+    need = count.bn_tail_bytes_per_image(rec.config, _imsize(rec))
     least_s = need * images / rec.peaks["hbm_bytes_per_s"]
     return 100.0 * least_s / (ms / 1e3)
 
@@ -92,6 +92,26 @@ def batch_fill(rec) -> Optional[float]:
 def engine_span_percentile_ms(rec, name: str, q: float) -> Optional[float]:
     values = [d for n, d in rec.engine_spans if n == name]
     return 1e3 * float(np.percentile(values, q)) if values else None
+
+
+DISPATCHER_STAGES = ("serve:batch-form", "serve:h2d", "serve:dispatch")
+
+
+def dispatcher_ms_per_batch(rec) -> Optional[float]:
+    """The dispatcher thread's serial host time a batch: the medians of the
+    engine's own spans of its three stages, summed."""
+    medians = [engine_span_percentile_ms(rec, name, 50)
+               for name in DISPATCHER_STAGES]
+    return None if None in medians else sum(medians)
+
+
+def peak_kernel_ms_per_image(rec) -> Optional[float]:
+    """Device milliseconds an image answered in the `peak_scores` Pallas
+    kernel. `in`, not `==`: the kernel runs under vmap and batch_parallel, and
+    jax may decorate such names."""
+    ms = kernel_ms(rec, lambda n: "peak_scores" in n)
+    images = rec.window.get("images")
+    return ms / images if ms and images else None
 
 
 def window_percentile(rec, key: str, q: float) -> Optional[float]:

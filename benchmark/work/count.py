@@ -14,23 +14,24 @@ count as 0, so an `mfu` here is slightly under the true share, never over):
   d image). Recomputed operations (`--remat`, a kernel's recompute backward)
   do not count.
 
-Bytes of a BatchNorm(+skip add)+activation tail, in activation-sized transfers
-of the compute dtype (bfloat16, 2 bytes). An activation here (>= 4 MB a row
-block at the sizes served) cannot stay on the chip between passes, and batch
-statistics must be complete before anything is normalized, so the least the
-algorithm can move is:
+Bytes of a train-mode BatchNorm(+skip add)+activation tail, in
+activation-sized transfers of the compute dtype (bfloat16, 2 bytes). An
+activation here (>= 4 MB a row block at the sizes trained) cannot stay on the
+chip between passes, and batch statistics must be complete before anything is
+normalized, so the least the algorithm can move is:
 
-* eval (running statistics): read x, write y = 2; with a skip, read it too = 3;
-* train forward: read x for the moments, read x again to normalize, write y
-  = 3; with a skip = 4;
-* train backward: one pass for the two channel sums (read dy and x; the
+* forward: read x for the moments, read x again to normalize, write y = 3;
+  with a skip = 4;
+* backward: one pass for the two channel sums (read dy and x; the
   activation's mask is recomputed from x, and from the skip where there is
   one), one pass for dx (read dy and x again, write dx) = 5; with a skip, read
   it in both passes and write its gradient = 8.
 
-These totals (8 and 12 per training tail, 2 and 3 at eval) happen to equal
-what the program's fused kernels move (`epilogue.site_kernel_bytes`): they
-were built to this minimum. The per-channel vectors are left out (< 0.01%).
+These totals (8 and 12 per tail) happen to equal what the program's fused
+kernels move (`epilogue.site_kernel_bytes`): they were built to this minimum.
+The per-channel vectors are left out (< 0.01%). An eval tail is not counted:
+with running statistics it is a per-channel affine that XLA fuses into the
+convolution, a pass the program does not make.
 
 (The reference has no benchmark: nothing of this directory has an analogue
 there.)
@@ -58,13 +59,11 @@ def conv_flops_per_image(cfg: dict, imsize: int, train: bool) -> float:
     return total
 
 
-def bn_tail_transfers(add: bool, train: bool) -> int:
-    if not train:
-        return 3 if add else 2
+def bn_tail_transfers(add: bool) -> int:
     return 12 if add else 8
 
 
-def bn_tail_bytes_per_image(cfg: dict, imsize: int, train: bool) -> float:
+def bn_tail_bytes_per_image(cfg: dict, imsize: int) -> float:
     _, tails = layer_shapes(cfg, imsize)
     return float(sum(math.prod(t["shape"]) * ACT_BYTES
-                     * bn_tail_transfers(t["add"], train) for t in tails))
+                     * bn_tail_transfers(t["add"]) for t in tails))

@@ -2,15 +2,20 @@
 that a configuration, a cell and a per-layer metric are added by adding files
 and BENCHMARK.json entries, with no edit to a file of benchmark/.
 
-The toy configurations keep every field of the real ones but the width (16),
-the resolution (64) and bfloat16 (off: the CPU suite compares float32 with
-float32); the traffic keeps every field but the sizes."""
+What "toy size" means is each data file's own business: a configuration's
+`toy` block overrides its `fields`, a traffic mix's `toy` block its
+parameters (a nested group such as `engine` key by key). `make_root` knows no
+field, family or traffic name, so a file a later PR adds is shrunk by the
+block it brings; a file without one is an error that names the file. (The one
+cell named here, `add_live_cell`, is the entries of a cell whose files are in
+benchmark/ and whose entries are not in BENCHMARK.json yet.)"""
 
 import json
 import os
 import shutil
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+DATA_DIRS = ("configs", "workloads", "layer_metrics", "sources")
 
 TOY_METRIC = '''"""A metric a later PR might add: steps or requests attempted per second."""
 
@@ -18,16 +23,6 @@ TOY_METRIC = '''"""A metric a later PR might add: steps or requests attempted pe
 def read(rec):
     return rec.window["attempted"] / rec.window["window_s"]
 '''
-
-SIZES = {
-    "train-b32": dict(batch=4, pool_batches=2, fetch_every=2),
-    "serve-bulk": dict(pool_frames=8, sample=4, lead_in_requests=8,
-                       stats_frames=4),
-    "serve-bulk-soft": dict(pool_frames=8, sample=4, lead_in_requests=8,
-                            stats_frames=4),
-    "serve-live": dict(pool_frames=8, sample=4, stats_frames=4,
-                       rate_per_s=20.0, burst=[1, 3]),
-}
 
 
 LIVE = "quality-serve-live"  # the cell PERF.md section 7 lists first: its
@@ -54,35 +49,61 @@ def add_live_cell(manifest: dict) -> None:
             "moves": "serve_p95_ms", "workloads": [LIVE]})
 
 
-def make_root(tmp: str) -> str:
-    """Copy nothing but data: the manifest (plus one new cell with its
-    metrics, plus one new metric with its reader), toy copies of the
-    configurations and mixes, and the metric readers."""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+def copy_data(src: str, dst: str) -> dict:
+    """`src`'s manifest and data files (no code) copied to `dst` as they are;
+    returns the manifest. What a later PR's tree starts from."""
+    with open(os.path.join(src, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    add_live_cell(manifest)
+    for name in DATA_DIRS:
+        shutil.copytree(os.path.join(src, "benchmark", name),
+                        os.path.join(dst, "benchmark", name),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(src, "BENCHMARK.json"), dst)
+    return manifest
+
+
+def _overlay(base: dict, over: dict) -> dict:
+    out = dict(base)
+    for key, value in over.items():
+        both = isinstance(value, dict) and isinstance(base.get(key), dict)
+        out[key] = _overlay(base[key], value) if both else value
+    return out
+
+
+def _toy(path: str) -> tuple:
+    with open(path) as f:
+        data = json.load(f)
+    if "toy" not in data:
+        raise ValueError('%s has no "toy" block: the sizes a CPU test runs '
+                         "it at belong in the file itself" % path)
+    return data, data.pop("toy")
+
+
+def make_root(tmp: str, src: str = REPO) -> str:
+    """Copy nothing but data from `src`: the manifest (plus one cell added by
+    entries alone with metrics of its own, plus one new metric with its
+    reader), each configuration and mix under its own `toy` block, and the
+    metric readers."""
+    with open(os.path.join(src, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    if not any(c["name"] == LIVE for c in manifest["workloads"]):
+        add_live_cell(manifest)  # a tree that has the cell keeps its own
     bench = os.path.join(tmp, "benchmark")
     os.makedirs(os.path.join(bench, "configs"))
     os.makedirs(os.path.join(bench, "workloads"))
-    shutil.copytree(os.path.join(REPO, "benchmark", "layer_metrics"),
+    shutil.copytree(os.path.join(src, "benchmark", "layer_metrics"),
                     os.path.join(bench, "layer_metrics"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     for conf in manifest["configs"]:
-        with open(os.path.join(REPO, conf["file"])) as f:
-            config = json.load(f)
-        config["fields"].update(hourglass_inch=16, imsize=64, amp=False)
+        config, toy = _toy(os.path.join(src, conf["file"]))
+        config["fields"] = _overlay(config["fields"], toy)
         with open(os.path.join(tmp, conf["file"]), "w") as f:
             json.dump(config, f)
-    for cell in manifest["workloads"]:
-        name = cell["traffic"]
-        with open(os.path.join(REPO, "benchmark", "workloads",
-                               name + ".json")) as f:
-            mix = json.load(f)
-        mix.update(SIZES[name])
-        if "engine" in mix:
-            mix["engine"].update(buckets=[2, 4], queue=16)
+    for name in sorted({cell["traffic"] for cell in manifest["workloads"]}):
+        mix, toy = _toy(os.path.join(src, "benchmark", "workloads",
+                                     name + ".json"))
         with open(os.path.join(bench, "workloads", name + ".json"), "w") as f:
-            json.dump(mix, f)
+            json.dump(_overlay(mix, toy), f)
     with open(os.path.join(bench, "layer_metrics", "toy_attempts_per_s.py"),
               "w") as f:
         f.write(TOY_METRIC)
@@ -93,3 +114,11 @@ def make_root(tmp: str) -> str:
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f)
     return tmp
+
+
+def toy_fields(config: str) -> dict:
+    """The `fields` of one of the repo's configurations under its `toy`
+    block."""
+    data, toy = _toy(os.path.join(REPO, "benchmark", "configs",
+                                  config + ".json"))
+    return _overlay(data["fields"], toy)

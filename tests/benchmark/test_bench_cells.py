@@ -110,7 +110,7 @@ def _altered_answer(cell):
 ])
 def test_a_broken_timed_path_is_not_correct(root, name, fault, caught_by):
     line = _cell(root, name, sabotage=fault)
-    assert line["correct"] is False
+    assert line["correct"] is False, line["checked"]
     c = line["checked"][caught_by]
     assert c["value"] > c["limit"], line["checked"]
 

@@ -20,12 +20,17 @@ SPAN_METRICS = {
     "flagship-train-b32": {"train_dispatch_ms_per_step",
                            "train_h2d_ms_per_step", "setup_trace_lower_s",
                            "setup_backend_compile_s"},
-    "flagship-serve-bulk": {"engine_dispatch_ms_per_batch.bulk",
-                            "engine_device_wait_ms_per_batch.bulk",
+    "flagship-serve-bulk": {"engine_dispatch_ms_per_batch.hostbound",
+                            "engine_device_wait_ms_per_batch.hostbound",
                             "setup_trace_lower_s",
                             "setup_backend_compile_s"},
+    "quality-serve-bulk": {"engine_dispatch_ms_per_batch.bulk",
+                           "engine_device_wait_ms_per_batch.bulk",
+                           "setup_trace_lower_s",
+                           "setup_backend_compile_s"},
 }
-TRACE_METRICS = {"peak_kernel_ms_per_img.bulk", "loss_kernel_ms_per_step"}
+TRACE_METRICS = {"peak_kernel_ms_per_img.bulk", "peak_kernel_ms_per_img.hostbound",
+                 "loss_kernel_ms_per_step"}
 
 
 @pytest.fixture(scope="module")
@@ -33,13 +38,13 @@ def root(tmp_path_factory):
     return bench_toy.make_root(str(tmp_path_factory.mktemp("bench_root")))
 
 
-def test_the_manifest_declares_the_new_metrics_at_the_end():
+def test_the_manifest_declares_the_new_metrics():
     manifest = bench_run.load_manifest(bench_toy.REPO)
-    names = [m["name"] for m in manifest["per_layer"]]
-    new = set().union(*SPAN_METRICS.values()) | TRACE_METRICS
-    assert set(names[-len(new):]) == new
-    for m in manifest["per_layer"][-len(new):]:
-        assert (m["source"] == "device_trace") == (m["name"] in TRACE_METRICS)
+    source = {m["name"]: m["source"] for m in manifest["per_layer"]}
+    for name in set().union(*SPAN_METRICS.values()):
+        assert source[name] == "program_span"
+    for name in TRACE_METRICS:
+        assert source[name] == "device_trace"
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_METRICS))
@@ -80,6 +85,27 @@ def test_kernel_readers_match_names_jax_may_decorate():
     assert _reader("loss_kernel_ms_per_step")(rec) is None
     rec.trace["op_ms"] = {"bn_act_fwd": 9.0}
     assert _reader("peak_kernel_ms_per_img.bulk")(rec) is None
+
+
+@pytest.mark.parametrize("quantity", [
+    "engine_batch_fill", "engine_device_wait_ms_per_batch",
+    "engine_dispatch_ms_per_batch", "peak_kernel_ms_per_img",
+    "predict_device_ms_per_img", "predict_mfu"])
+def test_a_hostbound_twin_reads_what_its_bulk_sibling_reads(quantity):
+    """`<quantity>.hostbound` is `<quantity>.bulk` in the cell that reports
+    `serve_img_per_s.hostbound`: one arithmetic under two names."""
+    rec = types.SimpleNamespace(
+        config=bench_toy.toy_fields("flagship-s1-w128"),
+        e2e={"serve_img_per_s": 1000.0, "setup_s": 30.0},
+        peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        trace={"busy_s": 15.0, "op_ms": {"peak_scores": 6.0}},
+        window={"images": 300, "counters": {"batch_slots": 320,
+                                            "padded_slots": 20}},
+        engine_spans=[("serve:batch-form", 0.01), ("serve:h2d", 0.002),
+                      ("serve:dispatch", 0.001), ("serve:device-wait", 0.25)])
+    value = _reader(quantity + ".hostbound")(rec)
+    assert value is not None and value > 0
+    assert value == _reader(quantity + ".bulk")(rec)
 
 
 def test_span_readers_return_none_where_there_is_nothing_to_read():

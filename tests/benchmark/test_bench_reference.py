@@ -23,10 +23,10 @@ from benchmark.work import count  # noqa: E402
 REPO = bench_toy.REPO
 
 
-def _fields(config, **over):
+def _fields(config):
     with open(os.path.join(REPO, "benchmark", "configs",
                            config + ".json")) as f:
-        return dict(json.load(f)["fields"], **over)
+        return json.load(f)["fields"]
 
 
 def _limits(mix):
@@ -45,7 +45,7 @@ def test_serving_control_is_not_correct(config, mix):
     itself at every limit; computed in fp8 they fail."""
     import jax
     import jax.numpy as jnp
-    cfg = _fields(config, hourglass_inch=16, imsize=64)
+    cfg = bench_toy.toy_fields(config)
     spec = ref.param_spec(cfg)
     frames = traffic.frame_pool(3, 4, 64)
     weights = wts.with_running_statistics(
@@ -68,7 +68,7 @@ def test_serving_control_is_not_correct(config, mix):
 def test_training_control_and_half_batch_are_not_correct():
     import jax
     import jax.numpy as jnp
-    cfg = _fields("flagship-s1-w128", hourglass_inch=16, imsize=64)
+    cfg = bench_toy.toy_fields("flagship-s1-w128")
     spec = ref.param_spec(cfg)
     weights = wts.make_weights(spec, 5)
     batches = [tuple(jnp.asarray(a) for a in (b.image, b.heatmap, b.offset,
@@ -193,10 +193,22 @@ def test_analytic_flops_at_the_published_sizes(config, fwd, train):
     cfg = _fields(config)
     assert abs(count.conv_flops_per_image(cfg, 512, False) / 1e9 - fwd) < 0.01
     assert abs(count.conv_flops_per_image(cfg, 512, True) / 1e9 - train) < 0.01
-    assert count.bn_tail_transfers(add=True, train=True) == 12
-    assert count.bn_tail_transfers(add=False, train=False) == 2
-    assert count.bn_tail_bytes_per_image(cfg, 512, True) == \
-        4 * count.bn_tail_bytes_per_image(cfg, 512, False)
+
+
+def test_bn_tail_bytes_against_a_hand_count_of_the_flagships_tails():
+    """The train step's 37 tails (PERF.md section 3): 8 bf16 activation
+    transfers a tail without a skip, 12 with one. Per level, elements of one
+    activation x (tails without, tails with a skip): the stem at 256^2 (one
+    64-channel tail, two 128-channel ones, one with the skip), 128^2 (5, 4),
+    and the hourglass's four levels 64^2 .. 8^2 (3, 3 each)."""
+    assert count.bn_tail_transfers(add=False) == 8
+    assert count.bn_tail_transfers(add=True) == 12
+    plain = 256 * 256 * 64 + 2 * 256 * 256 * 128 + 5 * 128 * 128 * 128 \
+        + 3 * 128 * (64 * 64 + 32 * 32 + 16 * 16 + 8 * 8)
+    skip = 256 * 256 * 128 + 4 * 128 * 128 * 128 \
+        + 3 * 128 * (64 * 64 + 32 * 32 + 16 * 16 + 8 * 8)
+    assert count.bn_tail_bytes_per_image(_fields("flagship-s1-w128"), 512) \
+        == 2 * (8 * plain + 12 * skip) == 989528064
 
 
 # ---- the trace reduction ----------------------------------------------------------
