@@ -34,7 +34,7 @@ import math
 import os
 import random
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -55,6 +55,9 @@ ARCHITECTURE_FIELDS = (
 # models/hourglass.py consumes this vocabulary; defined here (stdlib-only
 # module) so config validation never imports the model stack.
 MODEL_VARIANTS = ("residual", "depthwise", "ghost")
+# model families `models.build_model` dispatches on: the reference's stacked
+# hourglass, and the decoder of models/decoder.py (no reference analogue)
+MODEL_FAMILIES = ("hourglass", "latent_moe_decoder")
 
 # Latency-tier presets (ISSUE 13): named architecture+serving bundles —
 # the product tiers the fleet router mixes per tenant. `--tier edge`
@@ -88,6 +91,16 @@ TIER_PRESETS = {
 @dataclass
 class Config:
     """All flags. Field name -> CLI flag: underscores become dashes."""
+
+    # model family (ROADMAP D11/D13: one switch and one mapping, not a flat
+    # field per size of every family)
+    family: str = "hourglass"     # MODEL_FAMILIES
+    decoder: Dict[str, Any] = field(default_factory=dict)  # family
+    # "latent_moe_decoder": the source's own config.json keys as they stand
+    # (models/decoder.py `DecoderSpec.from_mapping` names them), plus
+    # `ep_size` / `ep_rank` (how many chips share an expert layer, which
+    # share this is; `n_routed_experts` and `vocab_size` then count what is
+    # held HERE). Not a CLI flag: a mapping comes from a file.
 
     # device
     num_devices: int = 0          # 0 = use every visible device
@@ -518,6 +531,9 @@ class Config:
         if self.preset not in ("", "sweep-best"):
             raise ValueError("--preset must be '' or 'sweep-best', got %r"
                              % (self.preset,))
+        if self.family not in MODEL_FAMILIES:
+            raise ValueError("--family must be one of %s, got %r"
+                             % (MODEL_FAMILIES, self.family))
         if self.variant not in MODEL_VARIANTS:
             raise ValueError("--variant must be one of %s, got %r"
                              % (MODEL_VARIANTS, self.variant))
@@ -617,6 +633,8 @@ def build_parser() -> argparse.ArgumentParser:
         flag = "--" + f.name.replace("_", "-")
         default = (f.default_factory() if f.default_factory is not dataclasses.MISSING
                    else f.default)
+        if isinstance(default, dict):
+            continue  # a mapping comes from a file, not from the CLI
         if f.type in ("bool", bool):
             # BooleanOptionalAction adds --no-<flag>, so default-True bools
             # (e.g. --use-pallas) can actually be switched off from the CLI
@@ -644,7 +662,8 @@ def build_parser() -> argparse.ArgumentParser:
 def parse_args(argv=None) -> Config:
     ns = build_parser().parse_args(argv)
     d = vars(ns)
-    return Config(**{f.name: d[f.name] for f in dataclasses.fields(Config)})
+    return Config(**{f.name: d[f.name] for f in dataclasses.fields(Config)
+                     if f.name in d})
 
 
 def sweep_best_overrides(repo_root: Optional[str] = None) -> dict:

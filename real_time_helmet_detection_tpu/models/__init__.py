@@ -1,6 +1,6 @@
+from . import hourglass as _hourglass
 from .hourglass import (
     Activation,
-    build_model,
     Convolution,
     FusedBNAct,
     FusedBNAddAct,
@@ -18,6 +18,22 @@ from .hourglass import (
     STEConv,
     mish,
 )
+
+
+def build_model(args_or_cfg, dtype=None, **hourglass_options):
+    """The model `cfg.family` names: the stacked hourglass (every option of
+    `hourglass.build_model`, ref train.py:164-172 `load_network`) or the
+    decoder of models/decoder.py (no reference analogue), imported only
+    when asked for."""
+    family = getattr(args_or_cfg, "family", "hourglass")
+    if family == "hourglass":
+        return _hourglass.build_model(args_or_cfg, dtype, **hourglass_options)
+    if family == "latent_moe_decoder" and not hourglass_options:
+        from .decoder import build_decoder
+        return build_decoder(args_or_cfg, dtype)
+    raise ValueError("no model of family %r takes %s"
+                     % (family, sorted(hourglass_options) or "this config"))
+
 
 __all__ = [
     "Activation",

@@ -18,7 +18,9 @@ Layers (PERF.md section 3): `stem` (PreLayer), `hourglass`, `neck`,
 `head`, `merge` (the inter-stack 1x1 convolutions of a multi-stack model),
 `normalize`, `loss`, `optimizer`, `peak`, `decode`, `nms`, with `/bwd`
 appended where the scope path runs through `transpose(`: the backward
-pass. An operation the program named but outside those (the step counter's
+pass. The decoder family's: `prefill/<scope>` and `decode/<scope>` for the
+scopes `embed`, `attn_full`, `indexer`, `attn_window`, `router`, `experts`,
+`shared_expert`, `dense_ffn`, `lm_head`. An operation the program named but outside those (the step counter's
 `jit(step)/add`, the network's own input cast) is `other`. An instruction
 XLA made itself carries no metadata (`copy`, `bitcast`, `copy-start/done`
 from layout assignment and memory-space assignment): it takes the layer
@@ -47,6 +49,11 @@ _LAYER_OF_SCOPE = (
 # the model's top module: its children are the layers, so it is looked
 # through; an operation directly under it is `other`
 _LOOKED_THROUGH = ("StackedHourglass",)
+# the decoder family (models/decoder.py): its `jax.named_scope`s, the
+# innermost of which is the layer, under the phase that ran it
+_DECODER_SCOPES = ("embed", "attn_full", "indexer", "attn_window", "router",
+                   "experts", "shared_expert", "dense_ffn", "lm_head")
+_DECODER_PHASES = ("prefill", "decode")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
@@ -84,7 +91,12 @@ def layer_of(op_name: str) -> str:
     """The layer a scope path belongs to (module docstring)."""
     backward = False
     layer = None
-    for element in _split_path(op_name):
+    elements = _split_path(op_name)
+    inner = [e for e in elements if e in _DECODER_SCOPES]
+    if inner:
+        phase = [e for e in elements if e in _DECODER_PHASES]
+        return "/".join(phase[:1] + inner[-1:])
+    for element in elements:
         # peel the transforms jax wrote around the scope: jvp(...),
         # transpose(jvp(...)), vmap(...), checkpoint(...)
         jitted = False

@@ -1,0 +1,205 @@
+"""Attention for the decoder family: norms and rotary positions, the exact
+top-k of the learned indexer, blockwise causal attention over a window or a
+chosen key set (prefill), and one query a row against a latent cache (decode).
+
+The reference has no attention of any kind (ref hourglass.py is
+convolutions only); this module is new capability. Plain XLA: the matrix
+products are large enough for the MXU as they stand, and the selection is a
+mask over dense causal blocks (skipping unchosen blocks is a later step).
+
+Conventions shared with benchmark/reference/latent_moe_decoder.py (which
+states the equations): float32 inside norms, softmax and the indexer's score
+sum, the operands' dtype (bfloat16) into every matrix product; the rotation
+pairs dimension i with i + d/2.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# masked scores: finite, so that a row with nothing allowed (a padded query)
+# gives numbers, not NaN
+NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def rms_norm(x, w, eps: float):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x, w, b, eps: float):
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean((x32 - mean) ** 2, axis=-1, keepdims=True)
+    y = (x32 - mean) * lax.rsqrt(var + eps)
+    return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def rotate(x, pos, theta: float):
+    """Rotary positions. x (..., d) with `pos` shaped like x's leading axes
+    up to where it stops: (T,) for x (T, H, d) or (T, d); (B,) for (B, H, d).
+    Computed in float32, returned in x's dtype."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[..., None] * freq
+    ang = ang.reshape(pos.shape + (1,) * (x.ndim - pos.ndim - 1) + (half,))
+    a, b = (x[..., :half].astype(jnp.float32),
+            x[..., half:].astype(jnp.float32))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def rotate_leading(x, pos, theta: float, dims: int):
+    """`rotate` over the first `dims` of the last axis, the rest as it is."""
+    return jnp.concatenate([rotate(x[..., :dims], pos, theta),
+                            x[..., dims:]], axis=-1)
+
+
+# ---- the indexer's exact top-k -------------------------------------------------
+
+def _ordered_bits(x):
+    """float32 -> uint32 with the same order (-inf lowest)."""
+    i = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    i = i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))
+    return lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def top_k_mask(scores, k: int):
+    """bool, like `scores`: True where the entry is among the k largest of
+    its row (last axis); entries equal to the k-th largest are all kept.
+    Exact: the k-th largest is found bit by bit (32 counts over the row), no
+    sort and no approximation, so the kept set is the reference's."""
+    if k >= scores.shape[-1]:
+        return jnp.ones(scores.shape, bool)
+    u = _ordered_bits(scores)
+    kth = jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32)
+    for bit in range(31, -1, -1):
+        cand = kth | jnp.uint32(1 << bit)
+        enough = jnp.sum(u >= cand, axis=-1, keepdims=True,
+                         dtype=jnp.int32) >= k
+        kth = jnp.where(enough, cand, kth)
+    return u >= kth
+
+
+def index_scores(qi, ki, w, head_block: Optional[int] = None):
+    """I[t, s] = sum_h w[t, h] ReLU(qi[t, h] . ki[s]) / sqrt(heads * dim),
+    float32. qi (..., T, H, d), ki (..., S, d), w (..., T, H) float32;
+    heads taken `head_block` at a time so that the per-head scores
+    (float32, H x T x S) never stand whole."""
+    heads, dim = qi.shape[-2], qi.shape[-1]
+    step = head_block or heads
+    total = None
+    for h0 in range(0, heads, step):
+        s = jnp.einsum("...thd,...sd->...hts", qi[..., h0:h0 + step, :], ki,
+                       preferred_element_type=jnp.float32)
+        wh = jnp.swapaxes(w[..., h0:h0 + step], -1, -2)[..., None]
+        part = jnp.sum(wh * jnp.maximum(s, 0.0), axis=-3)
+        total = part if total is None else total + part
+    return total / math.sqrt(heads * dim)
+
+
+def select_blocks(qi, ki, w, topk: int, q_block: int,
+                  head_block: Optional[int] = None) -> List[jax.Array]:
+    """The chosen keys of one sequence, a q block at a time: a list of bool
+    (q_block, r1), r1 the block's end (keys after it are not causal), True
+    where s <= t and I[t, s] is among the `topk` largest of row t."""
+    total = qi.shape[0]
+    out = []
+    for r0 in range(0, total, q_block):
+        r1 = min(total, r0 + q_block)
+        t = r0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 0)
+        s = lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 1)
+        causal = s <= t
+        if r1 <= topk:
+            out.append(causal)
+            continue
+        scores = index_scores(qi[r0:r1], ki[:r1], w[r0:r1], head_block)
+        out.append(top_k_mask(jnp.where(causal, scores, -jnp.inf), topk)
+                   & causal)
+    return out
+
+
+def _masked_exp(scores, allowed):
+    """float32 scores -> (exp(scores - row max), 0 where not allowed, and its
+    row sum): the softmax's two parts, divided after the value product. A row
+    with nothing allowed weighs every key alike: numbers, not NaN."""
+    s = jnp.where(allowed, scores, NEG)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    return p, jnp.sum(p, axis=-1, keepdims=True)
+
+
+# ---- prefill: blockwise causal attention ------------------------------------------
+
+def blockwise_attention(q, k, v, *, q_block: int, scale: float,
+                        window: Optional[int] = None,
+                        chosen: Optional[List[jax.Array]] = None,
+                        head_block: Optional[int] = None):
+    """Causal attention of one sequence. q (H, T, dq), k (H, T, dq), v (H, T,
+    dv) -> (H, T, dv); `scale` multiplies the scores (in float32). A q block [r0, r1) reads the keys
+    [lo, r1): lo = r0 - (window - 1) under a window, else 0; inside, a key
+    is allowed where s <= t, t - s < window and, with `chosen` (the list
+    `select_blocks` gives, same q_block), where it was chosen. Softmax in
+    float32 over the block's whole key range (no running maximum needed).
+    Heads `head_block` at a time under `lax.map`, so that the scores (float32,
+    heads x q_block x keys) stay small."""
+    heads, total = q.shape[0], q.shape[1]
+
+    def group(qkv):
+        qg, kg, vg = qkv
+        outs = []
+        for i, r0 in enumerate(range(0, total, q_block)):
+            r1 = min(total, r0 + q_block)
+            lo = 0 if window is None else max(0, r0 - (window - 1))
+            t = r0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1 - lo), 0)
+            s = lo + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1 - lo), 1)
+            allowed = s <= t
+            if window is not None:
+                allowed &= (t - s) < window
+            if chosen is not None:
+                allowed &= chosen[i][:, lo:]
+            sc = jnp.einsum("hqd,hkd->hqk", qg[:, r0:r1], kg[:, lo:r1],
+                            preferred_element_type=jnp.float32)
+            p, denom = _masked_exp(sc * scale, allowed)
+            o = jnp.einsum("hqk,hkd->hqd", p.astype(vg.dtype), vg[:, lo:r1],
+                           preferred_element_type=jnp.float32)
+            outs.append((o / denom).astype(vg.dtype))
+        return jnp.concatenate(outs, axis=1)
+
+    if not head_block or head_block >= heads:
+        return group((q, k, v))
+    split = lambda x: x.reshape((heads // head_block, head_block)  # noqa: E731
+                                + x.shape[1:])
+    out = lax.map(group, (split(q), split(k), split(v)))
+    return out.reshape((heads,) + out.shape[2:])
+
+
+# ---- decode: one query a row against the latent cache ---------------------------
+
+def latent_cache_attention(q_lat, q_rope, c_kv, k_r, allowed, scale: float):
+    """q_lat (B, H, r): the nope part of the query with W_uk absorbed; q_rope
+    (B, H, dr); the cache c_kv (B, S, r), k_r (B, S, dr); allowed (B, S)
+    bool. Returns the attention-weighted latents (B, H, r), to be taken
+    through W_uv by the caller."""
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, c_kv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,bsd->bhs", q_rope, k_r,
+                      preferred_element_type=jnp.float32)) * scale
+    p, denom = _masked_exp(s, allowed[:, None, :])
+    o = jnp.einsum("bhs,bsr->bhr", p.astype(c_kv.dtype), c_kv,
+                   preferred_element_type=jnp.float32)
+    return (o / denom).astype(c_kv.dtype)
+
+
+def ring_positions(pos, width: int):
+    """The position each slot of a ring of `width` holds once position `pos`
+    (B,) has been written at slot pos % width: (B, width) int32, negative
+    where the slot has not been written yet."""
+    slot = jnp.arange(width, dtype=jnp.int32)[None, :]
+    return pos[:, None] - jnp.mod(pos[:, None] - slot, width)

@@ -1,0 +1,129 @@
+"""The expert layer of the decoder family: sigmoid router with a selection
+bias, the choice, the (token, choice) pairs sorted by expert, the pairs whose
+expert is not held here dropped, a grouped matmul over the experts held, the
+weighted way back to the tokens.
+
+The reference has no experts (ref hourglass.py is convolutions only); this
+module is new capability. No token is dropped for capacity: the sorted pairs
+are taken `capacity` rows at a time until all held pairs are done (one pass
+in all but pathological routings: the capacity is twice the rows an even
+routing gives this share).
+
+The grouped matmul is `ops/pallas/expert_gmm.py` on the TPU (PERF.md section
+6, PR 29, has the chip readings behind the choice); elsewhere Mosaic cannot
+compile, so `lax.ragged_dot` stands in (`kernel_compiles`, the one place
+that decides). `interpret=True` is for tests: the kernel under the Pallas
+interpreter on the CPU.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..parallel.experts import ExpertShare
+from .pallas import expert_gmm as gmm
+
+
+def kernel_compiles() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def route(hn, w_router, b_select, per_token: int, norm_weights: bool,
+          routed_scale: float):
+    """hn (T, hidden) -> (chosen expert ids (T, per_token) int32, their
+    weights (T, per_token) float32). Scores in float32; the bias enters the
+    choice only."""
+    scores = jax.nn.sigmoid(jnp.dot(hn, w_router,
+                                    preferred_element_type=jnp.float32))
+    _, idx = lax.top_k(scores + b_select.astype(jnp.float32), per_token)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_weights:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weights * routed_scale
+
+
+def swiglu(x, w_gate_up, w_down):
+    gate, up = jnp.split(jnp.dot(x, w_gate_up), 2, axis=-1)
+    return jnp.dot(jax.nn.silu(gate) * up, w_down)
+
+
+def grouped_swiglu(rows, w_gate_up, w_down, group_sizes,
+                   interpret: bool = False):
+    """rows (m, hidden) sorted by group -> (m, hidden): SwiGLU of each row by
+    its group's expert. Rows past the groups' total are undefined."""
+    if interpret or kernel_compiles():
+        tm = gmm.row_tile(rows.shape[0])
+        meta = gmm.group_metadata(group_sizes, rows.shape[0], tm)
+
+        def mm(a, b):
+            return gmm.expert_gmm(a, b, meta, interpret=interpret)
+    else:
+        def mm(a, b):
+            return lax.ragged_dot(a, b, group_sizes.astype(jnp.int32))
+    gate, up = jnp.split(mm(rows, w_gate_up), 2, axis=-1)
+    return mm(jax.nn.silu(gate) * up, w_down)
+
+
+def capacity_rows(pairs: int, share: ExpertShare) -> int:
+    """Rows a pass takes: twice what an even routing sends this share, in
+    whole row tiles, and never more than all the pairs."""
+    tile = gmm.TILING[0]
+    whole = -(-pairs // 16) * 16  # bfloat16 rows come 16 to a tile
+    if whole <= tile:
+        return whole
+    even = -(-2 * pairs * share.held // share.n_routed // tile) * tile
+    return min(-(-whole // tile) * tile, max(tile, even))
+
+
+def routed_experts(hn, idx, weights, token_real, w_gate_up, w_down,
+                   share: ExpertShare, interpret: bool = False):
+    """The held experts' part of the layer. hn (T, hidden); idx, weights
+    (T, k) from `route`; token_real (T,) bool (a padded position takes no
+    expert's time). Returns (y (T, hidden) in hn's dtype, local (T, k) int32:
+    each pair's index among the experts held, `share.held` where the pair is
+    not computed here)."""
+    tokens, k = idx.shape
+    pairs = tokens * k
+    local = idx - share.first
+    here = (local >= 0) & (local < share.held) & token_real[:, None]
+    local = jnp.where(here, local, share.held)
+    flat = local.reshape(pairs)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    offsets = jnp.searchsorted(flat[order], jnp.arange(
+        share.held + 1, dtype=jnp.int32)).astype(jnp.int32)
+    n_here = offsets[share.held]
+    cap = capacity_rows(pairs, share)
+    passes = -(-pairs // cap)
+    # where each (token, choice) pair stands among the sorted rows
+    place = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32)).reshape(tokens, k)
+    order = jnp.pad(order, (0, passes * cap - pairs))
+
+    def one_pass(c, out):
+        start = c * cap
+        rows = lax.dynamic_slice(order, (start,), (cap,))
+        valid = start + jnp.arange(cap, dtype=jnp.int32) < n_here
+        sizes = (jnp.clip(offsets[1:] - start, 0, cap)
+                 - jnp.clip(offsets[:-1] - start, 0, cap))
+        y = grouped_swiglu(hn[jnp.where(valid, rows // k, 0)], w_gate_up,
+                           w_down, sizes, interpret)
+        # back to the tokens by gathers, a choice at a time (a scatter-add
+        # of these rows took five times the grouped matmuls: PERF.md
+        # section 6, PR 29); rows past the groups are undefined, so a pair
+        # is read only where it is held and in this pass
+        at = place - start
+        mine = here & (at >= 0) & (at < cap)
+        for j in range(k):
+            got = y[jnp.clip(at[:, j], 0, cap - 1)].astype(jnp.float32)
+            out = out + jnp.where(mine[:, j, None],
+                                  got * weights[:, j, None], 0.0)
+        return out
+
+    out = jnp.zeros(hn.shape, jnp.float32)
+    if passes == 1:
+        out = one_pass(0, out)
+    else:
+        out = lax.fori_loop(0, -(-n_here // cap), one_pass, out)
+    return out.astype(hn.dtype), local

@@ -13,15 +13,21 @@ re-designed as a **single jitted function** with static shapes:
 * variable-length outputs (conf filtering at ref transform.py:108-110, NMS
   survivors) become a fixed `(B, num_stack * topk)` box set with a `valid`
   mask; hosts filter when writing files.
+
+The decoder family (models/decoder.py; no reference analogue) has its own
+program at the end of this file: `make_generate_fn` (prefill, then greedy
+decode steps through the cache, one jitted call a batch), its answer
+`Generation` and the counters that answer feeds (`generation_counters`).
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .ops.decode import (CascadeDetections, Detections, confidence_summary,
                          decode_heatmap, decode_peak_scores)
@@ -222,3 +228,82 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
     return jax.jit(predict_impl,
                    in_shardings=(replicated(mesh), batch_sharding(mesh, 4)),
                    out_shardings=out_sh)
+
+
+# ---- the decoder family's generate program ---------------------------------------
+
+class Generation(NamedTuple):
+    """What one generate call answers, rows first (the engine slices rows).
+    `expert_tokens`: pairs routed to each held expert by expert layer, over
+    every position of the row (prompt and fed-back tokens, padding excluded);
+    `keys_kept` / `keys_causal`: keys the indexer kept / keys causal, the
+    full layers summed."""
+    tokens: jax.Array          # int32 (B, N)
+    logits_first: jax.Array    # float32 (B, V): at the prompt's last token
+    logits_last: jax.Array     # float32 (B, V): the step that gave token N
+    expert_tokens: jax.Array   # int32 (B, expert layers, held)
+    keys_kept: jax.Array       # int32 (B,)
+    keys_causal: jax.Array     # int32 (B,)
+    prompt_len: jax.Array      # int32 (B,): the length the row stated
+
+
+def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
+    """Build `generate(variables, payload int32 (B, P_max + 1)) ->
+    Generation` (batched, jitted): one call a batch, as `make_predict_fn` is
+    for the detector (no reference analogue: ref evaluate.py has no
+    generation). A payload row is `[length, ids..., padding]`. Prefill over
+    P_max with per-row lengths, then `new_tokens - 1` greedy decode steps in
+    one `lax.scan` through the cache at per-row positions; the last step's
+    logits are the ones that gave token `new_tokens`."""
+    new_tokens = int(new_tokens)
+    if new_tokens < 1:
+        raise ValueError("new_tokens must be >= 1, got %d" % new_tokens)
+    if getattr(cfg, "family", "hourglass") != "latent_moe_decoder":
+        raise ValueError("make_generate_fn serves family latent_moe_decoder, "
+                         "got %r" % (getattr(cfg, "family", None),))
+
+    def generate(variables, payload):
+        lengths = jnp.clip(payload[:, 0], 1, payload.shape[1] - 1)
+        logits, cache = model.apply(variables, payload[:, 1:], lengths,
+                                    new_tokens - 1, method="prefill")
+        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def step(carry, _):
+            token, pos, cache, _ = carry
+            logits, cache = model.apply(variables, token, pos, cache,
+                                        method="step")
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nxt, pos + 1, cache, logits), nxt
+
+        (_, _, cache, last), rest = jax.lax.scan(
+            step, (first, lengths, cache, logits), None,
+            length=new_tokens - 1)
+        counts = cache["counts"]
+        return Generation(
+            tokens=jnp.concatenate([first[:, None], rest.T], axis=1),
+            logits_first=logits, logits_last=last,
+            expert_tokens=counts["expert_tokens"],
+            keys_kept=counts["keys_kept"], keys_causal=counts["keys_causal"],
+            prompt_len=lengths)
+
+    return jax.jit(generate)
+
+
+def generation_counters(p_max: int) -> Callable:
+    """`row_counters` for `ServingEngine` over `make_generate_fn`'s answers:
+    what a fetched batch's real rows add to the `gen.*` counters (host
+    arithmetic on the fetch thread, after the one D2H)."""
+    def counters(rows: Generation) -> dict:
+        n = len(rows.prompt_len)
+        prompt = int(np.sum(rows.prompt_len))
+        out = {"gen.requests": n, "gen.prompt_tokens": prompt,
+               "gen.padded_prompt_tokens": n * int(p_max) - prompt,
+               "gen.new_tokens": int(rows.tokens.shape[0] * rows.tokens.shape[1]),
+               "gen.keys_kept": int(np.sum(rows.keys_kept, dtype=np.int64)),
+               "gen.keys_causal": int(np.sum(rows.keys_causal,
+                                             dtype=np.int64))}
+        by_expert = np.sum(rows.expert_tokens, axis=(0, 1), dtype=np.int64)
+        for e, pairs in enumerate(by_expert):
+            out["gen.expert_pairs.e%02d" % e] = int(pairs)
+        return out
+    return counters

@@ -42,6 +42,11 @@ Design rules, each load-bearing:
   queue — the generalization of evaluate.py's one-deep `pending` pattern
   and the C++ runner's `--depth` loop. `depth` bounds device memory
   (depth batches of images + detections) and provides backpressure.
+* **Any static payload in, any NamedTuple of row-first arrays out.** The
+  detector's frames (below) and the decoder family's token rows
+  (`predict.make_generate_fn`: int32 `[length, ids..., padding]` in, a
+  `Generation` out) ride the same buckets; a program whose answer should
+  feed counters hands the engine a `row_counters`.
 * **uint8 in, boxes out.** With a `normalize` predict (the eval wire),
   images cross H2D as uint8 and are normalized on-device; the ONLY D2H
   is the fixed-shape Detections block (boxes/classes/scores/valid) — no
@@ -267,11 +272,11 @@ class ServeFuture:
 
 
 class _Request:
-    __slots__ = ("image", "future", "attempts", "ctx", "ctx_owner")
+    __slots__ = ("payload", "future", "attempts", "ctx", "ctx_owner")
 
-    def __init__(self, image: np.ndarray, future: ServeFuture,
+    def __init__(self, payload: np.ndarray, future: ServeFuture,
                  ctx=None, ctx_owner: bool = False):
-        self.image = image
+        self.payload = payload
         self.future = future
         self.attempts = 0    # completed dispatch attempts that failed
         self.ctx = ctx       # TraceContext (ISSUE 14); stable across
@@ -290,8 +295,10 @@ class ServingEngine:
         fn they already use.
     variables : checkpoint pytree, device-committed once at construction
         (hot-swappable later via `reload`).
-    image_shape : (H, W, C) static per-request shape.
-    image_dtype : np dtype of the wire (uint8 for the raw eval wire).
+    payload_shape : static per-request shape: (H, W, C) for the detector's
+        frames, (P_max + 1,) for the generate program's token rows.
+    payload_dtype : np dtype of the wire (uint8 for the raw eval wire,
+        int32 for token ids).
     buckets : static batch-size set, AOT-compiled at construction.
     max_wait_ms : batch-formation wait bound (0 = dispatch immediately).
     depth : max in-flight batches (>=1); device memory is bounded by
@@ -317,16 +324,22 @@ class ServingEngine:
         (serve_bench, tests).
     watchdog : optional `obs.slo.SloWatchdog`, poked after every batch
         outcome; serving alerts degrade THIS engine.
+    row_counters : optional `rows -> {counter name: increment}`, called on
+        the fetch thread once a batch's answers are delivered, with the
+        fetched answer cut to its real rows; the increments go into
+        `metrics` (how a program's answer feeds counters:
+        `predict.generation_counters`). A callback that raises is counted
+        (`serve.row_counter_errors`) and the engine serves on.
     """
 
-    def __init__(self, predict, variables, image_shape: Sequence[int],
-                 image_dtype, buckets: Sequence[int] = DEFAULT_BUCKETS,
+    def __init__(self, predict, variables, payload_shape: Sequence[int],
+                 payload_dtype, buckets: Sequence[int] = DEFAULT_BUCKETS,
                  max_wait_ms: float = 5.0, depth: int = 2,
                  queue_capacity: int = 128, sharding=None, tracer=None,
                  start: bool = True, max_retries: int = 2,
                  hang_timeout_s: Optional[float] = None,
                  recover_after: int = 2, injector=None, metrics=None,
-                 watchdog=None):
+                 watchdog=None, row_counters=None):
         import jax
 
         from ..obs import metrics as metrics_mod
@@ -336,8 +349,9 @@ class ServingEngine:
         self._buckets = tuple(sorted({int(b) for b in buckets}))
         if not self._buckets or self._buckets[0] < 1:
             raise ValueError("buckets must be positive, got %r" % (buckets,))
-        self._image_shape = tuple(int(s) for s in image_shape)
-        self._image_dtype = np.dtype(image_dtype)
+        self._payload_shape = tuple(int(s) for s in payload_shape)
+        self._payload_dtype = np.dtype(payload_dtype)
+        self._row_counters = row_counters
         self._max_wait_s = max(0.0, float(max_wait_ms)) / 1e3
         self._depth = max(1, int(depth))
         self._sharding = sharding
@@ -358,7 +372,7 @@ class ServingEngine:
             "submitted", "completed", "batches_total", "batch_slots",
             "padded_slots", "shed_queue_full", "shed_deadline", "retried",
             "requeued_batches", "failed_batches", "hung_batches",
-            "retry_exhausted", "reloads")}
+            "retry_exhausted", "reloads", "row_counter_errors")}
         self._mg_queue = mm.gauge("serve.queue_depth")
         self._mg_retry = mm.gauge("serve.retry_depth")
         self._mg_inflight = mm.gauge("serve.inflight_batches")
@@ -374,8 +388,8 @@ class ServingEngine:
         install_compile_listener()
         self._compiled: Dict[int, object] = {}
         for b in self._buckets:
-            spec = jax.ShapeDtypeStruct((b,) + self._image_shape,
-                                        self._image_dtype)
+            spec = jax.ShapeDtypeStruct((b,) + self._payload_shape,
+                                        self._payload_dtype)
             with self._tracer.span("serve:lower", b=b):
                 lowered = predict.lower(self._variables, spec)
             with self._tracer.span("serve:compile", b=b):
@@ -690,7 +704,7 @@ class ServingEngine:
             self._tracer.event("serve:failed", ctx=req.ctx,
                                error=type(error).__name__)
 
-    def submit(self, image: np.ndarray, deadline_s: Optional[float] = None,
+    def submit(self, payload: np.ndarray, deadline_s: Optional[float] = None,
                block: bool = True, timeout: Optional[float] = None,
                ctx=None) -> ServeFuture:
         """Enqueue one request; returns its future immediately.
@@ -709,13 +723,13 @@ class ServingEngine:
         the engine mints one itself when tracing is on."""
         if self._closed:
             raise EngineClosedError("engine closed")
-        image = np.asarray(image)
-        if image.shape != self._image_shape \
-                or image.dtype != self._image_dtype:
+        payload = np.asarray(payload)
+        if payload.shape != self._payload_shape \
+                or payload.dtype != self._payload_dtype:
             raise ValueError(
-                "request image must be %s %s, got %s %s"
-                % (self._image_shape, self._image_dtype, image.shape,
-                   image.dtype))
+                "request payload must be %s %s, got %s %s"
+                % (self._payload_shape, self._payload_dtype, payload.shape,
+                   payload.dtype))
         fut = ServeFuture(
             deadline=None if deadline_s is None
             else time.monotonic() + float(deadline_s))
@@ -724,7 +738,7 @@ class ServingEngine:
             ctx = new_root()
             owner = True
         fut.ctx = ctx
-        req = _Request(image, fut, ctx=ctx, ctx_owner=owner)
+        req = _Request(payload, fut, ctx=ctx, ctx_owner=owner)
         with self._lock:
             self._stats["submitted"] += 1
         self._mc["submitted"].inc()
@@ -904,10 +918,10 @@ class ServingEngine:
                     b = self._pick_bucket(len(live))
                     # a fresh buffer per batch: the async H2D of the
                     # previous dispatch may still be reading its buffer
-                    buf = np.zeros((b,) + self._image_shape,
-                                   self._image_dtype)
+                    buf = np.zeros((b,) + self._payload_shape,
+                                   self._payload_dtype)
                     for i, r in enumerate(live):
-                        buf[i] = r.image
+                        buf[i] = r.payload
                 now = time.monotonic()
                 for r in live:
                     self._tracer.record("serve:queue-wait",
@@ -1051,6 +1065,15 @@ class ServingEngine:
             finally:
                 if collecting:
                     gc.enable()
+            if self._row_counters is not None:
+                # after delivery, and never the fetch thread's death: a
+                # callback that raises costs its batch's increments only
+                try:
+                    rows = type(host)(*(leaf[:len(live)] for leaf in host))
+                    for name, by in self._row_counters(rows).items():
+                        self._metrics.counter(name).inc(by)
+                except Exception:  # noqa: BLE001 — counted, serve on
+                    self._mc["row_counter_errors"].inc()
             with self._lock:
                 self._inflight_batches -= 1
                 inflight = self._inflight_batches
