@@ -66,7 +66,8 @@ Annotation convention (mirrored in docs/ARCHITECTURE.md):
 Scope: classes (attributes of `self`) and module globals (names with a
 `global` declaration). Function-local locks guarding closure state, and
 mutations via method calls (`deque.append`) are out of reach — the
-deque-based handoffs in the engine are deliberately in that bucket (the
+deque- and queue-based handoffs in the engine (`_retry`, `_q`, `_inflight`,
+the staging ring's `_staging_free`) are deliberately in that bucket (the
 docstrings there say why). Findings diff against the SAME
 `analysis/baseline.json` as the other layers, which stays EMPTY:
 findings get fixed or annotated with a reason, never grandfathered.

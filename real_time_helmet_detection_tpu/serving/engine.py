@@ -42,6 +42,28 @@ Design rules, each load-bearing:
   queue — the generalization of evaluate.py's one-deep `pending` pattern
   and the C++ runner's `--depth` loop. `depth` bounds device memory
   (depth batches of images + detections) and provides backpressure.
+* **A ring of reused staging buffers (ISSUE 30).** A batch is formed in
+  the leading rows of a host buffer of the largest bucket's shape, taken
+  from a free list and made only when that is empty: a fresh 201 MB
+  `np.zeros` a batch cost 230 ms of first-touch page faults on the TPU
+  host, the same 256 row copies into touched memory 16 ms. The buffer
+  travels with its batch through `_inflight`, and the FETCHER hands it
+  back once the batch's answer is on the host (after `serve:d2h`: until
+  then the asynchronous H2D may still read it, on the CPU backend the
+  device array may alias it, and an output may alias its input), or once
+  a failed batch has ended; a batch that failed after its `device_put`
+  began, or that the hang watchdog abandoned, keeps its buffer (the
+  device may still be reading) and the ring makes another. At most
+  `depth + 2` are out (one with the dispatcher, being formed or waiting
+  for room in `_inflight`; `depth` queued there; one with the fetcher),
+  so the engine holds up to `(depth + 2) x max(buckets) x row bytes` of
+  host memory for good (805 MB at bucket 256 of 512^2 uint8 frames and
+  depth 2, 524 KB for the decoder's token rows; rows never written are
+  never resident) where it held as much in passing.
+  Padding stays zeros: each buffer knows up to which row it was written,
+  and a batch clears only the stale rows its bucket would send.
+  `serve.staging_reused` / `serve.staging_allocated` (and `stats()`) say
+  whether buffers come back: in steady state every batch reuses one.
 * **Any static payload in, any NamedTuple of row-first arrays out.** The
   detector's frames (below) and the decoder family's token rows
   (`predict.make_generate_fn`: int32 `[length, ids..., padding]` in, a
@@ -284,6 +306,16 @@ class _Request:
         # root (standalone serving) and owe the trace its closure
 
 
+class _Staging:
+    """One host buffer of the staging ring: `buf` holds the largest bucket's
+    rows, rows at or after `mark` are zeros."""
+    __slots__ = ("buf", "mark")
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self.mark = 0
+
+
 class ServingEngine:
     """Persistent continuous-batching server over a jitted predict fn.
 
@@ -372,7 +404,8 @@ class ServingEngine:
             "submitted", "completed", "batches_total", "batch_slots",
             "padded_slots", "shed_queue_full", "shed_deadline", "retried",
             "requeued_batches", "failed_batches", "hung_batches",
-            "retry_exhausted", "reloads", "row_counter_errors")}
+            "retry_exhausted", "reloads", "row_counter_errors",
+            "staging_reused", "staging_allocated")}
         self._mg_queue = mm.gauge("serve.queue_depth")
         self._mg_retry = mm.gauge("serve.retry_depth")
         self._mg_inflight = mm.gauge("serve.inflight_batches")
@@ -402,6 +435,9 @@ class ServingEngine:
                                                          int(queue_capacity)))
         self._retry: "collections.deque" = collections.deque()
         self._inflight: "queue.Queue" = queue.Queue(maxsize=self._depth)
+        # the staging ring's free list: a hand-off queue, fetcher ->
+        # dispatcher, no lock of the engine's (see `_take_staging`)
+        self._staging_free: "queue.SimpleQueue" = queue.SimpleQueue()
         self._lock = threading.Lock()
         # serializes batch dispatch against reload's weight swap; the
         # dispatcher holds it across one batch's form+H2D+compute
@@ -410,7 +446,8 @@ class ServingEngine:
                        "shed_queue_full": 0, "shed_deadline": 0,
                        "padded_slots": 0, "failed": 0, "retried": 0,
                        "requeued_batches": 0, "hung_batches": 0,
-                       "failed_batches": 0, "reloads": 0}
+                       "failed_batches": 0, "reloads": 0,
+                       "staging_reused": 0, "staging_allocated": 0}
         self._state = SERVING
         self._consecutive_failures = 0
         self._consecutive_ok = 0
@@ -878,6 +915,22 @@ class ServingEngine:
             return None
         return item
 
+    def _take_staging(self) -> _Staging:
+        """A staging buffer for the batch being formed: one the fetcher
+        handed back, else a fresh one. No cap is needed: never more than
+        `depth + 2` are out (module docstring), and a fresh one is made
+        only when all that exist are."""
+        try:
+            st, key = self._staging_free.get_nowait(), "staging_reused"
+        except queue.Empty:
+            st, key = _Staging(np.zeros(
+                (self._buckets[-1],) + self._payload_shape,
+                self._payload_dtype)), "staging_allocated"
+        with self._lock:
+            self._stats[key] += 1
+        self._mc[key].inc()
+        return st
+
     def _dispatch_loop(self) -> None:
         import jax
 
@@ -915,23 +968,32 @@ class ServingEngine:
             with self._dispatch_mutex:
                 with self._tracer.span("serve:batch-form", links=blinks,
                                        n=len(live)):
-                    b = self._pick_bucket(len(live))
-                    # a fresh buffer per batch: the async H2D of the
-                    # previous dispatch may still be reading its buffer
-                    buf = np.zeros((b,) + self._payload_shape,
-                                   self._payload_dtype)
+                    b, n = self._pick_bucket(len(live)), len(live)
+                    # a buffer of the ring, which no H2D still reads (the
+                    # fetcher handed it back); padding stays zeros: the
+                    # rows an earlier batch wrote (below the mark) are
+                    # cleared, as far as this bucket sends them
+                    st = self._take_staging()
                     for i, r in enumerate(live):
-                        buf[i] = r.payload
+                        st.buf[i] = r.payload
+                    stale = min(st.mark, b)
+                    if n < stale:
+                        st.buf[n:stale] = 0
+                    if st.mark <= b:
+                        st.mark = n
+                    buf = st.buf[:b]
                 now = time.monotonic()
                 for r in live:
                     self._tracer.record("serve:queue-wait",
                                         now - r.future.t_submit,
                                         ctx=(r.ctx.child() if r.ctx
                                              else None))
+                handed = False  # True: the runtime may be reading `buf`
                 try:
                     if self._injector is not None:
                         self._injector.fire("serve:dispatch", b=b)
                     with self._tracer.span("serve:h2d", b=b, links=blinks):
+                        handed = True
                         dev = (jax.device_put(buf, self._sharding)
                                if self._sharding is not None
                                else jax.device_put(buf))
@@ -942,6 +1004,8 @@ class ServingEngine:
                                            links=blinks):
                         out = self._compiled[b](self._variables, dev)
                 except Exception as e:  # noqa: BLE001 — requeue, serve on
+                    if not handed:  # else dropped: a transfer may be live
+                        self._staging_free.put(st)
                     self._requeue_or_fail(live, e, stage="dispatch", b=b)
                     with self._lock:
                         self._dispatch_busy = False
@@ -962,7 +1026,7 @@ class ServingEngine:
             # dispatch-done -> fetch-start gap: where a deep pipeline
             # parks a batch behind its predecessors' D2H — without it the
             # waterfall cannot attribute a loaded p99, ISSUE 14)
-            self._inflight.put((out, live, b, time.monotonic()))
+            self._inflight.put((out, live, b, time.monotonic(), st))
             # depth-bounded: blocks at `depth` in-flight batches — the
             # pipelining backpressure
         self._inflight.put(_SENTINEL)
@@ -1020,12 +1084,22 @@ class ServingEngine:
             raise box["e"]
         return box["v"]
 
+    @staticmethod
+    def _await_end(out) -> None:
+        """Return once a FAILED batch has ended, well or badly: from then
+        on the device no longer reads its staging buffer."""
+        import jax
+        try:
+            jax.block_until_ready(out)
+        except Exception:  # noqa: BLE001 — it has ended
+            pass
+
     def _fetch_loop(self) -> None:
         while True:
             item = self._inflight.get()
             if item is _SENTINEL:
                 return
-            out, live, b, t_inq = item
+            out, live, b, t_inq, st = item
             flinks = links_of([r.ctx for r in live]) or None
             self._tracer.record("serve:inflight-wait",
                                 time.monotonic() - t_inq, b=b,
@@ -1034,10 +1108,18 @@ class ServingEngine:
                 self._fetch_meta = (len(live), flinks)
                 host = self._fetch(out, b)
             except Exception as e:  # noqa: BLE001 — requeue, serve on
+                if not isinstance(e, FetchHungError):
+                    self._await_end(out)
+                    self._staging_free.put(st)
+                # (an abandoned batch keeps its buffer: the device may still
+                # be reading it; the ring makes another)
                 self._requeue_or_fail(live, e, stage="fetch", b=b)
                 with self._lock:
                     self._inflight_batches -= 1
                 continue
+            # the answer is on the host (after the D2H, not the wait: an
+            # output may alias its input): the staging buffer is free
+            self._staging_free.put(st)
             with self._lock:
                 self._stats["completed"] += len(live)
             self._mc["completed"].inc(len(live))

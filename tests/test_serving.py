@@ -371,3 +371,152 @@ def test_results_in_submission_order_across_batches(parts):
     eng.close()
     assert all(_rows_equal(r, oracle[i % len(pool)])
                for i, r in enumerate(rows))
+
+
+# ---------------------------------------------------------------------------
+# the staging ring (ISSUE 30): batches are formed into reused host buffers
+
+ECHO_W = 8      # an int32 row of 8: the token-row kind of payload
+ECHO_DEPTH = 2
+
+
+@pytest.fixture(scope="module")
+def echo():
+    """A program that answers every request its own input row and the
+    column sums of the WHOLE batch the device was handed, padding
+    included: what a stale or rewritten staging row would show in."""
+    from typing import NamedTuple
+
+    class Echo(NamedTuple):
+        row: jax.Array
+        batch_sum: jax.Array
+
+    @jax.jit
+    def program(variables, x):
+        total = x.sum(axis=0) + variables
+        return Echo(x, jax.numpy.broadcast_to(total, x.shape))
+
+    return program, np.int32(0)
+
+
+def _echo_engine(echo, **kw):
+    program, variables = echo
+    kw.setdefault("max_wait_ms", 1.0)
+    return ServingEngine(program, variables, (ECHO_W,), np.int32,
+                         buckets=(2, 4), depth=ECHO_DEPTH,
+                         queue_capacity=128, **kw)
+
+
+def _echo_payloads(n):
+    return [np.arange(ECHO_W, dtype=np.int32) + 1000 * (j + 1)
+            for j in range(n)]
+
+
+def test_staging_stale_rows_never_reach_the_device(echo):
+    """Padding stays zeros though the buffer is reused: after a full
+    bucket, the padded rows of smaller batches formed into the same buffer
+    are cleared, and no answer ever holds a row of another batch."""
+    eng = _echo_engine(echo, max_wait_ms=20.0)
+    bit = 0
+    for size in (4, 1, 3, 1, 4, 2, 1, 2, 3):
+        # request j's row is 2^j everywhere, so the batch's column sum
+        # names its members: a stale row would add an earlier request's bit
+        rows = [np.full(ECHO_W, 1 << (bit + j), np.int32)
+                for j in range(size)]
+        mine = sum(1 << (bit + j) for j in range(size))
+        bit += size
+        # one batch at a time: each is formed into the buffer the last one
+        # handed back, whose rows that batch wrote
+        for row, ans in zip(rows, [f.result(timeout=60) for f in
+                                   [eng.submit(r) for r in rows]]):
+            assert np.array_equal(ans.row, row)
+            seen = int(ans.batch_sum[0])
+            assert np.all(ans.batch_sum == seen)
+            assert seen & ~mine == 0, \
+                "a padded row held an earlier batch's frame (bits %x)" % seen
+    assert eng.stats()["staging_allocated"] == 1  # the reuse was exercised
+    eng.close()
+    # a backlog: full batches in submission order, several in flight; a
+    # buffer rewritten while the device still read it would show here
+    eng = _echo_engine(echo, start=False)
+    rows = _echo_payloads(4 * 5 * (ECHO_DEPTH + 2))
+    futs = [eng.submit(r) for r in rows]
+    eng.start()
+    answers = [f.result(timeout=60) for f in futs]
+    eng.close()
+    for j, (row, ans) in enumerate(zip(rows, answers)):
+        assert np.array_equal(ans.row, row)
+        first = j - j % 4
+        assert np.array_equal(ans.batch_sum, sum(rows[first:first + 4]))
+
+
+def test_staging_ring_is_bounded_and_engages(echo):
+    from real_time_helmet_detection_tpu.obs.metrics import MetricsRegistry
+    eng = _echo_engine(echo, start=False, metrics=MetricsRegistry())
+    rows = _echo_payloads(4 * 20)
+    futs = [eng.submit(r) for r in rows]
+    eng.start()
+    assert all(np.array_equal(f.result(timeout=60).row, r)
+               for f, r in zip(futs, rows))
+    st = eng.stats()
+    eng.close()
+    assert st["batches"] == 20
+    assert 1 <= st["staging_allocated"] <= ECHO_DEPTH + 2
+    assert st["staging_reused"] == st["batches"] - st["staging_allocated"]
+    # the registry's counters (what a metric would read) say the same
+    for key in ("staging_reused", "staging_allocated"):
+        assert eng.metrics.counter("serve." + key).value == st[key]
+
+
+def test_staging_failure_paths_give_the_buffer_back(echo):
+    """10 batches failed at dispatch and 10 at fetch, requeued and served:
+    zero lost acks, and the failed batches' buffers came back (none leaked
+    to a fresh allocation)."""
+    spec = ",".join(["serve:dispatch=device-loss@%d" % n
+                     for n in range(2, 40, 4)]
+                    + ["serve:fetch=device-loss@%d" % n
+                       for n in range(3, 33, 3)])
+    inj = ChaosInjector(FaultSchedule.parse(spec))
+    eng = _echo_engine(echo, start=False, max_retries=25, injector=inj)
+    rows = _echo_payloads(4 * 30)
+    futs = [eng.submit(r) for r in rows]
+    eng.start()
+    answers = [f.result(timeout=60) for f in futs]
+    st = eng.stats()
+    eng.close()
+    assert all(np.array_equal(a.row, r) for a, r in zip(answers, rows))
+    assert len(inj.fired) == 20
+    assert st["failed_batches"] == 20 and st["failed"] == 0
+    assert st["completed"] == len(rows)
+    assert st["staging_allocated"] <= ECHO_DEPTH + 2
+
+
+def test_staging_abandoned_buffer_is_not_reused(echo, monkeypatch):
+    """A batch the hang watchdog abandons may still be read by the device:
+    its buffer never carries a later batch."""
+    inj = ChaosInjector(FaultSchedule([
+        FaultEvent("serve:fetch", "hung-fetch", 1, {"hang_s": 1.0})]))
+    eng = _echo_engine(echo, max_retries=2, hang_timeout_s=0.15,
+                       injector=inj)
+    bases = []  # the staging buffer behind each dispatch, kept alive so
+    # that no later buffer can take a dead one's place in memory
+    real_put = jax.device_put
+
+    def spy(x, *a, **k):
+        if isinstance(x, np.ndarray) and x.shape[1:] == (ECHO_W,):
+            bases.append(x if x.base is None else x.base)
+        return real_put(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    rows = _echo_payloads(2 * 8)
+    answers = []
+    for k in range(0, len(rows), 2):  # a batch at a time
+        futs = [eng.submit(r) for r in rows[k:k + 2]]
+        answers += [f.result(timeout=60) for f in futs]
+    st = eng.stats()
+    eng.close()
+    assert all(np.array_equal(a.row, r) for a, r in zip(answers, rows))
+    assert st["hung_batches"] == 1 and st["failed"] == 0
+    assert len(bases) >= 9  # 8 batches and the abandoned one's retry
+    assert all(b is not bases[0] for b in bases[1:])
+    assert st["staging_allocated"] >= 2
