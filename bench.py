@@ -849,6 +849,8 @@ def _bench(out: dict, hb) -> None:
             lock_audit.audit_repo(_lroot), load_baseline())["new"]
     except Exception as e:  # noqa: BLE001 — never block the bench
         log("lock audit unavailable: %r" % e)
+    from real_time_helmet_detection_tpu.ops.pallas.select import kernel_plan
+    plan = kernel_plan(tcfg)
     try:
         # transfer_audit_ok: the D2H/H2D budget (graftlint layer 4)
         # self-reported the same way — the TIMED program's fetched-
@@ -860,8 +862,6 @@ def _bench(out: dict, hb) -> None:
         # approved.
         from real_time_helmet_detection_tpu.analysis.transfer_audit \
             import bench_transfer_ok
-        from real_time_helmet_detection_tpu.models import \
-            resolve_block_fuse as _rbf
         # mode-matched manifest entry: sentinel wins (it changes the
         # fetched-leaf count), then the ISSUE-20 train modes — both
         # budget-identical to the base step, pinned as their own
@@ -870,7 +870,7 @@ def _bench(out: dict, hb) -> None:
             _t_entry = "train_step_scanned[sentinel]"
         elif tcfg.fwd_dtype == "int8":
             _t_entry = "train_step_scanned[fwd=int8]"
-        elif _rbf(tcfg) == "fused":
+        elif plan["block_fuse"] == "fused":
             _t_entry = "train_step_scanned[block-fuse]"
         else:
             _t_entry = "train_step_scanned"
@@ -918,15 +918,12 @@ def _bench(out: dict, hb) -> None:
     out["mfu_train"] = round(train_flops * n_train / dt / peak, 4)
     # why-MFU-moved context for the BENCH_rNN trajectory: the active
     # step-compression settings + the step's cost-analysis HBM bytes
-    from real_time_helmet_detection_tpu.models import (
-        resolve_block_fuse, resolve_epilogue)
-    from real_time_helmet_detection_tpu.train import resolve_loss_kernel
     out["hbm_bytes_per_step"] = train_bytes
     out["remat"] = tcfg.remat
-    out["loss_kernel"] = resolve_loss_kernel(tcfg)
+    out["loss_kernel"] = plan["loss"]
     out["param_policy"] = tcfg.param_policy
-    out["epilogue"] = resolve_epilogue(tcfg)
-    out["block_fuse"] = resolve_block_fuse(tcfg)
+    out["epilogue"] = plan["epilogue"]
+    out["block_fuse"] = plan["block_fuse"]
     out["fwd_dtype"] = tcfg.fwd_dtype
     out["mfu_peak_flops"] = peak
     try:

@@ -98,20 +98,16 @@ def stage_data(root: str, size: Size) -> None:
 def stage_train(root: str, save: str, size: Size) -> str:
     """`get_config(argv)` -> `train(cfg)`; returns the checkpoint path."""
     from real_time_helmet_detection_tpu.config import get_config
-    from real_time_helmet_detection_tpu.models import (resolve_block_fuse,
-                                                       resolve_epilogue)
-    from real_time_helmet_detection_tpu.predict import resolve_peak_kernel
+    from real_time_helmet_detection_tpu.ops.pallas.select import kernel_plan
     from real_time_helmet_detection_tpu.train import (find_latest_checkpoint,
-                                                      resolve_loss_kernel,
                                                       train)
     cfg = get_config(["--train-flag", "--data", root,
                       "--batch-size", str(size.batch),
                       "--end-epoch", str(size.epochs),
                       "--print-interval", "1", "--no-summary",
                       "--save-path", save] + size.arch_flags)
-    say("paths: loss_kernel=%s epilogue=%s block_fuse=%s peak=%s"
-        % (resolve_loss_kernel(cfg), resolve_epilogue(cfg),
-           resolve_block_fuse(cfg), resolve_peak_kernel(cfg)))
+    say("paths: loss_kernel=%(loss)s epilogue=%(epilogue)s "
+        "block_fuse=%(block_fuse)s peak=%(peak)s" % kernel_plan(cfg))
     train(cfg)
     ckpt = find_latest_checkpoint(save)
     if ckpt is None or not ckpt.endswith("check_point_%d" % size.epochs):
@@ -263,14 +259,13 @@ def _xla_bn_act(x, gamma, beta, act, skip=None):
 
 
 def parity_bn_kernels(shape, dtype, act: str, interpret: bool) -> None:
-    """epilogue + residual families, train mode, forward and gradient."""
+    """The BN-tail family without and with a skip, train mode, forward
+    and gradient."""
     import jax
     import jax.numpy as jnp
 
     from real_time_helmet_detection_tpu.ops.pallas.epilogue import \
         fused_bn_act_train
-    from real_time_helmet_detection_tpu.ops.pallas.residual import \
-        fused_bn_add_act_train
     c = shape[-1]
     kx, ks, kg, kv = jax.random.split(jax.random.key(c + shape[1]), 4)
     x, skip, g = (jax.random.normal(k, shape, jnp.float32).astype(dtype)
@@ -298,7 +293,7 @@ def parity_bn_kernels(shape, dtype, act: str, interpret: bool) -> None:
                x, ga, be, activation=act, interpret=interpret)[0], 3),
            via(lambda x, ga, be: _xla_bn_act(x, ga, be, act), 3), tol)
     _check("residual " + tag,
-           via(lambda x, ga, be, s: fused_bn_add_act_train(
+           via(lambda x, ga, be, s: fused_bn_act_train(
                x, ga, be, s, activation=act, interpret=interpret)[0], 4),
            via(lambda x, ga, be, s: _xla_bn_act(x, ga, be, act, skip=s), 4),
            tol)
@@ -358,8 +353,7 @@ def parity_peak(batch: int, fmap: int, interpret: bool) -> None:
 def parity_model(size: Size) -> None:
     """The pair the chip default depends on: train-mode logits of the
     whole network, every BN tail fused vs every BN tail xla, same fp32
-    weights and batch. What tests/test_epilogue.py and test_block_fuse.py
-    pin at toy size, here at the smoke's full width — at HIGHEST matmul
+    weights and batch. What tests/test_bn_tail.py pins at toy size, here at the smoke's full width — at HIGHEST matmul
     precision, so that the convolutions between the tails are the same
     f32 function on both sides (the MXU's default rounds f32 operands to
     bf16, which turns the tails' last-bit differences into rounding flips

@@ -62,8 +62,8 @@ MODEL_FAMILIES = ("hourglass", "latent_moe_decoder")
 # Latency-tier presets (ISSUE 13): named architecture+serving bundles —
 # the product tiers the fleet router mixes per tenant. `--tier edge`
 # overrides the listed Config fields (tier wins over individual arch
-# flags, exactly as --preset sweep-best wins over step flags); everything
-# else stays at CLI/default values. Widths/stacks/variants come from the
+# flags); everything else stays at CLI/default values.
+# Widths/stacks/variants come from the
 # r15 arch_grid counting-model sweep (artifacts/r15/sweep.json) with the
 # quality tier pinned to the flagship stack2+soft-NMS recipe (0.7734
 # held-out mAP, r05). serve_buckets per tier = each tier's own AOT bucket
@@ -370,9 +370,9 @@ class Config:
     # composition), "fused" (the block's second BN, the skip-add and the
     # closing activation collapse into ONE custom_vjp pass family with
     # the analytic BN backward extended through the add,
-    # ops/pallas/residual.py — Pallas on TPU, the jnp twin elsewhere),
-    # "auto" = fused on TPU, xla elsewhere (the --epilogue gating).
-    # Eligibility per block: residual/depthwise variants (ghost's tail
+    # ops/pallas/epilogue.py — Pallas on TPU, the jnp twin elsewhere),
+    # "auto" = fused on TPU, xla elsewhere (ops/pallas/select.py decides
+    # for all four kernel fields). Eligibility per block: residual/depthwise variants (ghost's tail
     # is a concat of two separately-normalized halves), per-replica
     # unfolded BN, no quantization, closing activation in {Mish, ReLU,
     # Linear} — ineligible blocks silently keep the xla tail. Param/stat
@@ -469,15 +469,6 @@ class Config:
     summary: bool = True          # print a layer table at train start on
     # the chief (≡ reference torchsummary on rank 0, ref train.py:50;
     # --no-summary disables). Shape inference only — no device compute.
-    preset: str = ""              # "" | "sweep-best": override the
-    # step-compression train flags (batch-size, remat, loss-kernel,
-    # param-policy, epilogue, block-fuse, fwd-dtype[, amp]) from the
-    # newest committed
-    # `step_grid_selected` record in artifacts/*/sweep.json — the chip's
-    # own measured pick promoted to defaults (ISSUE 7 satellite). The
-    # preset WINS over individually-passed step flags (it is the "use
-    # what the sweep chose" button); errors loudly when no committed
-    # artifact carries a selection.
 
     def __post_init__(self):
         # pre-r7 compatibility: --remat was a boolean (Config(remat=True)
@@ -528,9 +519,6 @@ class Config:
                     "--grad-accum > 1 is host-input-path only: the fused "
                     "--device-augment step augments per batch and has no "
                     "micro-batch scan")
-        if self.preset not in ("", "sweep-best"):
-            raise ValueError("--preset must be '' or 'sweep-best', got %r"
-                             % (self.preset,))
         if self.family not in MODEL_FAMILIES:
             raise ValueError("--family must be one of %s, got %r"
                              % (MODEL_FAMILIES, self.family))
@@ -666,60 +654,10 @@ def parse_args(argv=None) -> Config:
                      if f.name in d})
 
 
-def sweep_best_overrides(repo_root: Optional[str] = None) -> dict:
-    """Step-compression flags from the newest committed sweep selection.
-
-    Scans artifacts/*/sweep.json for a `step_grid_selected` record (the
-    best-throughput cell of tpu_sweep's batch x remat x loss-kernel x
-    param-policy x epilogue x block-fuse x fwd-dtype grid) and maps it
-    onto Config field overrides.
-    Highest round wins — the committed artifact IS the promotion record,
-    so `--preset sweep-best` always tracks the chip's latest verdict.
-    Raises FileNotFoundError when no artifact carries a selection (a
-    fresh clone, or no chip round yet)."""
-    import glob
-    import re
-    root = repo_root or os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))
-    best = None
-    for path in glob.glob(os.path.join(root, "artifacts", "*",
-                                       "sweep.json")):
-        try:
-            with open(path) as f:
-                rec = json.load(f).get("step_grid_selected")
-        except (OSError, json.JSONDecodeError):
-            continue
-        if not rec or "batch" not in rec:
-            continue
-        m = re.search(r"r(\d+)",
-                      os.path.basename(os.path.dirname(path)))
-        key = int(m.group(1)) if m else -1
-        if best is None or key > best[0]:
-            best = (key, path, rec)
-    if best is None:
-        raise FileNotFoundError(
-            "--preset sweep-best: no artifacts/*/sweep.json carries a "
-            "step_grid_selected record — run tpu_sweep's step_grid "
-            "section (through tpu_queue.py) first")
-    _, path, rec = best
-    over = {"batch_size": int(rec["batch"]),
-            "remat": rec.get("remat", "none"),
-            "loss_kernel": rec.get("loss_kernel", "auto")}
-    # pre-ISSUE-7/-20 selections lack the newer axes: leave those fields
-    # at their CLI/default values rather than inventing a policy
-    for key in ("param_policy", "epilogue", "block_fuse", "fwd_dtype"):
-        if key in rec:
-            over[key] = rec[key]
-    if over.get("param_policy") == "bf16-compute":
-        over["amp"] = True  # the policy's own validity requirement
-    over["_source"] = os.path.relpath(path, root)
-    return over
-
-
 def cascade_overrides(repo_root: Optional[str] = None) -> dict:
     """Calibrated cascade operating point from the newest committed
-    `quality_matrix --cascade` artifact (the sweep_best_overrides idiom:
-    the committed artifact IS the promotion record, highest round wins).
+    `quality_matrix --cascade` artifact (the committed artifact IS the
+    promotion record, highest round wins).
 
     Scans artifacts/*/cascade.json for a `selected` record (threshold +
     the escalation-rate/blended-mAP evidence it was chosen on) and maps
@@ -819,24 +757,11 @@ def apply_streams(cfg: Config) -> Config:
     return dataclasses.replace(cfg, **over)
 
 
-def apply_preset(cfg: Config) -> Config:
-    """Resolve `--preset` into concrete Config fields (no-op when unset)."""
-    if not cfg.preset:
-        return cfg
-    over = sweep_best_overrides()
-    src = over.pop("_source")
-    print("--preset sweep-best: %s -> %s" % (src, over), flush=True)
-    return dataclasses.replace(cfg, **over)
-
-
 def apply_tier(cfg: Config) -> Config:
     """Resolve `--tier` into concrete Config fields (no-op when unset).
 
     The tier WINS over individually-passed architecture/serving flags —
-    it is the "give me the edge product" button, the exact semantics
-    --preset sweep-best has for the step-compression flags. Composes with
-    --preset (tier sets the architecture, the sweep pick sets the train
-    step)."""
+    it is the "give me the edge product" button."""
     if not cfg.tier:
         return cfg
     over = TIER_PRESETS[cfg.tier]
@@ -902,7 +827,6 @@ def get_config(argv=None) -> Config:
     eval-time architecture restore."""
     cfg = parse_args(argv)
     cfg = apply_tier(cfg)
-    cfg = apply_preset(cfg)
     cfg = apply_cascade(cfg)
     cfg = apply_streams(cfg)
     seed_everything(cfg.random_seed)

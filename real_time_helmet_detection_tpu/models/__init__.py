@@ -3,7 +3,6 @@ from .hourglass import (
     Activation,
     Convolution,
     FusedBNAct,
-    FusedBNAddAct,
     Head,
     Hourglass,
     Neck,
@@ -11,8 +10,6 @@ from .hourglass import (
     PreLayer,
     QuantConv,
     Residual,
-    resolve_block_fuse,
-    resolve_epilogue,
     SPP,
     StackedHourglass,
     STEConv,
@@ -40,9 +37,6 @@ __all__ = [
     "build_model",
     "Convolution",
     "FusedBNAct",
-    "FusedBNAddAct",
-    "resolve_block_fuse",
-    "resolve_epilogue",
     "QuantConv",
     "Head",
     "Hourglass",

@@ -22,6 +22,7 @@ cross-replica sync-BN, a capability the reference lacks.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Sequence
 
 import jax
@@ -30,7 +31,7 @@ import flax.linen as nn
 
 from ..ops.pallas.epilogue import (FUSED_EPILOGUE_ACTIVATIONS, fused_bn_act,
                                    fused_bn_act_train)
-from ..ops.pallas.residual import fused_bn_add_act_train
+from ..ops.pallas.select import kernel_plan
 from ..ops.quant import (make_ste_conv, quantize_activations,
                          quantize_weights)
 
@@ -42,16 +43,15 @@ Dtype = Any
 # collection; "int8" = int8 conv bodies consuming the calibrated scales.
 QUANT_MODES = ("off", "calibrate", "int8")
 
-# conv epilogue implementations (--epilogue; ISSUE 7): "xla" = the
-# nn.BatchNorm + Activation composition (the pre-PR program, bit-exact),
-# "fused" = the one-pass BN-normalize+activation epilogue
-# (ops/pallas/epilogue.py) where eligible.
+# conv epilogue implementations (--epilogue): "xla" = the nn.BatchNorm +
+# Activation composition, "fused" = the one-pass BN-normalize+activation
+# epilogue (ops/pallas/epilogue.py) where eligible.
 EPILOGUE_MODES = ("xla", "fused")
 
-# residual-block TAIL implementations (--block-fuse; ISSUE 20): "xla" =
-# per-conv epilogue + XLA skip-add + Activation (the pre-PR composition,
-# bit-exact), "fused" = BN + skip-add + closing activation collapsed into
-# one custom_vjp pass family (ops/pallas/residual.py) where eligible.
+# residual-block TAIL implementations (--block-fuse): "xla" = per-conv
+# epilogue + XLA skip-add + Activation, "fused" = BN + skip-add + closing
+# activation collapsed into the same custom_vjp pass family, the skip as
+# its fourth operand, where eligible.
 BLOCK_FUSE_MODES = ("xla", "fused")
 
 # train-time forward conv compute dtypes (--fwd-dtype; ISSUE 20): "bf16"
@@ -69,30 +69,6 @@ FWD_DTYPES = ("bf16", "int8")
 # every tier for free — the BN tree keeps the Conv_0+BatchNorm_0 sibling
 # shape throughout.
 VARIANTS = ("residual", "depthwise", "ghost")
-
-
-def resolve_epilogue(cfg) -> str:
-    """'fused' | 'xla' for this backend: --epilogue auto selects the
-    fused BN+activation epilogue on TPU only, exactly as --loss-kernel
-    gates the fused loss (off-TPU 'fused' runs the jnp recompute twin —
-    test/attribution contexts select it explicitly)."""
-    mode = getattr(cfg, "epilogue", "auto")
-    if mode == "auto":
-        import jax
-        return "fused" if jax.default_backend() == "tpu" else "xla"
-    return mode
-
-
-def resolve_block_fuse(cfg) -> str:
-    """'fused' | 'xla' for this backend: --block-fuse auto selects the
-    fused residual-block tail on TPU only, exactly as --epilogue gates
-    the per-conv epilogue (off-TPU 'fused' runs the jnp recompute twin —
-    test/attribution contexts select it explicitly)."""
-    mode = getattr(cfg, "block_fuse", "auto")
-    if mode == "auto":
-        import jax
-        return "fused" if jax.default_backend() == "tpu" else "xla"
-    return mode
 
 
 def mish(x: jax.Array) -> jax.Array:
@@ -331,16 +307,21 @@ class STEConv(nn.Module):
 
 
 class FusedBNAct(nn.Module):
-    """BatchNorm + activation with the normalize+activation chain collapsed
-    into ONE pointwise pass (ops/pallas/epilogue.py; `--epilogue fused`):
-    a Pallas custom_vjp family in the train step, a plain expression that
-    XLA fuses into the conv at eval.
+    """BatchNorm (+ skip-add) + activation with the normalize(+add)
+    +activation chain collapsed into ONE pointwise pass
+    (ops/pallas/epilogue.py; `--epilogue fused`, and `--block-fuse fused`
+    for a residual block's tail, which passes the block's other branch as
+    `skip`): a Pallas custom_vjp family in the train step, a plain
+    expression that XLA fuses into the conv at eval.
 
     Param and batch_stats trees are IDENTICAL to
     `nn.BatchNorm(momentum=0.9, epsilon=1e-5)` and the block instantiates
     it under the same "BatchNorm_0" name, so checkpoints interchange
-    across every --epilogue mode and `ops.quant.fold_batchnorm` folds
-    this block exactly as it folds nn.BatchNorm (regression-tested).
+    across every --epilogue / --block-fuse mode and
+    `ops.quant.fold_batchnorm` folds this block exactly as it folds
+    nn.BatchNorm (regression-tested). Batch moments are of the BN input x
+    ALONE — the skip never enters the statistics, exactly as in the
+    unfused composition.
 
     The statistics stay in XLA (they are reductions, computed in f32 with
     flax's formulas: mean, E[x^2]-E[x]^2 clamped at 0, and the same
@@ -358,7 +339,8 @@ class FusedBNAct(nn.Module):
     dtype: Optional[Dtype] = None
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False,
+                 skip: Optional[jax.Array] = None) -> jax.Array:
         feat = x.shape[-1]
         ra_mean = self.variable("batch_stats", "mean",
                                 lambda: jnp.zeros((feat,), jnp.float32))
@@ -369,15 +351,17 @@ class FusedBNAct(nn.Module):
         bias = self.param("bias", nn.initializers.zeros_init(), (feat,),
                           jnp.float32)
         if train:
-            # moments + normalize + activation + the ANALYTIC BN backward
-            # all live inside ONE custom_vjp (ops/pallas/epilogue.py) —
-            # XLA never autodiffs through the statistics, so no f32
-            # activation copies or backward-through-stats chains exist in
-            # the program. The returned batch moments feed ONLY the
-            # running buffers, stop_gradient'd exactly as flax BatchNorm
-            # treats them (the custom_vjp drops their zero cotangents).
+            # moments + normalize (+ add) + activation + the ANALYTIC BN
+            # backward, with the skip's pass-through gradient, all live
+            # inside ONE custom_vjp (ops/pallas/epilogue.py) — XLA never
+            # autodiffs through the statistics, so no f32 activation
+            # copies, no materialized sum and no backward-through-stats
+            # chains exist in the program. The returned batch moments
+            # feed ONLY the running buffers, stop_gradient'd exactly as
+            # flax BatchNorm treats them (the custom_vjp drops their zero
+            # cotangents).
             out, mean, var = fused_bn_act_train(
-                x, scale, bias, eps=self.epsilon,
+                x, scale, bias, skip, eps=self.epsilon,
                 activation=self.activation)
             if not self.is_initializing():
                 m = self.momentum
@@ -389,58 +373,6 @@ class FusedBNAct(nn.Module):
         # eval: running statistics fold into the per-channel affine (the
         # PR 5 fold algebra); the tail is a plain pointwise expression
         # that XLA fuses into the conv that produced x
-        eff_scale = scale * jax.lax.rsqrt(ra_var.value + self.epsilon)
-        eff_bias = bias - ra_mean.value * eff_scale
-        return fused_bn_act(x, eff_scale, eff_bias,
-                            activation=self.activation)
-
-
-class FusedBNAddAct(nn.Module):
-    """BatchNorm + skip-add + activation with the whole residual-block
-    TAIL collapsed into ONE pass family (ops/pallas/residual.py;
-    `--block-fuse fused`, ISSUE 20).
-
-    The FusedBNAct contract, extended through the add: param and
-    batch_stats trees are IDENTICAL to `nn.BatchNorm(momentum=0.9,
-    epsilon=1e-5)` and the block instantiates it under the same
-    "BatchNorm_0" name inside the tail conv's scope, so checkpoints
-    interchange across every --block-fuse/--epilogue mode and
-    `ops.quant.fold_batchnorm` folds this block exactly as it folds
-    nn.BatchNorm (regression-tested). Batch moments are of the BN input
-    x ALONE — the skip never enters the statistics, exactly as in the
-    unfused composition — and the custom_vjp's analytic backward carries
-    the skip's pass-through gradient, so XLA never materializes the
-    normalized tensor, the sum, or backward-through-stats chains. At eval
-    (`train=False`) the tail is `fused_bn_act` with the skip: a plain
-    expression XLA fuses into the conv, as in `FusedBNAct`."""
-    activation: str = "Mish"
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-    dtype: Optional[Dtype] = None
-
-    @nn.compact
-    def __call__(self, x: jax.Array, skip: jax.Array,
-                 train: bool = False) -> jax.Array:
-        feat = x.shape[-1]
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((feat,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((feat,), jnp.float32))
-        scale = self.param("scale", nn.initializers.ones_init(), (feat,),
-                           jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros_init(), (feat,),
-                          jnp.float32)
-        if train:
-            out, mean, var = fused_bn_add_act_train(
-                x, scale, bias, skip, eps=self.epsilon,
-                activation=self.activation)
-            if not self.is_initializing():
-                m = self.momentum
-                mean = jax.lax.stop_gradient(mean)
-                var = jax.lax.stop_gradient(var)
-                ra_mean.value = m * ra_mean.value + (1.0 - m) * mean
-                ra_var.value = m * ra_var.value + (1.0 - m) * var
-            return out
         eff_scale = scale * jax.lax.rsqrt(ra_var.value + self.epsilon)
         eff_bias = bias - ra_mean.value * eff_scale
         return fused_bn_act(x, eff_scale, eff_bias, skip,
@@ -459,8 +391,8 @@ class Convolution(nn.Module):
     the first/last-layer rule, and their contractions are not where the
     roofline says the time is).
 
-    `epilogue="fused"` (ISSUE 7) swaps the nn.BatchNorm + Activation tail
-    for the one-pass `FusedBNAct` where ELIGIBLE: the conv has a BN that
+    `epilogue="fused"` swaps the nn.BatchNorm + Activation tail for the
+    one-pass `FusedBNAct` where ELIGIBLE: the conv has a BN that
     is not being folded away, the activation has a recomputable closed
     form (Mish/ReLU/Linear — FUSED_EPILOGUE_ACTIVATIONS), and BN is
     per-replica (cross-replica sync-BN keeps the XLA path: its stats
@@ -468,11 +400,12 @@ class Convolution(nn.Module):
     xla path — the decision table lives in docs/ARCHITECTURE.md "Step
     compression".
 
-    A non-None `skip` (ISSUE 20; `--block-fuse fused`, passed ONLY by
-    `Residual` on its tail conv) extends that tail through the
-    skip-add: `FusedBNAddAct` computes BN + add + activation in one pass
-    family with the skip's pass-through gradient. Eligibility is the
-    caller's job; this block only enforces the contract.
+    A non-None `skip` (`--block-fuse fused`, passed ONLY by `Residual`
+    on its tail conv) extends that tail through the skip-add:
+    `FusedBNAct` computes BN + add + activation in one pass family with
+    the skip's pass-through gradient, whatever `epilogue` says.
+    Eligibility is the caller's job; this block only enforces the
+    contract.
 
     `fwd_dtype="int8"` (ISSUE 20) swaps the TRAIN-mode conv body for
     `STEConv` (int8 MXU forward, straight-through float backward) where
@@ -546,22 +479,15 @@ class Convolution(nn.Module):
                         use_bias=self.use_bias or fold,
                         dtype=self.dtype)(x)
         if self.bn and not self.fold_bn:
-            if skip is not None:
-                # block-fused tail: BN + skip-add + closing activation in
-                # one custom_vjp family; same "BatchNorm_0" name as the
-                # nn.BatchNorm auto-name, so the param tree (and every
-                # checkpoint) is identical whichever tail computes it
-                return FusedBNAddAct(activation=self.activation,
-                                     dtype=self.dtype,
-                                     name="BatchNorm_0")(x, skip, train)
-            if (self.epilogue == "fused" and self.bn_axis_name is None
+            if skip is not None or (
+                    self.epilogue == "fused" and self.bn_axis_name is None
                     and self.activation in FUSED_EPILOGUE_ACTIVATIONS):
                 # same "BatchNorm_0" name as the nn.BatchNorm auto-name:
                 # the param tree (and every checkpoint) is identical
-                # whichever epilogue computes it
+                # whichever tail computes it
                 return FusedBNAct(activation=self.activation,
                                   dtype=self.dtype,
-                                  name="BatchNorm_0")(x, train)
+                                  name="BatchNorm_0")(x, train, skip=skip)
             x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
                              epsilon=1e-5, dtype=self.dtype,
                              axis_name=self.bn_axis_name)(x)
@@ -622,17 +548,17 @@ class Residual(nn.Module):
     the block's I/O contract (and the surrounding Hourglass geometry)
     never changes.
 
-    `block_fuse="fused"` (ISSUE 20) collapses the block TAIL — the last
-    conv's BN, the skip-add and the post-add activation — into one
-    custom_vjp pass family (ops/pallas/residual.py via `FusedBNAddAct`)
-    where ELIGIBLE: residual/depthwise variants (ghost's tail is a
-    concat of two separately-normalized GhostModule halves — there is no
-    single BN feeding the add), no quantization/folding, per-replica BN,
-    post-add activation in FUSED_EPILOGUE_ACTIVATIONS. Ineligible blocks
-    silently keep the xla tail (bit-exact pre-PR program). The fused
-    branch names its children explicitly to match the unfused branch's
-    auto-names — flax derives param RNGs and tree keys from the module
-    PATH, so the trees (values included) are identical and checkpoints
+    `block_fuse="fused"` collapses the block TAIL — the last conv's BN,
+    the skip-add and the post-add activation — into one custom_vjp pass
+    family (`FusedBNAct` with a skip) where ELIGIBLE: residual/depthwise
+    variants (ghost's tail is a concat of two separately-normalized
+    GhostModule halves — there is no single BN feeding the add), no
+    quantization/folding, per-replica BN, post-add activation in
+    FUSED_EPILOGUE_ACTIVATIONS. Ineligible blocks silently keep the xla
+    tail. Children are named explicitly, with the names flax's per-class
+    auto-numbering would give them in call order (body, then skip): flax
+    derives param RNGs and tree keys from the module PATH, so the trees
+    (values included) are identical whichever tail runs and checkpoints
     interchange (tested)."""
     out_ch: int
     kernel_size: int = 3
@@ -659,85 +585,46 @@ class Residual(nn.Module):
                      and self.quant_mode == "off" and not self.fold_bn
                      and self.bn_axis_name is None
                      and self.activation in FUSED_EPILOGUE_ACTIVATIONS)
-        if fuse_tail:
-            return self._fused(x, train, kw)
+        # the fused tail conv carries the POST-ADD activation (unfused it
+        # is Linear and the activation sits after the add)
+        act = self.activation
+        tail_act = act if fuse_tail else "Linear"
+        conv = functools.partial(Convolution, use_bias=False, bn=True, **kw)
+        k = self.kernel_size
         if self.variant == "depthwise":
             in_ch = x.shape[-1]
-            y = Convolution(in_ch, self.kernel_size, self.stride,
-                            use_bias=False, bn=True,
-                            activation=self.activation, groups=in_ch,
-                            **kw)(x, train)
-            y = Convolution(self.out_ch, 1, 1, use_bias=False, bn=True,
-                            activation=self.activation, **kw)(y, train)
-            y = Convolution(self.out_ch, self.kernel_size, 1,
-                            use_bias=False, bn=True,
-                            activation=self.activation,
-                            groups=self.out_ch, **kw)(y, train)
-            y = Convolution(self.out_ch, 1, 1, use_bias=False, bn=True,
-                            activation="Linear", **kw)(y, train)
+            y = conv(in_ch, k, self.stride, activation=act, groups=in_ch,
+                     name="Convolution_0")(x, train)
+            y = conv(self.out_ch, 1, 1, activation=act,
+                     name="Convolution_1")(y, train)
+            y = conv(self.out_ch, k, 1, activation=act, groups=self.out_ch,
+                     name="Convolution_2")(y, train)
+            tail = conv(self.out_ch, 1, 1, activation=tail_act,
+                        name="Convolution_3")
+            skip_name = "Convolution_4"
         elif self.variant == "ghost":
-            y = GhostModule(self.out_ch, self.kernel_size, self.stride,
-                            activation=self.activation, **kw)(x, train)
-            y = GhostModule(self.out_ch, self.kernel_size, 1,
-                            activation="Linear", **kw)(y, train)
+            y = GhostModule(self.out_ch, k, self.stride, activation=act,
+                            name="GhostModule_0", **kw)(x, train)
+            tail = GhostModule(self.out_ch, k, 1, activation="Linear",
+                               name="GhostModule_1", **kw)
+            skip_name = "Convolution_0"
         elif self.variant == "residual":
-            y = Convolution(self.out_ch, self.kernel_size, self.stride,
-                            use_bias=False, bn=True,
-                            activation=self.activation, **kw)(x, train)
-            y = Convolution(self.out_ch, self.kernel_size, self.stride,
-                            use_bias=False, bn=True, activation="Linear",
-                            **kw)(y, train)
+            y = conv(self.out_ch, k, self.stride, activation=act,
+                     name="Convolution_0")(x, train)
+            tail = conv(self.out_ch, k, self.stride, activation=tail_act,
+                        name="Convolution_1")
+            skip_name = "Convolution_2"
         else:
             raise NotImplementedError("Not expected variant: %s"
                                       % self.variant)
+        # the skip branch is computed BEFORE the tail conv is applied, so
+        # that it can feed the fused pass
         if x.shape[-1] != self.out_ch:
-            x = Convolution(self.out_ch, 1, self.stride, use_bias=False,
-                            bn=True, activation="Linear", **kw)(x, train)
-        return Activation(self.activation)(y + x)
-
-    def _fused(self, x: jax.Array, train: bool, kw: dict) -> jax.Array:
-        """Fused-tail body (still inside the compact __call__ context).
-
-        The SKIP branch is computed BEFORE the tail conv so it can feed
-        the fused pass, but keeps its unfused auto-name (body convs take
-        Convolution_0..n-1, the skip takes Convolution_n) so the param
-        tree — and the path-derived init RNGs — are bit-identical to the
-        xla composition. The tail Convolution carries the POST-ADD
-        activation (the unfused tail is Linear and the activation sits
-        after the add; fusing folds it into the same pass)."""
-        if self.variant == "depthwise":
-            in_ch = x.shape[-1]
-            y = Convolution(in_ch, self.kernel_size, self.stride,
-                            use_bias=False, bn=True,
-                            activation=self.activation, groups=in_ch,
-                            name="Convolution_0", **kw)(x, train)
-            y = Convolution(self.out_ch, 1, 1, use_bias=False, bn=True,
-                            activation=self.activation,
-                            name="Convolution_1", **kw)(y, train)
-            y = Convolution(self.out_ch, self.kernel_size, 1,
-                            use_bias=False, bn=True,
-                            activation=self.activation,
-                            groups=self.out_ch,
-                            name="Convolution_2", **kw)(y, train)
-            tail = Convolution(self.out_ch, 1, 1, use_bias=False,
-                               bn=True, activation=self.activation,
-                               name="Convolution_3", **kw)
-            skip_name = "Convolution_4"
-        else:  # residual
-            y = Convolution(self.out_ch, self.kernel_size, self.stride,
-                            use_bias=False, bn=True,
-                            activation=self.activation,
-                            name="Convolution_0", **kw)(x, train)
-            tail = Convolution(self.out_ch, self.kernel_size,
-                               self.stride, use_bias=False, bn=True,
-                               activation=self.activation,
-                               name="Convolution_1", **kw)
-            skip_name = "Convolution_2"
-        if x.shape[-1] != self.out_ch:
-            x = Convolution(self.out_ch, 1, self.stride, use_bias=False,
-                            bn=True, activation="Linear",
-                            name=skip_name, **kw)(x, train)
-        return tail(y, train, skip=x)
+            x = conv(self.out_ch, 1, self.stride, activation="Linear",
+                     name=skip_name)(x, train)
+        if fuse_tail:
+            return tail(y, train, skip=x)
+        return Activation(act)(tail(y, train) + x)
 
 
 def _upsample_nearest_2x(x: jax.Array) -> jax.Array:
@@ -923,8 +810,8 @@ class StackedHourglass(nn.Module):
     # ops/pallas/epilogue.py kernel where eligible; see Convolution)
     block_fuse: str = "xla"  # residual-block tail: "xla" (per-conv
     # epilogue + XLA add + Activation) | "fused" (BN + skip-add +
-    # activation in one ops/pallas/residual.py pass family where
-    # eligible; see Residual). ISSUE 20.
+    # activation in one ops/pallas/epilogue.py pass family where
+    # eligible; see Residual)
     fwd_dtype: str = "bf16"  # train-time forward conv compute dtype:
     # "bf16" | "int8" (STEConv where eligible; see Convolution). ISSUE 20.
 
@@ -983,6 +870,7 @@ def build_model(args_or_cfg, dtype: Optional[Dtype] = None,
     in `calibrate`/`int8` modes — the quantization machinery in place of
     the folded conv bodies. Training models never set these."""
     c = args_or_cfg
+    plan = kernel_plan(c)
     if quant_mode not in QUANT_MODES:
         raise ValueError("quant_mode must be one of %s, got %r"
                          % (QUANT_MODES, quant_mode))
@@ -1011,7 +899,7 @@ def build_model(args_or_cfg, dtype: Optional[Dtype] = None,
         fold_bn=fold_bn,
         quant_mode=quant_mode,
         calib_percentile=calib_percentile,
-        epilogue=resolve_epilogue(c),
-        block_fuse=resolve_block_fuse(c),
+        epilogue=plan["epilogue"],
+        block_fuse=plan["block_fuse"],
         fwd_dtype=getattr(c, "fwd_dtype", "bf16"),
     )

@@ -399,7 +399,7 @@ class MetricsWriter:
         self.enabled = self.path is not None
         self.period_s = max(0.0, float(period_s))
         self._f = None
-        self._last_flush = 0.0
+        self._last_flush = None  # never: the first flush always lands
         self._lock = threading.Lock()
 
     def maybe_flush(self, force: bool = False) -> bool:
@@ -414,7 +414,8 @@ class MetricsWriter:
             # writer lock: an unlocked fast-path read raced the disable
             if not self.enabled:
                 return False
-            if not force and now - self._last_flush < self.period_s:
+            if (not force and self._last_flush is not None
+                    and now - self._last_flush < self.period_s):
                 return False
             self._last_flush = now
             try:

@@ -24,10 +24,11 @@ from jax import lax
 
 from ..parallel.experts import ExpertShare
 from .pallas import expert_gmm as gmm
+from .pallas import select
 
 
 def kernel_compiles() -> bool:
-    return jax.default_backend() == "tpu"
+    return select.on_chip()
 
 
 def route(hn, w_router, b_select, per_token: int, norm_weights: bool,

@@ -3,9 +3,7 @@
 from .epilogue import FUSED_EPILOGUE_ACTIVATIONS, fused_bn_act
 from .loss import fused_detection_loss, fused_stack_loss_sums
 from .peak import fused_peak_scores, peak_scores_reference
-from .residual import fused_bn_add_act_train
 
 __all__ = ["FUSED_EPILOGUE_ACTIVATIONS", "fused_bn_act",
-           "fused_bn_add_act_train",
            "fused_detection_loss", "fused_stack_loss_sums",
            "fused_peak_scores", "peak_scores_reference"]

@@ -1,12 +1,17 @@
-"""BatchNorm + activation tail of every conv: Pallas kernels for training,
-a plain expression at eval.
+"""BatchNorm (+ skip-add) + activation tail of every conv: Pallas kernels
+for training, a plain expression at eval.
 
 Every conv in this architecture is followed by `BatchNorm -> activation`
 (models/hourglass.py `Convolution`, ref /root/reference/hourglass.py:94-108
-`Convolution`: conv -> BN -> act). Both modes fold the statistics into a
-per-channel affine, `eff_scale = gamma * rsqrt(var + eps)` and `eff_bias =
-beta - mean * eff_scale` (the BN-fold algebra of ops/quant.fold_batchnorm),
-and compute `act(x * eff_scale + eff_bias)` in f32.
+`Convolution`: conv -> BN -> act), and every residual block ends with
+`BatchNorm -> (+ skip) -> activation` (`Residual`, ref :111-131). Both
+modes fold the statistics into a per-channel affine, `eff_scale = gamma *
+rsqrt(var + eps)` and `eff_bias = beta - mean * eff_scale` (the BN-fold
+algebra of ops/quant.fold_batchnorm), and compute `act(x * eff_scale +
+eff_bias [+ skip])` in f32. The skip is an optional operand of one family,
+in both modes: it never enters the statistics (BatchNorm sees only the
+conv's output, as in the unfused composition), it shifts the
+pre-activation, and its gradient is the pass-through `ds = dz`.
 
 **Eval** (`fused_bn_act`): the running statistics make `eff_scale` /
 `eff_bias` constants, so the tail is a pointwise epilogue of the conv
@@ -21,13 +26,13 @@ layout and the kernel's (N, H*W, C) tiles (PERF.md section 6, PR 26).
 over x, a real barrier, so the chain is one `jax.custom_vjp` family:
 
 * the forward computes batch moments in f32, then `act(x * eff_scale +
-  eff_bias)` reading x once and writing the activation once — all f32
-  math lives in VMEM/registers, no materialized converts, no saved
-  residuals;
+  eff_bias [+ skip])` reading x (and the skip) once and writing the
+  activation once — all f32 math lives in VMEM/registers, no
+  materialized converts, no saved residuals;
 * the backward is the ANALYTIC BatchNorm+activation gradient
   (`_make_fused_train`), recomputing the forward terms from the same
   inputs (the ops/pallas/loss.py pattern): one pass for the per-channel
-  sums, one for d(x);
+  sums, one for d(x) (and d(skip));
 * layout: `(N, H, W, C) -> (N, H*W, C)`; rows block over the sublane
   axis, channels sit on the 128-wide lane axis — C=128 (the flagship
   width) fills v5e tiles exactly. (The reshape is free in row-major
@@ -37,18 +42,15 @@ over x, a real barrier, so the chain is one `jax.custom_vjp` family:
 Off-TPU, `interpret=None` (the production default) selects a pure-jnp
 custom_vjp twin of the train family built from the SAME math helpers
 instead of Pallas interpret mode: identical semantics and identical
-recompute structure, so CPU tests run fast and scripts/roofline.py's
-operand+result counting model sees the real traffic shape of the fused
-path (the interpret lowering's dynamic-slice machinery would be counted
-as garbage — the same honesty problem loss_subprogram_cost solves
-analytically). Pass interpret=True to force the Pallas kernel in
-interpret mode (the parity tests do).
+recompute structure, so CPU tests run fast. Pass interpret=True to force
+the Pallas kernels in interpret mode (the parity tests do).
 
-Selection is `--epilogue {auto,fused,xla}` (config.py), auto = fused on
-TPU only, mirroring `--loss-kernel`; eligibility rules live in
-models/hourglass.py `Convolution` (docs/ARCHITECTURE.md "Step
-compression"). Parity vs the XLA composition is pinned in fp32 and bf16
-by tests/test_epilogue.py.
+Selection is `--epilogue` / `--block-fuse {auto,fused,xla}` (config.py;
+auto = fused on the chip only, ops/pallas/select.py); eligibility lives in
+models/hourglass.py: `Convolution` decides the per-conv tail, `Residual`
+whether its last conv's tail takes the skip (docs/ARCHITECTURE.md "Step
+compression"). Parity vs the XLA composition is pinned in fp32 and bf16 by
+tests/test_bn_tail.py.
 """
 
 from __future__ import annotations
@@ -61,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .partition import batch_parallel
+from . import select
 
 # Activations the fused epilogue supports. Everything on this list has a
 # cheap closed-form derivative recomputable from the pre-activation value
@@ -69,38 +72,12 @@ from .partition import batch_parallel
 FUSED_EPILOGUE_ACTIVATIONS = ("Mish", "ReLU", "Linear")
 
 _BLOCK_ELEMS_CAP = 1024 * 128  # elements per row block: 512 KB in f32, so
-# the widest kernel (dx pass: three inputs, two outputs, double-buffered,
-# plus the Mish temporaries) stays well inside v5e's 16 MiB scoped VMEM at
-# any channel width — the row count shrinks as `increase_ch` widens C
+# the widest kernel (dx pass with a skip: three inputs, two outputs,
+# double-buffered, plus the Mish temporaries) stays well inside v5e's 16
+# MiB scoped VMEM at any channel width — the row count shrinks as
+# `increase_ch` widens C
 
 X, VEC, PART = "x", "vec", "part"  # operand kinds of `_rows_call`
-
-# Trace-time call-site registry (scripts/roofline.py's analytic counting
-# of the fused train path off-TPU): every fused_bn_act_train call appends
-# (elems, itemsize) while tracing. Appending is a pure host-side side
-# effect — the traced program (and so the graftlint retrace signature) is
-# unaffected.
-_TRACE_SITES: list = []
-
-
-def reset_site_registry() -> None:
-    _TRACE_SITES.clear()
-
-
-def traced_sites() -> list:
-    """[(n_elements, itemsize_bytes), ...] of every train-mode epilogue
-    call traced since the last reset."""
-    return list(_TRACE_SITES)
-
-
-def site_kernel_bytes(elems: int, itemsize: int) -> float:
-    """Operand+result HBM bytes of the REAL kernel sequence for one
-    train-mode epilogue site (the same counting rule scripts/roofline.py
-    applies to every other op; C-sized vectors/partials are negligible
-    and ignored): stats pass reads x; fwd pass reads x, writes out;
-    backward sums pass reads (x, g); backward dx pass reads (x, g),
-    writes dx -> 8 activation-sized transfers."""
-    return 8.0 * elems * itemsize
 
 
 def _act_fwd(z: jax.Array, act: str) -> jax.Array:
@@ -148,43 +125,38 @@ def _row_block(rows: int, c: int) -> int:
     return rows
 
 
-def _fwd_kernel(x_ref, a_ref, b_ref, o_ref, *, act: str):
-    x = x_ref[...].astype(jnp.float32)        # (R, C)
-    z = x * a_ref[...] + b_ref[...]           # (1, C) broadcasts over rows
-    o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
-
-
-def _resolve_pallas(interpret: bool | None):
-    if interpret is not None:
-        return True, bool(interpret)
-    return jax.default_backend() == "tpu", False
-
-
 @functools.lru_cache(maxsize=None)
 def _make_fused_train(act: str, eps: float, use_pallas: bool,
-                      interpret: bool):
-    """custom_vjp'd train-mode BN+act over (x3 (N, R, C), gamma (1, C) f32,
-    beta (1, C) f32) -> (out, mean (C,), var (C,)).
+                      interpret: bool, has_skip: bool):
+    """custom_vjp'd train-mode tail over (x3 (N, R, C), gamma (1, C) f32,
+    beta (1, C) f32[, s3 (N, R, C)]) -> (out, mean (C,), var (C,)); the
+    skip is the fourth operand when `has_skip`.
 
-    Forward: batch moments in f32 (two-pass variance — E[(x-mean)^2]
-    fuses into the reduction read, no materialized f32 copy or x^2), then
-    the one-pass `act(x*a + b)` with the fold algebra's a/b.
+    Forward: batch moments of x ALONE in f32 (two-pass variance —
+    E[(x-mean)^2] fuses into the reduction read, no materialized f32 copy
+    or x^2; the skip never enters the statistics), then the one-pass
+    `act(x*a + b [+ s])` with the fold algebra's a/b.
 
     Backward: the ANALYTIC BatchNorm+activation gradient, not XLA
     autodiff — the whole backward-through-statistics chain collapses to
     two per-channel sums S1 = sum(dz), S2 = sum(dz*x) plus ONE pointwise
     pass `dx = a*dz - k2*x - k1` with per-channel constants:
 
-        z  = a*(x - mean) + beta,  a = gamma*rsqrt(var + eps)
+        z  = a*(x - mean) + beta [+ s],  a = gamma*rsqrt(var + eps)
         dz = g * act'(z)
+        ds = dz                                  (pass-through)
         dgamma = rsqrt(var+eps) * (S2 - mean*S1),  dbeta = S1
         k2 = a*(S2 - mean*S1) / ((var+eps)*N),  k1 = a*S1/N - k2*mean
         dx = a*dz - k2*x - k1
 
-    The (mean, var) outputs exist ONLY to feed the running-statistics
-    buffers (the module stop_gradients them), so their cotangents are
-    structurally zero and the backward drops them — exactly flax
-    BatchNorm's semantics (running stats never carry gradient)."""
+    The skip shifts z but is affine in both operands, so the statistics
+    terms are untouched by it. The (mean, var) outputs exist ONLY to feed
+    the running-statistics buffers (the module stop_gradients them), so
+    their cotangents are structurally zero and the backward drops them —
+    exactly flax BatchNorm's semantics (running stats never carry
+    gradient)."""
+    tag = "bn_add_act" if has_skip else "bn_act"  # the kernels' `name=`s:
+    # benchmark/metrics_lib.is_bn_tail_kernel selects trace events by them
 
     def _colsum(m2):
         """Per-channel sum of a (rows, C) array, f32-accumulated, reading
@@ -222,23 +194,29 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
     # each one, which is exactly the traffic being removed (measured: it
     # doubled the flagship convert class). On TPU none of this exists —
     # the kernels read bf16 and keep f32 in registers.
-    def jnp_fwd(x3, gamma2, beta2):
+    def jnp_preact(xf, a, b, skip):
+        z = xf * a + b
+        return z + skip[0].astype(jnp.float32) if skip else z
+
+    def jnp_fwd(x3, gamma2, beta2, *skip):
         n, rows, c = x3.shape
         xf = x3.astype(jnp.float32)
         mean, var = moments(xf.reshape(n * rows, c), n * rows)
         a, b = coeffs(gamma2, beta2, mean, var)
-        return _act_fwd(xf * a + b, act).astype(x3.dtype), mean, var
+        out = _act_fwd(jnp_preact(xf, a, b, skip), act)
+        return out.astype(x3.dtype), mean, var
 
-    def jnp_bwd_math(x3, gamma2, beta2, mean, var, g):
+    def jnp_bwd_math(x3, gamma2, beta2, skip, mean, var, g):
         n, rows, c = x3.shape
         count = n * rows
         r2 = 1.0 / (var + eps)                     # (C,) f32
         a = gamma2 * jnp.sqrt(r2)                  # (1, C)
         b = beta2 - mean * a
         xf = x3.astype(jnp.float32)
-        # dz materializes ONCE (consumers: the two channel sums and the
-        # dx pass); everything else recomputes from xf
-        dz = g.astype(jnp.float32) * _act_grad(xf * a + b, act)
+        # dz materializes ONCE (consumers: the two channel sums, the dx
+        # pass and the dskip cast); everything else recomputes from xf
+        dz = g.astype(jnp.float32) * _act_grad(
+            jnp_preact(xf, a, b, skip), act)
         dz2 = dz.reshape(count, c)
         xf2 = xf.reshape(count, c)
         s1 = _colsum(dz2)                          # (C,)
@@ -249,9 +227,10 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
         k2 = a * ctr * r2 / count
         k1 = a * s1 / count - k2 * mean
         dx = (a * dz - k2 * xf - k1).astype(x3.dtype)
-        return dx, dgamma, dbeta
+        return (dx, dgamma, dbeta) + tuple(dz.astype(s3.dtype)
+                                           for s3 in skip)
 
-    def pallas_fwd(x3, gamma2, beta2):
+    def pallas_fwd(x3, gamma2, beta2, *skip):
         n, rows, _ = x3.shape
         s, ss = _rows_call(
             _stats_kernel, "bn_stats", [(X, x3)],
@@ -261,21 +240,24 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
         var = jnp.maximum(_total(ss) / count - jnp.square(mean), 0.0)
         a, b = coeffs(gamma2, beta2, mean, var)
         out = _rows_call(
-            functools.partial(_fwd_kernel, act=act), "bn_act_fwd",
-            [(X, x3), (VEC, a), (VEC, b)], [(X, x3.dtype)], interpret)
+            functools.partial(_fwd_kernel, act=act, has_skip=has_skip),
+            tag + "_fwd",
+            [(X, x3), (VEC, a), (VEC, b)] + [(X, s3) for s3 in skip],
+            [(X, x3.dtype)], interpret)
         return out, mean, var
 
-    def pallas_bwd(x3, gamma2, beta2, mean, var, g):
+    def pallas_bwd(x3, gamma2, beta2, skip, mean, var, g):
         n, rows, _ = x3.shape
         count = float(n * rows)
         r2 = 1.0 / (var + eps)
         a = gamma2 * jnp.sqrt(r2)
         b = beta2 - mean * a
-        # pass 1: recompute dz from (x, g), emit S1/S2 partials only —
-        # dz itself never touches HBM
+        recompute = [(X, x3), (VEC, a), (VEC, b)] + [(X, s3) for s3 in skip]
+        # pass 1: recompute dz from (x[, skip], g), emit S1/S2 partials
+        # only — dz itself never touches HBM
         s1_p, s2_p = _rows_call(
-            functools.partial(_bwd_sums_kernel, act=act), "bn_act_bwd_sums",
-            [(X, x3), (VEC, a), (VEC, b), (X, g)],
+            functools.partial(_bwd_sums_kernel, act=act, has_skip=has_skip),
+            tag + "_bwd_sums", recompute + [(X, g)],
             [(PART, jnp.float32), (PART, jnp.float32)], interpret)
         s1 = _total(s1_p)
         s2 = _total(s2_p)
@@ -284,30 +266,30 @@ def _make_fused_train(act: str, eps: float, use_pallas: bool,
         dbeta = s1.reshape(1, -1)
         k2 = (a * ctr * r2 / count).astype(jnp.float32)
         k1 = a * s1.reshape(1, -1) / count - k2 * mean
-        # pass 2: recompute dz again, write dx in one pass
-        dx = _rows_call(
-            functools.partial(_bwd_dx_kernel, act=act), "bn_act_bwd_dx",
-            [(X, x3), (VEC, a), (VEC, b), (X, g), (VEC, k1), (VEC, k2)],
-            [(X, x3.dtype)], interpret)
-        return dx, dgamma, dbeta
+        # pass 2: recompute dz again, write dx (and dskip) in one pass
+        grads = _rows_call(
+            functools.partial(_bwd_dx_kernel, act=act, has_skip=has_skip),
+            tag + "_bwd_dx",
+            recompute + [(X, g), (VEC, k1), (VEC, k2)],
+            [(X, x3.dtype)] + [(X, s3.dtype) for s3 in skip], interpret)
+        dx, *ds = grads if has_skip else (grads,)
+        return (dx, dgamma, dbeta, *ds)
 
     fwd_impl = pallas_fwd if use_pallas else jnp_fwd
+    bwd_impl = pallas_bwd if use_pallas else jnp_bwd_math
 
     @jax.custom_vjp
-    def fused(x3, gamma2, beta2):
-        return fwd_impl(x3, gamma2, beta2)
+    def fused(x3, gamma2, beta2, *skip):
+        return fwd_impl(x3, gamma2, beta2, *skip)
 
-    def fused_fwd(x3, gamma2, beta2):
-        out, mean, var = fwd_impl(x3, gamma2, beta2)
-        return (out, mean, var), (x3, gamma2, beta2, mean, var)
+    def fused_fwd(x3, gamma2, beta2, *skip):
+        out, mean, var = fwd_impl(x3, gamma2, beta2, *skip)
+        return (out, mean, var), (x3, gamma2, beta2, skip, mean, var)
 
     def fused_bwd(res, cots):
-        x3, gamma2, beta2, mean, var = res
         g, _g_mean, _g_var = cots  # statistics outputs: buffers only,
         # stop_gradient'd by the module — their cotangents are zero
-        if use_pallas:
-            return pallas_bwd(x3, gamma2, beta2, mean, var, g)
-        return jnp_bwd_math(x3, gamma2, beta2, mean, var, g)
+        return bwd_impl(*res, g)
 
     fused.defvjp(fused_fwd, fused_bwd)
     return fused
@@ -374,40 +356,62 @@ def _stats_kernel(x_ref, s_ref, ss_ref):
     ss_ref[...] = _block_colsum(x * x)
 
 
-def _bwd_sums_kernel(x_ref, a_ref, b_ref, g_ref, s1_ref, s2_ref, *,
-                     act: str):
+def _preact(x, a, b_ref, s_ref):
+    """z = x*a + b (+ skip) in f32: the pre-activation every kernel
+    recomputes. x (R, C) f32; a, b (1, C) broadcast over rows."""
+    z = x * a + b_ref[...]
+    if s_ref is not None:
+        z = z + s_ref[...].astype(jnp.float32)
+    return z
+
+
+def _fwd_kernel(x_ref, a_ref, b_ref, *refs, act: str, has_skip: bool):
+    s_ref, o_ref = refs if has_skip else (None, *refs)
+    x = x_ref[...].astype(jnp.float32)        # (R, C)
+    z = _preact(x, a_ref[...], b_ref, s_ref)  # (1, C) broadcasts over rows
+    o_ref[...] = _act_fwd(z, act).astype(o_ref.dtype)
+
+
+def _bwd_sums_kernel(x_ref, a_ref, b_ref, *refs, act: str, has_skip: bool):
+    s_ref, g_ref, s1_ref, s2_ref = refs if has_skip else (None, *refs)
     x = x_ref[...].astype(jnp.float32)
-    z = x * a_ref[...] + b_ref[...]
+    z = _preact(x, a_ref[...], b_ref, s_ref)
     dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
     s1_ref[...] = _block_colsum(dz)
     s2_ref[...] = _block_colsum(dz * x)
 
 
-def _bwd_dx_kernel(x_ref, a_ref, b_ref, g_ref, k1_ref, k2_ref, dx_ref, *,
-                   act: str):
+def _bwd_dx_kernel(x_ref, a_ref, b_ref, *refs, act: str, has_skip: bool):
+    s_ref, g_ref, k1_ref, k2_ref, dx_ref, ds_ref = (
+        refs if has_skip else (None, *refs, None))
     x = x_ref[...].astype(jnp.float32)
     a = a_ref[...]
-    z = x * a + b_ref[...]
+    z = _preact(x, a, b_ref, s_ref)
     dz = g_ref[...].astype(jnp.float32) * _act_grad(z, act)
     dx_ref[...] = (a * dz - k2_ref[...] * x
                    - k1_ref[...]).astype(dx_ref.dtype)
+    if ds_ref is not None:
+        ds_ref[...] = dz.astype(ds_ref.dtype)
 
 
 def fused_bn_act_train(x: jax.Array, gamma: jax.Array, beta: jax.Array,
-                       *, eps: float = 1e-5, activation: str = "Mish",
+                       skip: jax.Array | None = None, *,
+                       eps: float = 1e-5, activation: str = "Mish",
                        interpret: bool | None = None):
-    """Train-mode fused BatchNorm + activation: batch moments, normalize
-    and activation in fused passes with the ANALYTIC BN backward (see
-    `_make_fused_train`). Returns `(out, mean, var)`; mean/var are the
-    BATCH statistics for the caller's running-average update and must be
-    consumed under `stop_gradient` (the backward treats their cotangents
-    as structurally zero, exactly like flax BatchNorm's buffers).
+    """Train-mode fused BatchNorm (+ skip-add) + activation: batch moments
+    of x, normalize, add and activation in fused passes with the ANALYTIC
+    BN backward (see `_make_fused_train`). Returns `(out, mean, var)`;
+    mean/var are the BATCH statistics of x for the caller's
+    running-average update and must be consumed under `stop_gradient`
+    (the backward treats their cotangents as structurally zero, exactly
+    like flax BatchNorm's buffers).
 
-    Differentiable w.r.t. x, gamma, beta. interpret=None (production):
-    the Pallas kernels on TPU, the pure-jnp custom_vjp twin elsewhere
-    (same math, same recompute structure — see module docstring).
-    interpret=True/False forces the Pallas path in that mode (tests pin
-    kernel parity with interpret=True)."""
+    Differentiable w.r.t. x, gamma, beta and, when given, skip (the
+    residual block's other branch, same shape as x). interpret=None
+    (production): the Pallas kernels on the chip, the pure-jnp custom_vjp
+    twin elsewhere (same math, same recompute structure — see module
+    docstring). interpret=True/False forces the Pallas path in that mode
+    (tests pin kernel parity with interpret=True)."""
     if activation not in FUSED_EPILOGUE_ACTIVATIONS:
         raise NotImplementedError(
             "fused epilogue supports %s, got %r"
@@ -416,15 +420,22 @@ def fused_bn_act_train(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     if gamma.shape != (c,) or beta.shape != (c,):
         raise ValueError("gamma/beta must be (%d,), got %s/%s"
                          % (c, gamma.shape, beta.shape))
-    use_pallas, interp = _resolve_pallas(interpret)
+    if skip is not None and skip.shape != x.shape:
+        raise ValueError("skip must match the BN input shape %s, got %s"
+                         % (x.shape, skip.shape))
+    use_pallas, interp = ((select.on_chip(), False) if interpret is None
+                          else (True, bool(interpret)))
+    # (N, H, W, C) -> (N, H*W, C) merges adjacent row-major dims; whether
+    # the chip copies between the conv's layout and this one is XLA's
+    # choice (PERF.md section 7)
     lead = x.shape[0] if x.ndim >= 3 else 1
     rows = x.size // (lead * c)
     x3 = x.reshape(lead, rows, c)
-    g2 = gamma.astype(jnp.float32).reshape(1, c)
-    b2 = beta.astype(jnp.float32).reshape(1, c)
-    _TRACE_SITES.append((int(x.size), int(jnp.dtype(x.dtype).itemsize)))
-    fn = _make_fused_train(str(activation), float(eps), use_pallas, interp)
-    out, mean, var = fn(x3, g2, b2)
+    skip3 = () if skip is None else (skip.reshape(lead, rows, c),)
+    fn = _make_fused_train(str(activation), float(eps), use_pallas, interp,
+                           skip is not None)
+    out, mean, var = fn(x3, gamma.astype(jnp.float32).reshape(1, c),
+                        beta.astype(jnp.float32).reshape(1, c), *skip3)
     return out.reshape(x.shape), mean, var
 
 
@@ -432,9 +443,9 @@ def fused_bn_act(x: jax.Array, eff_scale: jax.Array, eff_bias: jax.Array,
                  skip: jax.Array | None = None, *,
                  activation: str = "Mish") -> jax.Array:
     """Eval-mode BN tail `act(x * eff_scale + eff_bias [+ skip])`, f32
-    inside, x's dtype out — the arithmetic of `_fwd_kernel` /
-    residual.py's `_fwd_add_kernel`, as a plain expression that XLA fuses
-    into the convolution producing x (see module docstring).
+    inside, x's dtype out — the arithmetic of `_fwd_kernel`, as a plain
+    expression that XLA fuses into the convolution producing x (see
+    module docstring).
 
     x: (..., C) conv output; eff_scale/eff_bias: (C,) — the BN-fold
     algebra's per-channel affine from the running statistics; skip: the

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import select
 from .partition import batch_parallel
 
 _EPS = 1e-7  # matches ops/loss.py focal_loss eps
@@ -302,7 +303,7 @@ def fused_stack_loss_sums(out: jax.Array, gt_heat: jax.Array,
     positive-count normalization. Differentiable w.r.t. `out` only.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not select.on_chip()
     num_cls = gt_heat.shape[-1]
     b, s, h, w, k = out.shape
     # free reshapes only: merging the two minor dims of a channels-last

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import select
+
 _NEG = -1e30  # python scalar: a jnp constant would be captured by the kernel
 
 
@@ -106,7 +108,7 @@ def fused_peak_scores(logits: jax.Array, interpret: bool | None = None,
     if pool_size % 2 != 1 or pool_size < 1:
         raise ValueError("pool_size must be odd and >= 1, got %d" % pool_size)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not select.on_chip()
     chw = jnp.transpose(logits, (2, 0, 1))
     return jnp.transpose(_fused_chw(chw, interpret=interpret,
                                     pool_size=pool_size), (1, 2, 0))

@@ -34,15 +34,7 @@ from .ops.decode import (CascadeDetections, Detections, confidence_summary,
 from .ops.nms import maxpool_nms_mask, nms_mask, soft_nms_mask
 from .ops.pallas import fused_peak_scores
 from .ops.pallas.partition import batch_parallel
-
-
-def resolve_peak_kernel(cfg) -> str:
-    """'fused' | 'xla' for this backend: the Pallas sigmoid+peak kernel
-    replaces the XLA reduce_window path on TPU unless `--no-use-pallas`;
-    off-TPU it would run in (slow) interpret mode, so the backend gates it
-    as it gates --loss-kernel/--epilogue/--block-fuse auto."""
-    on_tpu = jax.default_backend() == "tpu"
-    return "fused" if getattr(cfg, "use_pallas", True) and on_tpu else "xla"
+from .ops.pallas.select import kernel_plan
 
 
 def make_predict_fn(model, cfg, normalize: str | None = None,
@@ -101,7 +93,7 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
     use_maxpool = cfg.nms == "maxpool"
     if cfg.nms not in ("nms", "soft-nms", "maxpool"):
         raise NotImplementedError("Not expected nms algorithm: %s" % cfg.nms)
-    use_pallas = resolve_peak_kernel(cfg) == "fused"
+    use_pallas = kernel_plan(cfg)["peak"] == "fused"
     imsize = int(cfg.imsize or 512)  # maxpool-NMS grid extent (static)
 
     infer_dtype = getattr(cfg, "infer_dtype", "bf16")

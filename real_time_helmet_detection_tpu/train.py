@@ -46,6 +46,7 @@ from .data import BatchLoader, load_dataset
 from .models import build_model
 from .ops.loss import (LossLog, split_stack_predictions,
                        stacked_detection_loss)
+from .ops.pallas.select import kernel_plan
 from .optim import build_optimizer
 from .parallel import (batch_sharding, init_distributed, make_mesh,
                        replicated, shard_batch, under_kernel_mesh)
@@ -125,16 +126,6 @@ def create_train_state(model, cfg: Config, rng: jax.Array, imsize: int,
     return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                       batch_stats=batch_stats, opt_state=opt_state,
                       ema_params=ema)
-
-
-def resolve_loss_kernel(cfg: Config) -> str:
-    """'fused' | 'xla' for this backend: --loss-kernel auto selects the
-    Pallas fused loss on TPU only, exactly as the fused peak kernel is
-    gated (off-TPU it would run in slow interpret mode)."""
-    mode = getattr(cfg, "loss_kernel", "auto")
-    if mode == "auto":
-        return "fused" if jax.default_backend() == "tpu" else "xla"
-    return mode
 
 
 class Distiller:
@@ -279,7 +270,7 @@ def loss_fn(params, batch_stats, model, images, gt_heat, gt_off, gt_wh, mask,
     # fwd_`), with it `jvp(loss)/detection_loss_fwd`, as the BN tails get
     # `jvp(StackedHourglass)/.../bn_act_fwd` from flax's module scopes.
     with jax.named_scope("loss"):
-        if resolve_loss_kernel(cfg) == "fused":
+        if kernel_plan(cfg)["loss"] == "fused":
             from .ops.pallas import fused_detection_loss
             totals = fused_detection_loss(
                 out, gt_heat, gt_off, gt_wh, mask,
@@ -1452,7 +1443,7 @@ class AsyncEvaluator:
                  device_augment=False, cache_device=False, async_eval=False,
                  async_ckpt=False, auto_resume=0, sentinel=False,
                  grad_accum=1, profile=False, summary=False, span_log="",
-                 preset="", fault_inject="",
+                 fault_inject="",
                  imsize=self.cfg.imsize or self.cfg.multiscale[1],
                  num_workers=min(2, max(1, self.cfg.num_workers)))
         return d

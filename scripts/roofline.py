@@ -35,7 +35,11 @@ Off-chip honesty: on the CPU backend the per-op BYTES reflect the CPU
 pipeline's fusion/layout choices (a proxy for TPU's — r5's analytic
 roofline showed CPU bytes can overestimate chip traffic severely for
 convolutions), and times are host times; the artifact labels its platform
-and the v5e constants it classifies against. When the chip is reachable,
+and the v5e constants it classifies against. That holds for the fused BN
+tails too: off the chip a `--epilogue fused` / `--block-fuse fused` step
+compiles the jnp twins of ops/pallas/epilogue.py, and their rows are
+counted as every other row is (the CPU pipeline's bytes, not the 8 / 12
+activation-sized transfers the kernels make on the chip). When the chip is reachable,
 run exactly the same command behind the single claim waiter (CLAUDE.md).
 
 `--diff baseline.json candidate.json` (ISSUE 7) is the attribution
@@ -180,10 +184,10 @@ def _shape_elems(dims: str) -> int:
 
 class Instr:
     __slots__ = ("name", "opcode", "out_bytes", "operand_bytes",
-                 "out_elems", "flops", "calls", "line", "src")
+                 "out_elems", "flops", "calls", "line")
 
     def __init__(self, name, opcode, out_bytes, operand_bytes, out_elems,
-                 flops, calls, line, src=None):
+                 flops, calls, line):
         self.name = name
         self.opcode = opcode
         self.out_bytes = out_bytes
@@ -192,7 +196,6 @@ class Instr:
         self.flops = flops
         self.calls = calls
         self.line = line
-        self.src = src
 
 
 def _parse_rhs(rhs: str):
@@ -285,11 +288,6 @@ def parse_hlo(text: str):
         if m is None or current is None:
             continue
         name, rhs = m.group(1), m.group(2)
-        # provenance: op_name metadata names the python source that built
-        # the op — the analytic-substitution hook (fused epilogue) keys
-        # on it. Captured BEFORE the annotation blocks are cut.
-        sm = re.search(r'source_file="([^"]+)"', rhs)
-        src = os.path.basename(sm.group(1)) if sm else None
         # cut trailing annotation blocks whose payload can contain
         # bracketed text that would pollute the operand-shape scan
         body = re.split(r",\s*(?:metadata=|backend_config=|sharding=)",
@@ -311,30 +309,18 @@ def parse_hlo(text: str):
             appliers.add(am.group(1))
         flops = _instr_flops(opcode, body, out_elems)
         comps[current].append(Instr(name, opcode, out_bytes, opnd_bytes,
-                                    out_elems, flops, calls, body, src))
+                                    out_elems, flops, calls, body))
     return comps, fusion_bodies, appliers
 
 
 def attribute(comps, fusion_bodies, appliers):
     """Reportable per-op records: every instruction of every computation
     that is not a fusion body or scalar applier, with fusion FLOPs rolled
-    up from their called computations.
-
-    Fusion provenance: the fusion INSTRUCTION usually carries no
-    metadata; its source (`src`) is the majority source_file over the
-    called computation's instructions — what the analytic-substitution
-    hook (fused epilogue) keys on."""
+    up from their called computations."""
     comp_flops = {
         cname: sum(i.flops for i in instrs)
         for cname, instrs in comps.items()
     }
-
-    def comp_src(cname):
-        votes = {}
-        for i in comps.get(cname, ()):
-            if i.src:
-                votes[i.src] = votes.get(i.src, 0) + 1
-        return max(votes, key=votes.get) if votes else None
 
     rows = []
     for cname, instrs in comps.items():
@@ -345,19 +331,14 @@ def attribute(comps, fusion_bodies, appliers):
                 continue
             flops = i.flops
             kind = i.opcode
-            src = i.src
             if i.opcode == "fusion" and i.calls:
                 flops = comp_flops.get(i.calls, 0.0)
-                src = src or comp_src(i.calls)
             bytes_ = i.out_bytes + i.operand_bytes
             if bytes_ == 0 and flops == 0:
                 continue
-            row = {"name": i.name, "opcode": kind,
-                   "class": op_class(i.name, kind),
-                   "flops": flops, "bytes": float(bytes_)}
-            if src:
-                row["src"] = src
-            rows.append(row)
+            rows.append({"name": i.name, "opcode": kind,
+                         "class": op_class(i.name, kind),
+                         "flops": flops, "bytes": float(bytes_)})
     return rows
 
 
@@ -484,17 +465,8 @@ def build_step(jax, args, loss_kernel: str):
     arrs = tuple(jnp.asarray(a) for a in synthetic_target_batch(
         args.batch, args.imsize, pos_rate=0.01))
     train_n = make_scanned_train_fn(body, args.steps)
-    # site registries: the timed program's train-mode fused-kernel calls —
-    # epilogue.py's BN+act tails and residual.py's BN+add+act tails each
-    # keep their own registry (different per-site transfer counts)
-    from real_time_helmet_detection_tpu.ops.pallas import epilogue as _epi
-    from real_time_helmet_detection_tpu.ops.pallas import residual as _res
-    _epi.reset_site_registry()
-    _res.reset_site_registry()
     compiled = jax.jit(train_n, donate_argnums=(0,)).lower(
         state, *arrs).compile()
-    build_step.epilogue_sites = _epi.traced_sites()
-    build_step.residual_sites = _res.traced_sites()
     remake = lambda: create_train_state(  # noqa: E731 — donation refills
         model, cfg, jax.random.key(0), args.imsize, tx)
     return compiled, state, arrs, remake
@@ -585,69 +557,6 @@ def loss_subprogram_cost(jax, args, kernel: str):
             2.0 * inputs + float(out.size) * out.dtype.itemsize
             + float(mask.size) * mask.dtype.itemsize)
     return rec
-
-
-def substitute_epilogue_analytic(rows, sites, residual_sites=()):
-    """Off-TPU, a `--epilogue fused` / `--block-fuse fused` model
-    compiles the jnp custom_vjp TWINS (ops/pallas/epilogue.py and
-    ops/pallas/residual.py) — faithful stand-ins for semantics and
-    tests, but NOT the programs the chip runs: the twins pay
-    CPU-pipeline taxes (materialized f32 views, Gram-dot reduction
-    reads) that the Pallas kernels keep in VMEM/registers. Exactly like
-    `loss_subprogram_cost`'s `kernel_bytes_analytic` (the r07 counting
-    model's documented basis for Pallas paths), each twin's rows —
-    identified by their HLO `source_file` metadata — are replaced by the
-    REAL kernel sequence's operand+result bytes per traced call site
-    (`epilogue.site_kernel_bytes`: 8 activation-sized transfers a train
-    tail; `residual.site_kernel_bytes`: 12 — the skip tensor rides every
-    pass). Twin rows whose fusion roots carry
-    other source metadata stay counted (conservative: overcounts the
-    candidate). Returns (rows, info|None); info rides in the artifact as
-    `epilogue_counting` — aggregate fields keep the r09 shape, and
-    `families` records each kernel family's twin-vs-kernel bytes side
-    by side (ISSUE 20)."""
-    from real_time_helmet_detection_tpu.ops.pallas import epilogue as _e
-    from real_time_helmet_detection_tpu.ops.pallas import residual as _r
-    families = (
-        ("epilogue.py", "fused_epilogue", _e.site_kernel_bytes,
-         list(sites or ())),
-        ("residual.py", "fused_residual", _r.site_kernel_bytes,
-         list(residual_sites or ())),
-    )
-    kept = list(rows)
-    per_family = {}
-    for src_name, label, kernel_bytes, fam_sites in families:
-        twin = [r for r in kept if r.get("src") == src_name]
-        if not twin or not fam_sites:
-            continue
-        kept = [r for r in kept if r.get("src") != src_name]
-        for i, (elems, itemsize) in enumerate(fam_sites):
-            kept.append({
-                "name": "%s.%d" % (label, i), "opcode": "custom-call",
-                "class": "elementwise", "src": src_name,
-                # ~20 f32 ops/element across the passes (act + derivative
-                # recompute; +skip add for residual); byte-bound either way
-                "flops": (22.0 if src_name == "residual.py" else 20.0)
-                         * elems,
-                "bytes": kernel_bytes(elems, itemsize)})
-        per_family[label] = {
-            "twin_rows_dropped": len(twin),
-            "twin_rows_bytes": sum(r["bytes"] for r in twin),
-            "kernel_bytes_analytic": sum(
-                kernel_bytes(e, s) for e, s in fam_sites),
-            "sites": len(fam_sites)}
-    if not per_family:
-        return rows, None
-    info = {"basis": "analytic",
-            "twin_rows_dropped": sum(f["twin_rows_dropped"]
-                                     for f in per_family.values()),
-            "twin_rows_bytes": sum(f["twin_rows_bytes"]
-                                   for f in per_family.values()),
-            "kernel_bytes_analytic": sum(f["kernel_bytes_analytic"]
-                                         for f in per_family.values()),
-            "sites": sum(f["sites"] for f in per_family.values()),
-            "families": per_family}
-    return kept, info
 
 
 DIFF_SCHEMA = "roofline-diff-v1"
@@ -899,21 +808,6 @@ def main() -> None:
     rows = attribute(comps, fusion_bodies, appliers)
     log("HLO: %d computations, %d reportable ops"
         % (len(comps), len(rows)))
-    epilogue_counting = None
-    if platform != "tpu" and not predict_mode:
-        # fused-epilogue analytic basis off-TPU (see the function's
-        # docstring); on TPU the Pallas custom-calls are counted natively
-        rows, epilogue_counting = substitute_epilogue_analytic(
-            rows, getattr(build_step, "epilogue_sites", []),
-            getattr(build_step, "residual_sites", []))
-        if epilogue_counting:
-            log("fused kernels counted analytically: %d sites (%s), "
-                "twin rows %.2f GB -> kernels %.2f GB"
-                % (epilogue_counting["sites"],
-                   "+".join(sorted(epilogue_counting["families"])),
-                   epilogue_counting["twin_rows_bytes"] / 1e9,
-                   epilogue_counting["kernel_bytes_analytic"] / 1e9))
-
     durations = None
     trace_note = "disabled (--no-trace)"
     if not args.no_trace:
@@ -966,7 +860,6 @@ def main() -> None:
                    "parsed_bytes": summary["total_bytes"]},
         "trace": trace_note,
         "summary": summary,
-        "epilogue_counting": epilogue_counting,
         "note": ("bytes are operand+result buffer sizes of the optimized "
                  "HLO's reportable ops (fusion-internal temporaries "
                  "excluded); on cpu they reflect the host pipeline's "

@@ -28,9 +28,8 @@ bf16-compute and --epilogue fused, alone and together) per batch, plus
 the ISSUE-20 lever cells (--block-fuse fused and --fwd-dtype int8,
 alone and together, on the best ISSUE-7 base — the A/B twin is the
 matching cell with the lever off), flagship 512^2 num_stack=1 bf16. The
-record with the best img/s that compiled lands in `step_grid_selected` —
-the artifact `--preset sweep-best` (config.py) promotes to the default
-train flags once committed. Cells resume individually (a mid-sweep kill
+record with the best img/s that compiled lands in `step_grid_selected`
+(a record for the reader; no code reads it). Cells resume individually (a mid-sweep kill
 re-measures only failed/missing cells, even under `--only step_grid`).
 On-chip etiquette: queue this behind the single claim waiter (CLAUDE.md);
 each config flushes before the next compiles.
@@ -295,16 +294,16 @@ def main() -> None:
         # give the donated input an aliasing target, not to be fetched
         dt = timed_fetch(lambda *a: compiled(*a)[1], (state, *arrs),
                          overhead, repeats=1)
-        from real_time_helmet_detection_tpu.models import (
-            resolve_block_fuse, resolve_epilogue)
-        from real_time_helmet_detection_tpu.train import resolve_loss_kernel
+        from real_time_helmet_detection_tpu.ops.pallas.select import \
+            kernel_plan
         from bench import bytes_of
+        plan = kernel_plan(cfg)
         rec = {"batch": batch, "remat": cfg.remat, "imsize": sz,
                "num_stack": num_stack,
-               "loss_kernel": resolve_loss_kernel(cfg),
+               "loss_kernel": plan["loss"],
                "param_policy": cfg.param_policy,
-               "epilogue": resolve_epilogue(cfg),
-               "block_fuse": resolve_block_fuse(cfg),
+               "epilogue": plan["epilogue"],
+               "block_fuse": plan["block_fuse"],
                "fwd_dtype": cfg.fwd_dtype,
                "img_per_sec_chip": round(batch * n / dt, 1),
                "step_ms": round(dt / n * 1e3, 3),
@@ -531,8 +530,6 @@ def main() -> None:
             flush()
         ok = [r for r in results["step_grid"] if "img_per_sec_chip" in r]
         if ok:
-            # the record `--preset sweep-best` promotes to default train
-            # flags (config.sweep_best_overrides reads the committed pick)
             results["step_grid_selected"] = max(
                 ok, key=lambda r: r["img_per_sec_chip"])
             log("step_grid selected: %s" % results["step_grid_selected"])

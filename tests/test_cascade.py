@@ -101,7 +101,7 @@ def test_cascade_detections_view_drops_only_the_scalar():
 
 # ---------------------------------------------------------------------------
 # cascade_overrides: the committed calibration artifact IS the promotion
-# record (sweep_best_overrides idiom)
+# record
 
 
 def _write_calib(root, rnd, threshold):

@@ -111,7 +111,7 @@ def test_stages_at_toy_size_on_the_cpu_mesh(tmp_path, monkeypatch):
     import real_time_helmet_detection_tpu.predict as predict
     from real_time_helmet_detection_tpu.obs.telemetry import \
         install_recompile_counter
-    monkeypatch.setattr(predict, "resolve_peak_kernel", lambda cfg: "fused")
+    monkeypatch.setattr(predict, "kernel_plan", lambda cfg: {"peak": "fused"})
     size = chip_smoke.Size(
         extra_flags=("--loss-kernel", "fused", "--epilogue", "fused",
                      "--block-fuse", "fused", "--num-workers", "2"), **TOY)

@@ -125,56 +125,6 @@ def test_param_policy_and_epilogue_flags_parse_and_validate():
         Config(param_policy="bf16-compute", amp=True, sub_divisions=4)
 
 
-def test_preset_sweep_best_promotes_committed_selection(tmp_path):
-    """ISSUE 7 satellite: --preset sweep-best reads the newest committed
-    step_grid_selected artifact and maps it onto the train flags
-    (highest round wins; bf16-compute implies amp)."""
-    import json as _json
-
-    import pytest
-
-    from real_time_helmet_detection_tpu.config import sweep_best_overrides
-
-    def write(round_name, rec):
-        d = tmp_path / "artifacts" / round_name
-        d.mkdir(parents=True, exist_ok=True)
-        (d / "sweep.json").write_text(_json.dumps(
-            {"platform": "tpu", "step_grid_selected": rec}))
-
-    write("r07", {"batch": 16, "remat": "none", "loss_kernel": "xla"})
-    write("r09", {"batch": 32, "remat": "stacks", "loss_kernel": "fused",
-                  "param_policy": "bf16-compute", "epilogue": "fused"})
-    over = sweep_best_overrides(repo_root=str(tmp_path))
-    assert over["_source"].endswith("r09/sweep.json")
-    assert over["batch_size"] == 32
-    assert over["remat"] == "stacks"
-    assert over["loss_kernel"] == "fused"
-    assert over["param_policy"] == "bf16-compute"
-    assert over["epilogue"] == "fused"
-    assert over["amp"] is True  # the policy's validity requirement rides
-
-    # a pre-ISSUE-7 selection maps only the fields it has
-    (tmp_path / "artifacts" / "r09" / "sweep.json").unlink()
-    over = sweep_best_overrides(repo_root=str(tmp_path))
-    assert over["batch_size"] == 16
-    assert "param_policy" not in over and "epilogue" not in over
-
-    # no selection anywhere -> loud failure, not silent defaults
-    (tmp_path / "artifacts" / "r07" / "sweep.json").unlink()
-    with pytest.raises(FileNotFoundError, match="sweep-best"):
-        sweep_best_overrides(repo_root=str(tmp_path))
-
-
-def test_preset_validation_and_noop():
-    import pytest
-
-    from real_time_helmet_detection_tpu.config import apply_preset
-    with pytest.raises(ValueError, match="preset"):
-        Config(preset="fastest")
-    cfg = Config()
-    assert apply_preset(cfg) is cfg  # unset preset touches nothing
-
-
 def test_serve_flags_parse_and_validate():
     """ISSUE 8: the serving-engine knobs exist as generated CLI flags and
     validate loudly."""

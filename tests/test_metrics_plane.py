@@ -170,6 +170,26 @@ def test_writer_period_gates_flushes(tmp_path):
     w.close()
 
 
+def test_writer_first_flush_lands_on_a_fresh_host(tmp_path, monkeypatch):
+    """The first flush does not wait for the host's monotonic clock (its
+    uptime, on Linux) to pass one period: a machine up for ten seconds
+    exports at once, then gates."""
+    import types
+
+    from real_time_helmet_detection_tpu.obs import metrics
+    clock = types.SimpleNamespace(monotonic=lambda: clock.now,
+                                  time=time.time, now=10.0)
+    monkeypatch.setattr(metrics, "time", clock)  # the module's view only
+    w = MetricsWriter(MetricsRegistry(), str(tmp_path / "metrics.jsonl"),
+                      period_s=3600.0)
+    assert w.maybe_flush()            # at 10 s of uptime
+    clock.now = 11.0
+    assert not w.maybe_flush()        # one second later: gated
+    clock.now = 3611.0
+    assert w.maybe_flush()            # one period after the first
+    w.close()
+
+
 _KILL9_WRITER = """
 import os, sys
 sys.path.insert(0, %r)

@@ -662,13 +662,6 @@ def test_loss_kernel_fused_matches_xla_in_loss_fn():
                                atol=scale * 1e-5, rtol=1e-3)
 
 
-def test_loss_kernel_auto_resolves_by_backend():
-    from real_time_helmet_detection_tpu.train import resolve_loss_kernel
-    assert resolve_loss_kernel(tiny_cfg()) == "xla"  # CPU backend in tests
-    assert resolve_loss_kernel(tiny_cfg(loss_kernel="fused")) == "fused"
-    assert resolve_loss_kernel(tiny_cfg(loss_kernel="xla")) == "xla"
-
-
 def test_remat_bool_coercion_and_validation():
     assert Config(remat=True).remat == "stacks"
     assert Config(remat=False).remat == "none"
