@@ -23,7 +23,9 @@ Cache, one entry a layer (`cache["layers"][i]`):
   sliding layer  c_kv (B, window, kv_rank), k_r (B, window, rope): a ring,
                  position p at slot p % window.
 `cache["counts"]`: per row, pairs routed to each held expert by expert layer
-(padding excluded), keys the indexer kept and keys causal, full layers summed.
+(padding excluded), keys the indexer kept and keys causal, full layers summed,
+and the q blocks of prefill attention that ran and that the padded row holds,
+attention layers summed.
 """
 
 from __future__ import annotations
@@ -188,9 +190,13 @@ def _gated_output(p, xn, o):
 
 def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
                           slots: int, faults=frozenset()):
-    """One sequence. x (P, hidden) raw (this normalises), `length` its real
-    rows. Returns (attention output (P, hidden), the row's cache entry, keys
-    kept over the real rows (0 for a sliding layer))."""
+    """One sequence. x (P, hidden) raw (this normalises), `length` (int32
+    scalar) its real rows: the indexer and the attention leave out every q
+    block that starts at or past it (`ops/attention.py`; the output's rows
+    there are the gate's and W_o's of zeros), the projections run over all P.
+    Returns (attention output (P, hidden), the row's cache entry, keys kept
+    over the real rows (0 for a sliding layer), q blocks that ran: the
+    predicates the branches took, summed)."""
     a = spec.attn(kind)
     total = x.shape[0]
     pos = jnp.arange(total, dtype=jnp.int32)
@@ -210,7 +216,8 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
             qi, ki, w = _index_parts(p["indexer"], spec, xn, c_q, pos)
             if "no_indexer" not in faults:
                 chosen = att.select_blocks(qi, ki, w, spec.index_topk,
-                                           spec.q_block, spec.head_block)
+                                           spec.q_block, spec.head_block,
+                                           length=length)
                 real = pos < length
                 for i, block in enumerate(chosen):
                     r0 = i * spec.q_block
@@ -229,9 +236,11 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
         entry = {"c_kv": take(c_kv), "k_r": take(k_r)}
     o = att.blockwise_attention(
         heads_first(q), heads_first(k), heads_first(kv[..., a.nope:]),
-        q_block=spec.q_block, window=window, chosen=chosen,
+        q_block=spec.q_block, window=window, chosen=chosen, length=length,
         head_block=spec.head_block, scale=1.0 / math.sqrt(a.nope + a.rope))
-    return _gated_output(p, xn, heads_first(o)), entry, kept
+    ran = sum(jnp.asarray(live, jnp.int32)
+              for live in att.q_blocks_live(total, spec.q_block, length))
+    return _gated_output(p, xn, heads_first(o)), entry, kept, ran
 
 
 def attention_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
@@ -467,19 +476,21 @@ class LatentMoEDecoder(nn.Module):
         params = [block() for block in self.layer]
         entries, pairs = [], []
         kept = jnp.zeros((rows,), jnp.int32)
+        ran = jnp.zeros((rows,), jnp.int32)
         with jax.named_scope("prefill"):
             with jax.named_scope("embed"):
                 x = self.embed[tokens]
             for i, p in enumerate(params):
                 kind = s.kinds[i]
                 with jax.named_scope(_attn_scope(kind)):
-                    out, entry, k = lax.map(
+                    out, entry, k, r = lax.map(
                         lambda xl, p=p, kind=kind: attention_prefill_row(
                             p["attn"], kind, s, xl[0], xl[1], slots,
                             self.faults), (x, lengths))
                 x = x + out
                 entries.append(entry)
                 kept += k
+                ran += r
                 x, local = _feed_forward(p, s, i < s.dense_layers, x, real,
                                          self.faults)
                 if local is not None:
@@ -489,7 +500,10 @@ class LatentMoEDecoder(nn.Module):
         counts = {"expert_tokens": jnp.stack(pairs, axis=1) if pairs else
                   jnp.zeros((rows, 0, s.share.held), jnp.int32),
                   "keys_kept": kept,
-                  "keys_causal": s.full_layers * lengths * (lengths + 1) // 2}
+                  "keys_causal": s.full_layers * lengths * (lengths + 1) // 2,
+                  "q_blocks_run": ran,
+                  "q_blocks_total": jnp.full(
+                      (rows,), s.layers * -(-total // s.q_block), jnp.int32)}
         return logits, {"layers": tuple(entries), "counts": counts}
 
     def step(self, token, positions, cache):
@@ -523,7 +537,9 @@ class LatentMoEDecoder(nn.Module):
                   + (jnp.stack(pairs, axis=1) if pairs else 0),
                   "keys_kept": kept,
                   "keys_causal": counts["keys_causal"]
-                  + s.full_layers * (positions + 1)}
+                  + s.full_layers * (positions + 1),
+                  "q_blocks_run": counts["q_blocks_run"],
+                  "q_blocks_total": counts["q_blocks_total"]}
         return logits, {"layers": tuple(entries), "counts": counts}
 
 

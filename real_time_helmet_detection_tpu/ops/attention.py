@@ -5,7 +5,12 @@ chosen key set (prefill), and one query a row against a latent cache (decode).
 The reference has no attention of any kind (ref hourglass.py is
 convolutions only); this module is new capability. Plain XLA: the matrix
 products are large enough for the MXU as they stand, and the selection is a
-mask over dense causal blocks (skipping unchosen blocks is a later step).
+mask over dense causal blocks (skipping unchosen key blocks is a later step).
+Prefill walks a sequence a q block at a time and is told the sequence's real
+`length`: a q block that starts at or past it is a branch not taken on the
+device (`lax.cond`), so padding at the end of a row costs no scores, no top-k
+and no values. A caller without lengths passes the padded length and every
+branch is taken.
 
 Conventions shared with benchmark/reference/latent_moe_decoder.py (which
 states the equations): float32 inside norms, softmax and the indexer's score
@@ -105,24 +110,63 @@ def index_scores(qi, ki, w, head_block: Optional[int] = None):
     return total / math.sqrt(heads * dim)
 
 
+def q_blocks_live(total: int, q_block: int, length) -> list:
+    """One predicate a q block [r0, r0 + q_block) of `total` rows: does the
+    block hold a real row, r0 < `length` (int32 scalar, >= 1)? These are the
+    predicates `select_blocks` and `blockwise_attention` branch on; block 0
+    always does (True, no branch)."""
+    return [True if r0 == 0 else r0 < length
+            for r0 in range(0, total, q_block)]
+
+
+def _when(live, body, shape, dtype):
+    """`body()` where the scalar `live` holds, else zeros (False for bool) of
+    its shape, as a branch on the device: under `lax.map` over rows `live` is
+    a scalar, so the body not taken is not computed. A Python True (block 0)
+    is no branch at all."""
+    if live is True:
+        return body()
+    return lax.cond(live, body, lambda: jnp.zeros(shape, dtype))
+
+
+def _causal(r0: int, r1: int):
+    """bool (r1 - r0, r1): key s <= query t, for the queries [r0, r1)."""
+    t = r0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 0)
+    return lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 1) <= t
+
+
 def select_blocks(qi, ki, w, topk: int, q_block: int,
-                  head_block: Optional[int] = None) -> List[jax.Array]:
+                  head_block: Optional[int] = None, *,
+                  length) -> List[jax.Array]:
     """The chosen keys of one sequence, a q block at a time: a list of bool
     (q_block, r1), r1 the block's end (keys after it are not causal), True
-    where s <= t and I[t, s] is among the `topk` largest of row t."""
+    where s <= t and I[t, s] is among the `topk` largest of row t. `length`
+    (int32 scalar, an operand) is the sequence's real rows: a block that
+    starts at or past it is not scored and chooses nothing (all False). A
+    block wholly inside the first `topk` keys is the causal mask, a constant,
+    whatever the length."""
     total = qi.shape[0]
     out = []
-    for r0 in range(0, total, q_block):
+    for r0, live in zip(range(0, total, q_block),
+                        q_blocks_live(total, q_block, length)):
         r1 = min(total, r0 + q_block)
-        t = r0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 0)
-        s = lax.broadcasted_iota(jnp.int32, (r1 - r0, r1), 1)
-        causal = s <= t
         if r1 <= topk:
-            out.append(causal)
+            out.append(_causal(r0, r1))
             continue
-        scores = index_scores(qi[r0:r1], ki[:r1], w[r0:r1], head_block)
-        out.append(top_k_mask(jnp.where(causal, scores, -jnp.inf), topk)
-                   & causal)
+
+        # the block's queries are cut out here, behind a barrier that keeps
+        # the compiler from moving the cut back into the branch: a branch
+        # cannot ask its operand's producer for the layout its product wants,
+        # so a branch handed the whole of `qi` copies the whole of it (134 MB
+        # at 8,192 x 64 x 128, 0.4 ms a block: PERF.md section 6, PR 32)
+        rows = lax.optimization_barrier(qi[r0:r1])
+
+        def chosen(r0=r0, r1=r1, rows=rows):
+            causal = _causal(r0, r1)
+            scores = index_scores(rows, ki[:r1], w[r0:r1], head_block)
+            return top_k_mask(jnp.where(causal, scores, -jnp.inf),
+                              topk) & causal
+        out.append(_when(live, chosen, (r1 - r0, r1), bool))
     return out
 
 
@@ -137,19 +181,24 @@ def _masked_exp(scores, allowed):
 
 # ---- prefill: blockwise causal attention ------------------------------------------
 
-def blockwise_attention(q, k, v, *, q_block: int, scale: float,
+def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
                         window: Optional[int] = None,
                         chosen: Optional[List[jax.Array]] = None,
                         head_block: Optional[int] = None):
     """Causal attention of one sequence. q (H, T, dq), k (H, T, dq), v (H, T,
-    dv) -> (H, T, dv); `scale` multiplies the scores (in float32). A q block [r0, r1) reads the keys
-    [lo, r1): lo = r0 - (window - 1) under a window, else 0; inside, a key
-    is allowed where s <= t, t - s < window and, with `chosen` (the list
-    `select_blocks` gives, same q_block), where it was chosen. Softmax in
-    float32 over the block's whole key range (no running maximum needed).
-    Heads `head_block` at a time under `lax.map`, so that the scores (float32,
-    heads x q_block x keys) stay small."""
+    dv) -> (H, T, dv); `scale` multiplies the scores (in float32). A q block
+    [r0, r1) reads the keys [lo, r1): lo = r0 - (window - 1) under a window,
+    else 0; inside, a key is allowed where s <= t, t - s < window and, with
+    `chosen` (the list `select_blocks` gives, same q_block), where it was
+    chosen. Softmax in float32 over the block's whole key range (no running
+    maximum needed). `length` (int32 scalar, an operand) is the sequence's
+    real rows: a block that starts at or past it is not computed and its rows
+    are zeros; no real query reads a padded key (s <= t < length), so rows
+    below `length` are what the full length gives. Heads `head_block` at a
+    time under `lax.map`, so that the scores (float32, heads x q_block x
+    keys) stay small."""
     heads, total = q.shape[0], q.shape[1]
+    live = q_blocks_live(total, q_block, length)
 
     def group(qkv):
         qg, kg, vg = qkv
@@ -157,19 +206,26 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float,
         for i, r0 in enumerate(range(0, total, q_block)):
             r1 = min(total, r0 + q_block)
             lo = 0 if window is None else max(0, r0 - (window - 1))
-            t = r0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1 - lo), 0)
-            s = lo + lax.broadcasted_iota(jnp.int32, (r1 - r0, r1 - lo), 1)
-            allowed = s <= t
-            if window is not None:
-                allowed &= (t - s) < window
-            if chosen is not None:
-                allowed &= chosen[i][:, lo:]
-            sc = jnp.einsum("hqd,hkd->hqk", qg[:, r0:r1], kg[:, lo:r1],
-                            preferred_element_type=jnp.float32)
-            p, denom = _masked_exp(sc * scale, allowed)
-            o = jnp.einsum("hqk,hkd->hqd", p.astype(vg.dtype), vg[:, lo:r1],
-                           preferred_element_type=jnp.float32)
-            outs.append((o / denom).astype(vg.dtype))
+
+            def block(i=i, r0=r0, r1=r1, lo=lo):
+                t = r0 + lax.broadcasted_iota(jnp.int32,
+                                              (r1 - r0, r1 - lo), 0)
+                s = lo + lax.broadcasted_iota(jnp.int32,
+                                              (r1 - r0, r1 - lo), 1)
+                allowed = s <= t
+                if window is not None:
+                    allowed &= (t - s) < window
+                if chosen is not None:
+                    allowed &= chosen[i][:, lo:]
+                sc = jnp.einsum("hqd,hkd->hqk", qg[:, r0:r1], kg[:, lo:r1],
+                                preferred_element_type=jnp.float32)
+                p, denom = _masked_exp(sc * scale, allowed)
+                o = jnp.einsum("hqk,hkd->hqd", p.astype(vg.dtype),
+                               vg[:, lo:r1],
+                               preferred_element_type=jnp.float32)
+                return (o / denom).astype(vg.dtype)
+            outs.append(_when(live[i], block,
+                              (qg.shape[0], r1 - r0, vg.shape[-1]), vg.dtype))
         return jnp.concatenate(outs, axis=1)
 
     if not head_block or head_block >= heads:

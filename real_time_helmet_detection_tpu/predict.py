@@ -229,7 +229,9 @@ class Generation(NamedTuple):
     `expert_tokens`: pairs routed to each held expert by expert layer, over
     every position of the row (prompt and fed-back tokens, padding excluded);
     `keys_kept` / `keys_causal`: keys the indexer kept / keys causal, the
-    full layers summed."""
+    full layers summed; `q_blocks_run` / `q_blocks_total`: q blocks of prefill
+    attention the program ran (a block past the row's length is a branch not
+    taken) / that the padded row holds, the attention layers summed."""
     tokens: jax.Array          # int32 (B, N)
     logits_first: jax.Array    # float32 (B, V): at the prompt's last token
     logits_last: jax.Array     # float32 (B, V): the step that gave token N
@@ -237,6 +239,8 @@ class Generation(NamedTuple):
     keys_kept: jax.Array       # int32 (B,)
     keys_causal: jax.Array     # int32 (B,)
     prompt_len: jax.Array      # int32 (B,): the length the row stated
+    q_blocks_run: jax.Array    # int32 (B,)
+    q_blocks_total: jax.Array  # int32 (B,)
 
 
 def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
@@ -276,7 +280,8 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             logits_first=logits, logits_last=last,
             expert_tokens=counts["expert_tokens"],
             keys_kept=counts["keys_kept"], keys_causal=counts["keys_causal"],
-            prompt_len=lengths)
+            prompt_len=lengths, q_blocks_run=counts["q_blocks_run"],
+            q_blocks_total=counts["q_blocks_total"])
 
     return jax.jit(generate)
 
@@ -293,7 +298,9 @@ def generation_counters(p_max: int) -> Callable:
                "gen.new_tokens": int(rows.tokens.shape[0] * rows.tokens.shape[1]),
                "gen.keys_kept": int(np.sum(rows.keys_kept, dtype=np.int64)),
                "gen.keys_causal": int(np.sum(rows.keys_causal,
-                                             dtype=np.int64))}
+                                             dtype=np.int64)),
+               "gen.q_blocks_run": int(np.sum(rows.q_blocks_run)),
+               "gen.q_blocks_total": int(np.sum(rows.q_blocks_total))}
         by_expert = np.sum(rows.expert_tokens, axis=(0, 1), dtype=np.int64)
         for e, pairs in enumerate(by_expert):
             out["gen.expert_pairs.e%02d" % e] = int(pairs)

@@ -16,6 +16,7 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "benchmark"))
 import bench_toy  # noqa: E402
+from test_bn_tail import count_primitives  # noqa: E402
 
 from benchmark import decoder_check  # noqa: E402
 from benchmark.reference import latent_moe_decoder as ref  # noqa: E402
@@ -153,14 +154,102 @@ def test_one_layers_attention_matches_the_reference(fields, layer):
     p["attn_norm"] = flat["layer_%d/attn_norm" % layer]
     p["indexer"] = {k[len(pre) + 8:]: v for k, v in flat.items()
                     if k.startswith(pre + "indexer/")}
-    got, entry, kept = dec.attention_prefill_row(
+    got, entry, kept, ran = dec.attention_prefill_row(
         p, spec.kinds[layer], spec, x, jnp.int32(16), 20)
     assert np.allclose(got, want, atol=2e-5)
+    assert int(ran) == 16 // spec.q_block
     if spec.kinds[layer] == dec.FULL:
         assert int(kept) == int(np.sum(allowed)) < 16 * 17 // 2
         assert entry["c_kv"].shape == (20, spec.full.kv_rank)
     else:
         assert entry["c_kv"].shape == (spec.window, spec.swa.kv_rank)
+
+
+Q_BLOCK, TOTAL = 8, 32
+
+
+def _blockwise_case(kind):
+    """A jitted (length -> array) of one sequence of TOTAL rows in q blocks
+    of Q_BLOCK, float32: `blockwise_attention` plain, under a window, behind
+    a chosen set (heads two at a time under `lax.map`), or `select_blocks`
+    itself (its blocks padded to TOTAL keys and stacked)."""
+    rng = np.random.default_rng(7)
+    draw = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), jnp.float32)
+    q, k, v = draw(4, TOTAL, 12), draw(4, TOTAL, 12), draw(4, TOTAL, 8)
+    qi, ki, w = draw(TOTAL, 2, 16), draw(TOTAL, 16), draw(TOTAL, 2)
+
+    def select(length):
+        return att.select_blocks(qi, ki, w, 8, Q_BLOCK, length=length)
+
+    def run(length):
+        if kind == "select":
+            return jnp.concatenate([jnp.pad(
+                b, ((0, 0), (0, TOTAL - b.shape[1]))) for b in select(length)])
+        return att.blockwise_attention(
+            q, k, v, q_block=Q_BLOCK, scale=0.3, length=length, head_block=2,
+            window=5 if kind == "window" else None,
+            chosen=select(length) if kind == "chosen" else None)
+    return jax.jit(run)
+
+
+@pytest.mark.parametrize("length", [1, Q_BLOCK, Q_BLOCK + 1, TOTAL - 1, TOTAL])
+@pytest.mark.parametrize("kind", ["plain", "window", "chosen", "select"])
+def test_q_blocks_past_the_length_are_not_computed(kind, length):
+    """Rows below `length` are what the full length gives; the rows of a
+    block that starts at or past it are zeros (nothing chosen); no NaN."""
+    run = _blockwise_case(kind)
+    got = np.asarray(run(jnp.int32(length)))
+    full = np.asarray(run(jnp.int32(TOTAL)))
+    rows = 0 if kind == "select" else 1           # the axis of the queries
+    ran = -(-length // Q_BLOCK) * Q_BLOCK         # rows of the blocks that ran
+    take = lambda a, lo, hi: np.take(a, np.arange(lo, hi), rows)  # noqa: E731
+    assert not np.isnan(got.astype(np.float32)).any()
+    assert np.allclose(take(got, 0, length), take(full, 0, length), atol=2e-5)
+    assert not take(got, ran, TOTAL).any()
+    assert take(full, length, TOTAL).any() or length == TOTAL
+
+
+def test_prefill_branches_once_a_q_block_and_counts_the_blocks_it_ran(fields):
+    """Toy: 16 slots in q blocks of 8, top-k 8. A full layer holds one branch
+    in `select_blocks` (block 0 is the causal constant) and one in
+    `blockwise_attention`, a sliding layer one; a row's `q_blocks_run` is
+    what its length gives, the attention layers summed."""
+    cfg = _config(fields)
+    spec = dec.DecoderSpec.from_mapping(cfg.decoder)
+    model = build_model(cfg)
+    tree = ref.program_tree(fields, SEED)
+    rows = _payload(fields["vocab_size"])
+    prefill = lambda v, t, n: model.apply(v, t, n, 4,  # noqa: E731
+                                          method="prefill")
+    blocks = P_MAX // spec.q_block
+    assert count_primitives(jax.make_jaxpr(prefill)(
+        tree, rows[:, 1:], rows[:, 0]).jaxpr)["cond"] == sum(
+        (blocks - 1) * (2 if kind == dec.FULL else 1) for kind in spec.kinds)
+    _, cache = jax.jit(prefill)(tree, rows[:, 1:], rows[:, 0])
+    assert cache["counts"]["q_blocks_run"].tolist() == [
+        spec.layers * -(-n // spec.q_block) for n in LENGTHS]
+    assert cache["counts"]["q_blocks_total"].tolist() == [
+        spec.layers * blocks] * len(LENGTHS)
+
+
+def test_skipping_padded_q_blocks_changes_no_answer(fields, sound,
+                                                    monkeypatch):
+    """Prefill and 12 steps of the batch of mixed lengths against the same
+    rows with every branch taken (every block live: the program as it was
+    before a block could be skipped)."""
+    _, served, _ = sound
+    monkeypatch.setattr(att, "q_blocks_live", lambda total, q_block, length:
+                        [True] * -(-total // q_block))
+    _, every = _generate(fields)
+    for s, e in zip(served, every):
+        assert int(e.q_blocks_run) == int(e.q_blocks_total)
+        assert int(s.q_blocks_run) <= int(s.q_blocks_total)
+        for name in ("tokens", "logits_first", "logits_last", "keys_kept",
+                     "expert_tokens"):
+            assert np.array_equal(getattr(s, name), getattr(e, name)), name
+    assert sum(int(s.q_blocks_run) for s in served) < sum(
+        int(s.q_blocks_total) for s in served)
 
 
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(fields):
@@ -241,6 +330,10 @@ def test_the_engine_serves_int32_payloads_and_feeds_row_counters(fields):
     assert count("gen.padded_prompt_tokens") == 3 * P_MAX - sum(LENGTHS[:3])
     assert count("gen.new_tokens") == 9
     assert count("gen.keys_kept") == sum(int(a.keys_kept) for a in answers)
+    layers, q_block = fields["num_hidden_layers"], fields["attn_q_block"]
+    assert count("gen.q_blocks_total") == 3 * layers * -(-P_MAX // q_block)
+    assert count("gen.q_blocks_run") == layers * sum(
+        -(-n // q_block) for n in LENGTHS[:3])
     pairs = sum(count("gen.expert_pairs.e%02d" % e)
                 for e in range(fields["n_routed_experts"]))
     assert pairs == sum(int(a.expert_tokens.sum()) for a in answers) > 0
