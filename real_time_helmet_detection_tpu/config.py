@@ -57,7 +57,10 @@ ARCHITECTURE_FIELDS = (
 MODEL_VARIANTS = ("residual", "depthwise", "ghost")
 # model families `models.build_model` dispatches on: the reference's stacked
 # hourglass, and the decoder of models/decoder.py (no reference analogue)
-MODEL_FAMILIES = ("hourglass", "latent_moe_decoder")
+# `DECODER_FAMILIES`: one body, the attention the family names
+# (models/decoder.py `ATTENTIONS`), served by predict.make_generate_fn
+DECODER_FAMILIES = ("latent_moe_decoder", "gqa_moe_decoder")
+MODEL_FAMILIES = ("hourglass",) + DECODER_FAMILIES
 
 # Latency-tier presets (ISSUE 13): named architecture+serving bundles —
 # the product tiers the fleet router mixes per tenant. `--tier edge`
@@ -95,12 +98,13 @@ class Config:
     # model family (ROADMAP D11/D13: one switch and one mapping, not a flat
     # field per size of every family)
     family: str = "hourglass"     # MODEL_FAMILIES
-    decoder: Dict[str, Any] = field(default_factory=dict)  # family
-    # "latent_moe_decoder": the source's own config.json keys as they stand
-    # (models/decoder.py `DecoderSpec.from_mapping` names them), plus
-    # `ep_size` / `ep_rank` (how many chips share an expert layer, which
-    # share this is; `n_routed_experts` and `vocab_size` then count what is
-    # held HERE). Not a CLI flag: a mapping comes from a file.
+    decoder: Dict[str, Any] = field(default_factory=dict)  # a family of
+    # DECODER_FAMILIES: the source's own config.json keys as they stand
+    # (models/decoder.py `DecoderSpec.from_mapping` names each family's),
+    # plus `ep_size` / `ep_rank` (how many chips share an expert layer, which
+    # share this is; the source's count of routed experts and `vocab_size`
+    # then count what is held HERE). Not a CLI flag: a mapping comes from a
+    # file.
 
     # device
     num_devices: int = 0          # 0 = use every visible device

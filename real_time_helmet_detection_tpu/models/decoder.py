@@ -1,38 +1,58 @@
-"""The second family: a decoder of latent-attention layers (full layers
-behind a learned sparse indexer, sliding layers behind a window, a head-wise
-output gate on both), a leading dense SwiGLU layer and sigmoid-routed experts
-with a shared expert, as ONE SHARE of an expert-parallel deployment.
+"""The decoder families: ONE body (embedding, layer loop, a leading dense
+SwiGLU layer, sigmoid-routed experts with a shared expert as one share of an
+expert-parallel deployment or held whole, counts, head; `prefill` and `step`)
+behind which the spec selects one of two attentions (`ATTENTIONS`):
+
+`latent_moe_decoder`  latent attention: full layers behind a learned sparse
+    indexer, sliding layers behind a window, a head-wise output gate on both;
+    decode with W_uk / W_uv absorbed into the query and the output.
+`gqa_moe_decoder`     grouped-query attention: 8 k/v heads shared by a
+    number of query heads that differs BY LAYER, rotary positions from a table
+    (YaRN on half the head on full layers, plain theta on the whole head on
+    sliding ones), the same head-wise gate.
+
+An attention is three things: the flax module that declares its parameters,
+`prefill_row(p, kind, spec, x, length, slots, faults) -> (out, cache entry,
+counts)` and `step(p, kind, spec, x, pos, entry, faults) -> (out, entry,
+counts)`.
 
 The reference has no language model (ref hourglass.py is the only network);
-this module is new capability. The equations are stated once, in
-benchmark/reference/latent_moe_decoder.py (the plain reference the tests and
+this module is new capability. The equations are stated once a family, in
+benchmark/reference/latent_moe_decoder.py and
+benchmark/reference/gqa_moe_decoder.py (the plain references the tests and
 the benchmark hold this program to); this file is how the program computes
 them: bfloat16 parameters and activations, float32 in norms, softmax, router
 scores and the indexer's score sum; prefill a sequence at a time under
 `lax.map` (so that one row's q, k, v and scores are what stands in memory),
-blockwise over queries; decode one token a row against two kinds of cache,
-with W_uk / W_uv absorbed into the query and the output.
+blockwise over queries; decode one token a row against two kinds of cache.
 
 The flax modules declare parameters and hold no arithmetic of their own: a
 module reads its arrays, then calls the pure functions below (a flax module
 cannot be entered under `lax.map`).
 
 Cache, one entry a layer (`cache["layers"][i]`):
-  full layer     c_kv (B, S, kv_rank), k_r (B, S, rope), k_i (B, S, index dim)
-                 at absolute positions (S = prompt slots + reserved);
-  sliding layer  c_kv (B, window, kv_rank), k_r (B, window, rope): a ring,
-                 position p at slot p % window.
-`cache["counts"]`: per row, pairs routed to each held expert by expert layer
-(padding excluded), keys the indexer kept and keys causal, full layers summed,
-and the q blocks of prefill attention that ran and that the padded row holds,
-attention layers summed.
+  full layer     at absolute positions (S = prompt slots + reserved): latent
+                 c_kv (B, S, kv_rank), k_r (B, S, rope), k_i (B, S, index
+                 dim); grouped-query k, v (B, S, kv heads, head dim), rotated
+                 before they are stored;
+  sliding layer  a ring, position p at slot p % window: latent c_kv (B,
+                 window, kv_rank), k_r (B, window, rope); grouped-query k, v
+                 (B, window, kv heads, head dim).
+`cache["counts"]`, per row: pairs routed to each held expert by expert layer
+(padding excluded); expert visits (for each prefill or step and expert layer,
+the experts that had at least one pair, credited to the lowest row that
+routed there, so that rows add up to the batch's count); the q blocks of
+prefill attention that ran and that the padded row holds, attention layers
+summed; and what one attention counts (`ATTENTION_COUNTS`; zeros for the
+other): keys the indexer kept and keys causal, full layers summed; cache
+slots the decode steps read and real keys among them, by layer kind.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Mapping, Optional, Tuple
+from typing import Callable, Mapping, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -45,10 +65,16 @@ from ..parallel.experts import ExpertShare, expert_share
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 FAMILY = "latent_moe_decoder"
+GQA_FAMILY = "gqa_moe_decoder"
+# per-row counts an attention may give (int32 (B,)); the body adds the layers
+# up and a count the family's attention does not give stays zeros
+ATTENTION_COUNTS = ("keys_kept", "keys_causal", "q_blocks_run",
+                    "slots_full", "keys_full", "slots_window", "keys_window")
 
 
 @dataclasses.dataclass(frozen=True)
 class AttnSizes:
+    """One layer kind of the latent family."""
     heads: int
     q_rank: int
     kv_rank: int
@@ -59,68 +85,102 @@ class AttnSizes:
 
 
 @dataclasses.dataclass(frozen=True)
+class GroupedSizes:
+    """One layer kind of the grouped-query family: k/v heads and the head's
+    size, and the rotary table over the head's leading `rot` dims (`inv_freq`
+    (rot/2,) made from `theta`, `rope_scale` on cos and sin)."""
+    kv_heads: int
+    head_dim: int
+    theta: float
+    rot: int
+    inv_freq: Tuple[float, ...]
+    rope_scale: float
+
+
+def rotary_table(params: Mapping, head_dim: int) -> Tuple[int, tuple, float]:
+    """(rot, inverse frequencies (rot/2,), scale) of one entry of the
+    source's `rope_parameters`: `default` is theta^(-2j/rot); `yarn` blends
+    each frequency with itself / factor along a ramp between the dimensions
+    that turn beta_fast and beta_slow times over the original length, and
+    scales cos and sin by `attention_factor` (0.1 ln factor + 1 where the
+    source leaves it out). Python floats: the table does not depend on the
+    sequence's length."""
+    rot = int(round(head_dim * float(params.get("partial_rotary_factor", 1))))
+    theta, half = float(params["rope_theta"]), rot // 2
+    freq = [theta ** (-2.0 * j / rot) for j in range(half)]
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return rot, tuple(freq), 1.0
+    if kind != "yarn":
+        raise ValueError("rope_type %r is not default | yarn" % (kind,))
+    factor = float(params["factor"])
+    span = float(params["original_max_position_embeddings"])
+
+    def turns_at(n):  # the dimension that turns n times over `span`
+        return rot * math.log(span / (2 * math.pi * n)) / (
+            2 * math.log(theta))
+    low = max(math.floor(turns_at(float(params["beta_fast"]))), 0)
+    high = min(math.ceil(turns_at(float(params["beta_slow"]))), rot - 1)
+    ramp = [min(1.0, max(0.0, (j - low) / max(high - low, 1e-3)))
+            for j in range(half)]
+    scale = float(params.get("attention_factor")
+                  or 0.1 * math.log(factor) + 1.0)
+    return rot, tuple(f * ((1 - r) + r / factor)
+                      for f, r in zip(freq, ramp)), scale
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderSpec:
     """The sizes, from the source's own keys (`Config.decoder`: the model's
-    `config.json` keys as they stand, plus `ep_size` / `ep_rank`, and
-    `n_routed_experts` / `vocab_size` counting what is held HERE)."""
+    `config.json` keys as they stand, plus `ep_size` / `ep_rank`, and the
+    source's count of routed experts / `vocab_size` counting what is held
+    HERE). `full` / `swa`: the attention's sizes by layer kind, an
+    `AttnSizes` or a `GroupedSizes` by the family."""
+    family: str
     hidden: int
     vocab: int
     kinds: Tuple[str, ...]
+    heads: Tuple[int, ...]   # query heads, by layer
     dense_layers: int
     dense_width: int
     expert_width: int
-    shared: int
+    shared_width: int
     share: ExpertShare
     per_token: int
     norm_weights: bool
     routed_scale: float
     eps: float
     window: int
-    index_heads: int
-    index_dim: int
-    index_topk: int
-    full: AttnSizes
-    swa: AttnSizes
+    full: object
+    swa: object
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     # how the program walks a prompt (not the model's): query rows a block,
     # heads a pass of the attention and of the indexer
     q_block: int = 512
     head_block: int = 32
 
     @classmethod
-    def from_mapping(cls, d: Mapping) -> "DecoderSpec":
-        def sizes(prefix, heads):
-            return AttnSizes(
-                int(d[heads]), int(d[prefix + "q_lora_rank"]),
-                int(d[prefix + "kv_lora_rank"]),
-                int(d[prefix + "qk_nope_head_dim"]),
-                int(d[prefix + "qk_rope_head_dim"]),
-                int(d[prefix + "v_head_dim"]), float(d[prefix + "rope_theta"]))
+    def from_mapping(cls, d: Mapping, family: str = FAMILY) -> "DecoderSpec":
+        if family not in _SPEC_KEYS:
+            raise ValueError("no decoder of family %r (have: %s)"
+                             % (family, ", ".join(sorted(_SPEC_KEYS))))
         layers = int(d["num_hidden_layers"])
         kinds = tuple(d["layer_types"])[:layers]
         if len(kinds) != layers or set(kinds) - {FULL, SLIDING}:
             raise ValueError("layer_types must name %d layers as %s or %s, "
                              "got %r" % (layers, FULL, SLIDING, kinds))
-        ep = int(d.get("ep_size", 1))
-        held = int(d["n_routed_experts"])
         return cls(
-            hidden=int(d["hidden_size"]), vocab=int(d["vocab_size"]),
-            kinds=kinds, dense_layers=int(d["first_k_dense_replace"]),
+            family=family, hidden=int(d["hidden_size"]),
+            vocab=int(d["vocab_size"]), kinds=kinds,
             dense_width=int(d["intermediate_size"]),
             expert_width=int(d["moe_intermediate_size"]),
-            shared=int(d["n_shared_experts"]),
-            share=expert_share(ep, int(d.get("ep_rank", 0)), held * ep),
             per_token=int(d["num_experts_per_tok"]),
-            norm_weights=bool(d["norm_topk_prob"]),
-            routed_scale=float(d["routed_scaling_factor"]),
             eps=float(d["rms_norm_eps"]),
-            window=int(d["sliding_window_size"]),
-            index_heads=int(d["index_n_heads"]),
-            index_dim=int(d["index_head_dim"]),
-            index_topk=int(d["index_topk"]),
-            full=sizes("", "num_attention_heads"),
-            swa=sizes("swa_", "swa_num_attention_heads"),
             q_block=int(d.get("attn_q_block", 512)),
-            head_block=int(d.get("attn_head_block", 32)))
+            head_block=int(d.get("attn_head_block", 32)),
+            **_SPEC_KEYS[family](d, kinds))
 
     @property
     def layers(self) -> int:
@@ -134,8 +194,70 @@ class DecoderSpec:
     def full_layers(self) -> int:
         return sum(kind == FULL for kind in self.kinds)
 
-    def attn(self, kind: str) -> AttnSizes:
+    def attn(self, kind: str):
         return self.full if kind == FULL else self.swa
+
+
+def _share(d: Mapping, held_key: str) -> ExpertShare:
+    ep = int(d.get("ep_size", 1))
+    return expert_share(ep, int(d.get("ep_rank", 0)), int(d[held_key]) * ep)
+
+
+def _latent_keys(d: Mapping, kinds) -> dict:
+    def sizes(prefix, heads):
+        return AttnSizes(
+            int(d[heads]), int(d[prefix + "q_lora_rank"]),
+            int(d[prefix + "kv_lora_rank"]),
+            int(d[prefix + "qk_nope_head_dim"]),
+            int(d[prefix + "qk_rope_head_dim"]),
+            int(d[prefix + "v_head_dim"]), float(d[prefix + "rope_theta"]))
+    full = sizes("", "num_attention_heads")
+    swa = sizes("swa_", "swa_num_attention_heads")
+    return dict(
+        heads=tuple((full if k == FULL else swa).heads for k in kinds),
+        dense_layers=int(d["first_k_dense_replace"]),
+        shared_width=int(d["moe_intermediate_size"])
+        * int(d["n_shared_experts"]),
+        share=_share(d, "n_routed_experts"),
+        norm_weights=bool(d["norm_topk_prob"]),
+        routed_scale=float(d["routed_scaling_factor"]),
+        window=int(d["sliding_window_size"]),
+        index_heads=int(d["index_n_heads"]),
+        index_dim=int(d["index_head_dim"]),
+        index_topk=int(d["index_topk"]), full=full, swa=swa)
+
+
+def _grouped_keys(d: Mapping, kinds) -> dict:
+    groups, dim = int(d["num_key_value_heads"]), int(d["head_dim"])
+    heads = tuple(int(h) for h in d["num_attention_heads_per_layer"])[
+        :len(kinds)]
+    if len(heads) != len(kinds) or any(h % groups for h in heads):
+        raise ValueError("num_attention_heads_per_layer must give %d layers "
+                         "a multiple of %d k/v heads, got %r"
+                         % (len(kinds), groups, heads))
+    mlp = tuple(d["mlp_layer_types"])[:len(kinds)]
+    dense = sum(kind == "dense" for kind in mlp)
+    if mlp != ("dense",) * dense + ("sparse",) * (len(kinds) - dense):
+        raise ValueError("mlp_layer_types must name %d layers, the dense ones "
+                         "first, got %r" % (len(kinds), mlp))
+    if not d.get("gating"):
+        raise ValueError("family %s has the head-wise output gate: `gating` "
+                         "must be true" % GQA_FAMILY)
+    rope = d["rope_parameters"]
+    return dict(
+        heads=heads, dense_layers=dense,
+        shared_width=int(d["shared_expert_intermediate_size"]),
+        share=_share(d, "num_experts"), norm_weights=True,
+        routed_scale=float(d["moe_routed_scaling_factor"]),
+        window=int(d["sliding_window"]),
+        **{name: GroupedSizes(groups, dim, float(rope[kind]["rope_theta"]),
+                              *rotary_table(rope[kind], dim))
+           for name, kind in (("full", FULL), ("swa", SLIDING))})
+
+
+# what each family's source calls its sizes (ROADMAP D11/D13: the source's
+# keys as they stand)
+_SPEC_KEYS = {FAMILY: _latent_keys, GQA_FAMILY: _grouped_keys}
 
 
 _INIT = nn.initializers.normal(0.02)
@@ -285,17 +407,153 @@ def attention_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
     return _gated_output(p, xn, o), entry, kept
 
 
+def _latent_prefill_row(p, kind, spec, x, length, slots, faults=frozenset()):
+    out, entry, kept, ran = attention_prefill_row(p, kind, spec, x, length,
+                                                  slots, faults)
+    causal = length * (length + 1) // 2 * (kind == FULL)
+    return out, entry, {"keys_kept": kept, "keys_causal": causal,
+                        "q_blocks_run": ran}
+
+
+def _latent_step(p, kind, spec, x, pos, entry, faults=frozenset()):
+    out, entry, kept = attention_step(p, kind, spec, x, pos, entry, faults)
+    return out, entry, {"keys_kept": kept,
+                        "keys_causal": (pos + 1) * (kind == FULL)}
+
+
+# ---- the grouped-query attention -------------------------------------------------
+
+def _grouped_qkv(p, g: GroupedSizes, spec: DecoderSpec, x, pos, faults):
+    """x (..., hidden) raw at positions `pos` -> (xn, q (..., G, R, d), k, v
+    (..., G, d)), q and k rotated over their leading `rot` dims. Query head h
+    belongs to group h // R."""
+    xn = att.rms_norm(x, p["attn_norm"], spec.eps)
+    lead = x.shape[:-1]
+    q = jnp.dot(xn, p["w_q"])
+    ratio = q.shape[-1] // (g.kv_heads * g.head_dim)
+    if "kv_group_misassigned" in faults:  # head h reads group h % G
+        q = jnp.swapaxes(q.reshape(lead + (ratio, g.kv_heads, g.head_dim)),
+                         -3, -2)
+    else:
+        q = q.reshape(lead + (g.kv_heads, ratio, g.head_dim))
+    k = jnp.dot(xn, p["w_k"]).reshape(lead + (g.kv_heads, g.head_dim))
+    v = jnp.dot(xn, p["w_v"]).reshape(lead + (g.kv_heads, g.head_dim))
+    rot, freq, scale = g.rot, g.inv_freq, g.rope_scale
+    plain = lambda r: tuple(g.theta ** (-2.0 * j / r)  # noqa: E731
+                            for j in range(r // 2))
+    if "full_rope_whole_head" in faults and rot < g.head_dim:
+        rot, freq = g.head_dim, plain(g.head_dim)  # the partial factor lost
+    if "yarn_dropped" in faults and scale != 1.0:  # plain theta, no scale
+        freq, scale = plain(rot), 1.0
+    with jax.named_scope("rope"):
+        freq = jnp.asarray(freq, jnp.float32)
+        q = att.rotate_leading_by(q, pos, freq, scale, rot)
+        k = att.rotate_leading_by(k, pos, freq, scale, rot)
+    return xn, q, k, v
+
+
+def _grouped_output(p, xn, o, faults):
+    """o (..., G, R, d) -> (..., hidden): heads back in their order, the
+    head-wise gate, W_o."""
+    if "kv_group_misassigned" in faults:
+        o = jnp.swapaxes(o, -3, -2)
+    o = o.reshape(o.shape[:-3] + (-1, o.shape[-1]))
+    with jax.named_scope("gate"):
+        if "no_gate" in faults:
+            return jnp.dot(o.reshape(o.shape[:-2] + (-1,)), p["w_o"])
+        return _gated_output(p, xn, o)
+
+
+def grouped_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
+                        slots: int, faults=frozenset()):
+    """One sequence. x (P, hidden) raw, `length` its real rows. Returns
+    (attention output (P, hidden), the row's cache entry k, v (G, slots or
+    window, d), counts)."""
+    g = spec.attn(kind)
+    total = x.shape[0]
+    pos = jnp.arange(total, dtype=jnp.int32)
+    xn, q, k, v = _grouped_qkv(p, g, spec, x, pos, faults)
+    k, v = jnp.transpose(k, (1, 0, 2)), jnp.transpose(v, (1, 0, 2))
+    with jax.named_scope("kv_write"):
+        if kind == FULL:
+            window = None
+            pad = ((0, 0), (0, slots - total), (0, 0))
+            entry = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
+        else:
+            window = spec.window + ("window_off_by_one" in faults)
+            held = att.ring_positions(length[None] - 1, spec.window)[0]
+            take = lambda t: jnp.where(  # noqa: E731
+                held[:, None] >= 0, t[:, jnp.clip(held, 0, total - 1)], 0)
+            entry = {"k": take(k), "v": take(v)}
+    ratio = q.shape[-2]
+    # k/v groups a pass: about `head_block` query heads' scores at a time
+    step = max(1, spec.head_block // ratio)
+    while g.kv_heads % step:
+        step -= 1
+    o = att.blockwise_attention(
+        jnp.transpose(q, (1, 2, 0, 3)), k, v, q_block=spec.q_block,
+        window=window, length=length, head_block=step,
+        scale=1.0 / math.sqrt(g.head_dim))
+    ran = sum(jnp.asarray(live, jnp.int32)
+              for live in att.q_blocks_live(total, spec.q_block, length))
+    return (_grouped_output(p, xn, jnp.transpose(o, (2, 0, 1, 3)), faults),
+            entry, {"q_blocks_run": ran, "keys_causal":
+                    length * (length + 1) // 2 * (kind == FULL)})
+
+
+def grouped_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
+                 faults=frozenset()):
+    """One token a row. x (B, hidden) raw, pos (B,) its position, `entry` the
+    layer's k/v cache. Returns (attention output (B, hidden), the cache with
+    the token written, counts: the slots this step read and the real keys
+    among them; on a full layer those are the causal keys)."""
+    g = spec.attn(kind)
+    batch, groups = x.shape[0], g.kv_heads
+    xn, q, k, v = _grouped_qkv(p, g, spec, x, pos, faults)
+    slots = entry["k"].shape[2]
+    # the cache as (B x G, S, d) while it is written and read: one row of d
+    # a (row, group), at a slot; the write's index dims lead and the
+    # products have one batch dim, so the compiler keeps ONE layout for
+    # both (as (B, G, S, d) it wrote in one layout, read in another and
+    # copied every ring whole every step: PERF.md section 6, PR 33)
+    flat = lambda t: t.reshape((batch * groups,) + t.shape[2:])  # noqa: E731
+    with jax.named_scope("kv_write"):
+        if kind == FULL:
+            slot, tag = pos, "full"
+        else:
+            slot, tag = jnp.mod(pos, spec.window), "window"
+            if "stale_ring_row" in faults:  # every 7th slot's write is lost
+                slot = jnp.where(slot % 7 == 3, (slot + 1) % spec.window, slot)
+        at = jnp.arange(batch * groups), jnp.repeat(slot, groups)
+        cache_k = flat(entry["k"]).at[at].set(flat(k))
+        cache_v = flat(entry["v"]).at[at].set(flat(v))
+    if kind == FULL:
+        allowed = jnp.arange(slots, dtype=jnp.int32)[None, :] <= pos[:, None]
+    else:
+        allowed = att.ring_positions(pos, spec.window) >= 0
+    o = att.grouped_cache_attention(
+        flat(q), cache_k, cache_v, jnp.repeat(allowed, groups, axis=0),
+        1.0 / math.sqrt(g.head_dim)).reshape(q.shape)
+    entry = {"k": cache_k.reshape(entry["k"].shape),
+             "v": cache_v.reshape(entry["v"].shape)}
+    keys = jnp.sum(allowed, axis=-1, dtype=jnp.int32)
+    return _grouped_output(p, xn, o, faults), entry, {
+        "slots_" + tag: jnp.full(pos.shape, slots, jnp.int32),
+        "keys_" + tag: keys, "keys_causal": keys * (kind == FULL)}
+
+
 def expert_layer(p, spec: DecoderSpec, hn, token_real, faults=frozenset()):
     """hn (T, hidden) normed -> (y (T, hidden): the held experts' part plus
     the shared expert, local (T, k): each pair's held expert or `held`)."""
     with jax.named_scope("router"):
         bias = p["b_select"] * (0.0 if "no_select_bias" in faults else 1.0)
+        scale = 1.0 if "no_routed_scale" in faults else spec.routed_scale
         idx, weights = moe.route(hn, p["w_router"], bias, spec.per_token,
-                                 spec.norm_weights, spec.routed_scale)
+                                 spec.norm_weights, scale)
     with jax.named_scope("experts"):
         y, local = moe.routed_experts(hn, idx, weights, token_real,
                                       p["w_gate_up"], p["w_down"], spec.share)
-    if spec.shared and "no_shared" not in faults:
+    if spec.shared_width and "no_shared" not in faults:
         with jax.named_scope("shared_expert"):
             y = y + moe.swiglu(hn, p["shared_gate_up"], p["shared_down"])
     return y, local
@@ -307,6 +565,15 @@ def _pairs_by_expert(local, real, held: int):
     hit = (local[..., None] == jnp.arange(held, dtype=jnp.int32)) \
         & real[..., None, None]
     return jnp.sum(hit, axis=(1, 2), dtype=jnp.int32)
+
+
+def _visits_by_row(pairs):
+    """pairs (B, held) of one pass over an expert layer -> (B,) int32: the
+    experts that had at least one pair, each credited to the lowest row that
+    routed there (so the rows add up to the experts the batch visited)."""
+    hit = pairs > 0
+    first = hit & (jnp.cumsum(hit, axis=0, dtype=jnp.int32) == 1)
+    return jnp.sum(first, axis=1, dtype=jnp.int32)
 
 
 # ---- the modules --------------------------------------------------------------------
@@ -330,12 +597,16 @@ class Indexer(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Both latent attentions: `kind` picks the size set, the window or the
-    indexer. `__call__` returns the arrays; the arithmetic is
+    """Both latent attentions: the layer's kind picks the size set, the
+    window or the indexer. `__call__` returns the arrays; the arithmetic is
     `attention_prefill_row` / `attention_step`."""
     spec: DecoderSpec
-    kind: str
+    index: int
     dtype: jnp.dtype = jnp.bfloat16
+
+    @property
+    def kind(self) -> str:
+        return self.spec.kinds[self.index]
 
     @nn.compact
     def __call__(self):
@@ -355,6 +626,40 @@ class LatentAttention(nn.Module):
         if self.kind == FULL:
             p["indexer"] = Indexer(s, d, name="indexer")()
         return p
+
+
+class GroupedAttention(nn.Module):
+    """Grouped-query attention of layer `index`: its own count of query
+    heads over the kind's k/v heads. The arithmetic is `grouped_prefill_row`
+    / `grouped_step`."""
+    spec: DecoderSpec
+    index: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        s, d, heads = self.spec, self.dtype, self.spec.heads[self.index]
+        g = s.attn(s.kinds[self.index])
+        kv = g.kv_heads * g.head_dim
+        return {
+            "w_q": self.param("w_q", _INIT, (s.hidden, heads * g.head_dim), d),
+            "w_k": self.param("w_k", _INIT, (s.hidden, kv), d),
+            "w_v": self.param("w_v", _INIT, (s.hidden, kv), d),
+            "w_g": self.param("w_g", _INIT, (s.hidden, heads), d),
+            "w_o": self.param("w_o", _INIT, (heads * g.head_dim, s.hidden), d)}
+
+
+class Attention(NamedTuple):
+    """What the spec's family selects (module docstring)."""
+    module: Callable
+    prefill_row: Callable
+    step: Callable
+
+
+ATTENTIONS = {
+    FAMILY: Attention(LatentAttention, _latent_prefill_row, _latent_step),
+    GQA_FAMILY: Attention(GroupedAttention, grouped_prefill_row,
+                          grouped_step)}
 
 
 class DenseFFN(nn.Module):
@@ -377,7 +682,7 @@ class ExpertLayer(nn.Module):
     @nn.compact
     def __call__(self):
         s, d = self.spec, self.dtype
-        f, fs, held = s.expert_width, s.expert_width * s.shared, s.share.held
+        f, fs, held = s.expert_width, s.shared_width, s.share.held
         return {
             "w_router": self.param("w_router", _INIT,
                                    (s.hidden, s.share.n_routed), d),
@@ -408,7 +713,8 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self):
         s, d = self.spec, self.dtype
-        p = {"attn": dict(LatentAttention(s, self.kind, d, name="attn")(),
+        attention = ATTENTIONS[s.family].module
+        p = {"attn": dict(attention(s, self.index, d, name="attn")(),
                           attn_norm=self.param("attn_norm", _scale_init,
                                                (s.hidden,), d)),
              "ffn_norm": self.param("ffn_norm", _scale_init, (s.hidden,), d)}
@@ -439,9 +745,9 @@ def _feed_forward(p, spec: DecoderSpec, dense: bool, x, real, faults):
     return x + y.reshape(x.shape), local.reshape(x.shape[:2] + (-1,))
 
 
-class LatentMoEDecoder(nn.Module):
-    """`prefill` and `step`; `__call__` (what `init` runs) is a prefill of
-    the tokens given, all real."""
+class MoEDecoder(nn.Module):
+    """The one body of both families. `prefill` and `step`; `__call__` (what
+    `init` runs) is a prefill of the tokens given, all real."""
     spec: DecoderSpec
     dtype: jnp.dtype = jnp.bfloat16
     faults: frozenset = frozenset()  # tests only: a planted fault by name
@@ -464,6 +770,43 @@ class LatentMoEDecoder(nn.Module):
             return jnp.dot(att.rms_norm(h, self.final_norm, self.spec.eps),
                            self.lm_head, preferred_element_type=jnp.float32)
 
+    def _layers(self, x, real, attend, counts):
+        """The layer loop of `prefill` and `step` alike. x (B, T, hidden) or
+        (B, hidden); `attend(i, p, kind, x) -> (out, cache entry, the
+        attention's counts)`. Returns (x, the cache entries, `counts` with
+        this pass added)."""
+        s = self.spec
+        entries, pairs, visits = [], [], 0
+        tally = {name: counts[name] for name in ATTENTION_COUNTS}
+        for i, block in enumerate(self.layer):
+            p, kind = block(), s.kinds[i]
+            with jax.named_scope(_attn_scope(kind)):
+                out, entry, got = attend(i, p, kind, x)
+            x = x + out
+            entries.append(entry)
+            for name, value in got.items():
+                tally[name] = tally[name] + value
+            wide = x if x.ndim == 3 else x[:, None]
+            wide, local = _feed_forward(p, s, i < s.dense_layers, wide, real,
+                                        self.faults)
+            x = wide if x.ndim == 3 else wide[:, 0]
+            if local is not None:
+                pairs.append(_pairs_by_expert(local, real, s.share.held))
+                visits = visits + _visits_by_row(pairs[-1])
+        tally["expert_tokens"] = counts["expert_tokens"] + (
+            jnp.stack(pairs, axis=1) if pairs else 0)
+        tally["expert_visits"] = counts["expert_visits"] + visits
+        tally["q_blocks_total"] = counts["q_blocks_total"]
+        return x, tuple(entries), tally
+
+    def _no_counts(self, rows: int) -> dict:
+        s = self.spec
+        zeros = jnp.zeros((rows,), jnp.int32)
+        return dict({name: zeros for name in ATTENTION_COUNTS
+                     + ("expert_visits", "q_blocks_total")},
+                    expert_tokens=jnp.zeros(
+                        (rows, s.expert_layers, s.share.held), jnp.int32))
+
     def prefill(self, tokens, lengths, reserve: int = 0):
         """tokens int32 (B, P), lengths int32 (B,) -> (float32 logits (B,
         vocab) at each row's last real token, cache with `reserve` more
@@ -473,81 +816,50 @@ class LatentMoEDecoder(nn.Module):
         slots = total + reserve
         lengths = jnp.clip(lengths, 1, total)
         real = jnp.arange(total, dtype=jnp.int32)[None, :] < lengths[:, None]
-        params = [block() for block in self.layer]
-        entries, pairs = [], []
-        kept = jnp.zeros((rows,), jnp.int32)
-        ran = jnp.zeros((rows,), jnp.int32)
+        prefill_row = ATTENTIONS[s.family].prefill_row
+
+        def attend(i, p, kind, x):
+            return lax.map(lambda xl: prefill_row(
+                p["attn"], kind, s, xl[0], xl[1], slots, self.faults),
+                (x, lengths))
         with jax.named_scope("prefill"):
             with jax.named_scope("embed"):
                 x = self.embed[tokens]
-            for i, p in enumerate(params):
-                kind = s.kinds[i]
-                with jax.named_scope(_attn_scope(kind)):
-                    out, entry, k, r = lax.map(
-                        lambda xl, p=p, kind=kind: attention_prefill_row(
-                            p["attn"], kind, s, xl[0], xl[1], slots,
-                            self.faults), (x, lengths))
-                x = x + out
-                entries.append(entry)
-                kept += k
-                ran += r
-                x, local = _feed_forward(p, s, i < s.dense_layers, x, real,
-                                         self.faults)
-                if local is not None:
-                    pairs.append(_pairs_by_expert(local, real, s.share.held))
+            x, entries, counts = self._layers(x, real, attend,
+                                              self._no_counts(rows))
             last = x[jnp.arange(rows), lengths - 1]
             logits = self._logits(last)
-        counts = {"expert_tokens": jnp.stack(pairs, axis=1) if pairs else
-                  jnp.zeros((rows, 0, s.share.held), jnp.int32),
-                  "keys_kept": kept,
-                  "keys_causal": s.full_layers * lengths * (lengths + 1) // 2,
-                  "q_blocks_run": ran,
-                  "q_blocks_total": jnp.full(
-                      (rows,), s.layers * -(-total // s.q_block), jnp.int32)}
-        return logits, {"layers": tuple(entries), "counts": counts}
+        counts["q_blocks_total"] = jnp.full(
+            (rows,), s.layers * -(-total // s.q_block), jnp.int32)
+        return logits, {"layers": entries, "counts": counts}
 
     def step(self, token, positions, cache):
         """token int32 (B,) at `positions` (B,) -> (float32 logits (B,
         vocab), the cache with the token written and counted)."""
         s = self.spec
-        params = [block() for block in self.layer]
-        entries, pairs = [], []
-        counts = cache["counts"]
-        kept = counts["keys_kept"]
+        step = ATTENTIONS[s.family].step
         real = jnp.ones(token.shape + (1,), bool)
+
+        def attend(i, p, kind, x):
+            return step(p["attn"], kind, s, x, positions, cache["layers"][i],
+                        self.faults)
         with jax.named_scope("decode"):
             with jax.named_scope("embed"):
                 x = self.embed[token]
-            for i, p in enumerate(params):
-                kind = s.kinds[i]
-                with jax.named_scope(_attn_scope(kind)):
-                    out, entry, k = attention_step(
-                        p["attn"], kind, s, x, positions, cache["layers"][i],
-                        self.faults)
-                x = x + out
-                entries.append(entry)
-                kept = kept + k
-                x, local = _feed_forward(p, s, i < s.dense_layers,
-                                         x[:, None], real, self.faults)
-                x = x[:, 0]
-                if local is not None:
-                    pairs.append(_pairs_by_expert(local, real, s.share.held))
+            x, entries, counts = self._layers(x, real, attend,
+                                              cache["counts"])
             logits = self._logits(x)
-        counts = {"expert_tokens": counts["expert_tokens"]
-                  + (jnp.stack(pairs, axis=1) if pairs else 0),
-                  "keys_kept": kept,
-                  "keys_causal": counts["keys_causal"]
-                  + s.full_layers * (positions + 1),
-                  "q_blocks_run": counts["q_blocks_run"],
-                  "q_blocks_total": counts["q_blocks_total"]}
-        return logits, {"layers": tuple(entries), "counts": counts}
+        return logits, {"layers": entries, "counts": counts}
 
 
-def build_decoder(cfg, dtype: Optional[jnp.dtype] = None) -> LatentMoEDecoder:
+LatentMoEDecoder = MoEDecoder  # the name the first family's callers know
+
+
+def build_decoder(cfg, dtype: Optional[jnp.dtype] = None) -> MoEDecoder:
     """The decoder `cfg.decoder` describes (`models.build_model` dispatches
     here on `cfg.family`)."""
     if not getattr(cfg, "decoder", None):
         raise ValueError("family %r needs `decoder`: the source's config keys"
-                         % FAMILY)
-    return LatentMoEDecoder(DecoderSpec.from_mapping(cfg.decoder),
-                            dtype or jnp.bfloat16)
+                         % cfg.family)
+    return MoEDecoder(DecoderSpec.from_mapping(cfg.decoder, cfg.family),
+                      dtype or jnp.bfloat16)

@@ -18,9 +18,10 @@ Layers (PERF.md section 3): `stem` (PreLayer), `hourglass`, `neck`,
 `head`, `merge` (the inter-stack 1x1 convolutions of a multi-stack model),
 `normalize`, `loss`, `optimizer`, `peak`, `decode`, `nms`, with `/bwd`
 appended where the scope path runs through `transpose(`: the backward
-pass. The decoder family's: `prefill/<scope>` and `decode/<scope>` for the
+pass. The decoder families': `prefill/<scope>` and `decode/<scope>` for the
 scopes `embed`, `attn_full`, `indexer`, `attn_window`, `router`, `experts`,
-`shared_expert`, `dense_ffn`, `lm_head`. An operation the program named but outside those (the step counter's
+`shared_expert`, `dense_ffn`, `lm_head`, and under an attention layer its
+parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`). An operation the program named but outside those (the step counter's
 `jit(step)/add`, the network's own input cast) is `other`. An instruction
 XLA made itself carries no metadata (`copy`, `bitcast`, `copy-start/done`
 from layout assignment and memory-space assignment): it takes the layer
@@ -54,6 +55,9 @@ _LOOKED_THROUGH = ("StackedHourglass",)
 _DECODER_SCOPES = ("embed", "attn_full", "indexer", "attn_window", "router",
                    "experts", "shared_expert", "dense_ffn", "lm_head")
 _DECODER_PHASES = ("prefill", "decode")
+# parts of an attention layer, named under the layer that holds them
+# (`decode/attn_full/kv_write`)
+_DECODER_PARTS = ("rope", "kv_write", "gate")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
@@ -95,7 +99,8 @@ def layer_of(op_name: str) -> str:
     inner = [e for e in elements if e in _DECODER_SCOPES]
     if inner:
         phase = [e for e in elements if e in _DECODER_PHASES]
-        return "/".join(phase[:1] + inner[-1:])
+        part = [e for e in elements if e in _DECODER_PARTS]
+        return "/".join(phase[:1] + inner[-1:] + part[-1:])
     for element in elements:
         # peel the transforms jax wrote around the scope: jvp(...),
         # transpose(jvp(...)), vmap(...), checkpoint(...)

@@ -1,6 +1,8 @@
-"""Attention for the decoder family: norms and rotary positions, the exact
-top-k of the learned indexer, blockwise causal attention over a window or a
-chosen key set (prefill), and one query a row against a latent cache (decode).
+"""Attention for the decoder families: norms and rotary positions (plain theta,
+or a table of inverse frequencies with a scale: YaRN), the exact top-k of the
+learned indexer, blockwise causal attention over a window or a chosen key set
+(prefill; one k/v a head, or one k/v a GROUP of query heads, read once a
+group), and one query a row against a latent cache or a k/v cache (decode).
 
 The reference has no attention of any kind (ref hourglass.py is
 convolutions only); this module is new capability. Plain XLA: the matrix
@@ -46,25 +48,47 @@ def layer_norm(x, w, b, eps: float):
     return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotate(x, pos, theta: float):
-    """Rotary positions. x (..., d) with `pos` shaped like x's leading axes
-    up to where it stops: (T,) for x (T, H, d) or (T, d); (B,) for (B, H, d).
-    Computed in float32, returned in x's dtype."""
+def rotate_by(x, pos, freq, scale: float = 1.0):
+    """Rotary positions from a table. x (..., d), `freq` (d/2,) float32 the
+    inverse frequencies (dimension j pairs with j + d/2, angle pos * freq[j]),
+    `scale` on cos and sin alike (YaRN's attention factor; 1 multiplies
+    nothing). `pos` is shaped like x's leading axes up to where it stops:
+    (T,) for x (T, H, d) or (T, d); (B,) for (B, H, d). Computed in float32,
+    returned in x's dtype."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[..., None] * freq
     ang = ang.reshape(pos.shape + (1,) * (x.ndim - pos.ndim - 1) + (half,))
     a, b = (x[..., :half].astype(jnp.float32),
             x[..., half:].astype(jnp.float32))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
 
 
+def rotate(x, pos, theta: float):
+    """`rotate_by` at the plain frequencies theta^(-2j/d)."""
+    half = x.shape[-1] // 2
+    return rotate_by(x, pos,
+                     theta ** (-jnp.arange(half, dtype=jnp.float32) / half))
+
+
+def rotate_leading_by(x, pos, freq, scale: float, dims: int):
+    """`rotate_by` over the first `dims` of the last axis (all of it: no
+    cut), the rest as it is."""
+    if dims == x.shape[-1]:
+        return rotate_by(x, pos, freq, scale)
+    return jnp.concatenate([rotate_by(x[..., :dims], pos, freq, scale),
+                            x[..., dims:]], axis=-1)
+
+
 def rotate_leading(x, pos, theta: float, dims: int):
     """`rotate` over the first `dims` of the last axis, the rest as it is."""
-    return jnp.concatenate([rotate(x[..., :dims], pos, theta),
-                            x[..., dims:]], axis=-1)
+    half = dims // 2
+    return rotate_leading_by(
+        x, pos, theta ** (-jnp.arange(half, dtype=jnp.float32) / half), 1.0,
+        dims)
 
 
 # ---- the indexer's exact top-k -------------------------------------------------
@@ -186,7 +210,10 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
                         chosen: Optional[List[jax.Array]] = None,
                         head_block: Optional[int] = None):
     """Causal attention of one sequence. q (H, T, dq), k (H, T, dq), v (H, T,
-    dv) -> (H, T, dv); `scale` multiplies the scores (in float32). A q block
+    dv) -> (H, T, dv); or the grouped form, q (G, R, T, d) against k, v (G, T,
+    d) -> (G, R, T, d): the R query heads of a group read the group's one k/v
+    head, which is read once a group and never copied a head. `scale`
+    multiplies the scores (in float32). A q block
     [r0, r1) reads the keys [lo, r1): lo = r0 - (window - 1) under a window,
     else 0; inside, a key is allowed where s <= t, t - s < window and, with
     `chosen` (the list `select_blocks` gives, same q_block), where it was
@@ -194,11 +221,13 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
     maximum needed). `length` (int32 scalar, an operand) is the sequence's
     real rows: a block that starts at or past it is not computed and its rows
     are zeros; no real query reads a padded key (s <= t < length), so rows
-    below `length` are what the full length gives. Heads `head_block` at a
-    time under `lax.map`, so that the scores (float32, heads x q_block x
-    keys) stay small."""
-    heads, total = q.shape[0], q.shape[1]
+    below `length` are what the full length gives. The leading axis (heads,
+    or k/v groups) `head_block` at a time under `lax.map`, so that the scores
+    (float32, heads x q_block x keys) stay small."""
+    heads, total = q.shape[0], q.shape[-2]
     live = q_blocks_live(total, q_block, length)
+    qk, pv = (("grqd,gkd->grqk", "grqk,gkd->grqd") if q.ndim == 4 else
+              ("hqd,hkd->hqk", "hqk,hkd->hqd"))
 
     def group(qkv):
         qg, kg, vg = qkv
@@ -217,16 +246,16 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
                     allowed &= (t - s) < window
                 if chosen is not None:
                     allowed &= chosen[i][:, lo:]
-                sc = jnp.einsum("hqd,hkd->hqk", qg[:, r0:r1], kg[:, lo:r1],
+                sc = jnp.einsum(qk, qg[..., r0:r1, :], kg[:, lo:r1],
                                 preferred_element_type=jnp.float32)
                 p, denom = _masked_exp(sc * scale, allowed)
-                o = jnp.einsum("hqk,hkd->hqd", p.astype(vg.dtype),
-                               vg[:, lo:r1],
+                o = jnp.einsum(pv, p.astype(vg.dtype), vg[:, lo:r1],
                                preferred_element_type=jnp.float32)
                 return (o / denom).astype(vg.dtype)
             outs.append(_when(live[i], block,
-                              (qg.shape[0], r1 - r0, vg.shape[-1]), vg.dtype))
-        return jnp.concatenate(outs, axis=1)
+                              qg.shape[:-2] + (r1 - r0, vg.shape[-1]),
+                              vg.dtype))
+        return jnp.concatenate(outs, axis=-2)
 
     if not head_block or head_block >= heads:
         return group((q, k, v))
@@ -236,7 +265,7 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
     return out.reshape((heads,) + out.shape[2:])
 
 
-# ---- decode: one query a row against the latent cache ---------------------------
+# ---- decode: one query a row against a cache ------------------------------------
 
 def latent_cache_attention(q_lat, q_rope, c_kv, k_r, allowed, scale: float):
     """q_lat (B, H, r): the nope part of the query with W_uk absorbed; q_rope
@@ -251,6 +280,20 @@ def latent_cache_attention(q_lat, q_rope, c_kv, k_r, allowed, scale: float):
     o = jnp.einsum("bhs,bsr->bhr", p.astype(c_kv.dtype), c_kv,
                    preferred_element_type=jnp.float32)
     return (o / denom).astype(c_kv.dtype)
+
+
+def grouped_cache_attention(q, k, v, allowed, scale: float):
+    """One query a row against a k/v cache, a row being one k/v head of one
+    sequence: q (N, R, d), the R query heads that read the head; the cache
+    k, v (N, S, d); allowed (N, S) bool. Returns (N, R, d): every query head
+    of a group reads the group's one k/v head in place (no copy a head).
+    Scores and softmax in float32."""
+    s = jnp.einsum("nrd,nsd->nrs", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p, denom = _masked_exp(s, allowed[:, None, :])
+    o = jnp.einsum("nrs,nsd->nrd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return (o / denom).astype(v.dtype)
 
 
 def ring_positions(pos, width: int):

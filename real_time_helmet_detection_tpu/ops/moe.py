@@ -5,9 +5,13 @@ weighted way back to the tokens.
 
 The reference has no experts (ref hourglass.py is convolutions only); this
 module is new capability. No token is dropped for capacity: the sorted pairs
-are taken `capacity` rows at a time until all held pairs are done (one pass
-in all but pathological routings: the capacity is twice the rows an even
-routing gives this share).
+are taken `capacity` rows at a time until all held pairs are done. A share
+of an expert-parallel layer takes one pass in all but pathological routings
+(the capacity is twice the rows an even routing gives it); a layer that
+holds every expert (`ep_size` 1) holds every pair, and its passes are
+`MAX_PASS_ROWS` at most so that what a pass gathers stays bounded and the
+number of passes does not follow the batch's lengths (PERF.md section 6,
+PR 33).
 
 The grouped matmul is `ops/pallas/expert_gmm.py` on the TPU (PERF.md section
 6, PR 29, has the chip readings behind the choice); elsewhere Mosaic cannot
@@ -67,15 +71,28 @@ def grouped_swiglu(rows, w_gate_up, w_down, group_sizes,
     return mm(jax.nn.silu(gate) * up, w_down)
 
 
+# the most rows one pass gathers. A 32 x 1,024-slot prefill of a layer that
+# holds all its experts sorts up to 262,144 pairs, of which the real ones
+# (131,072-262,144 for prompts of 512-1,024 tokens: padding sorts last and is
+# not run) take TWO passes whatever the batch's lengths; at 65,536 the same
+# batches took 3 or 4 by their lengths and a batch's time followed (2%:
+# PERF.md section 6, PR 33). A pass of a 2,048-wide layer then stands as 0.54
+# GB of rows in, 0.40 GB between the products and 0.54 GB out, where all the
+# pairs at once would stand as twice that. An eighth's share of a 5,120-wide
+# layer keeps its own 65,536 (twice its even load)
+MAX_PASS_ROWS = 131072
+
+
 def capacity_rows(pairs: int, share: ExpertShare) -> int:
     """Rows a pass takes: twice what an even routing sends this share, in
-    whole row tiles, and never more than all the pairs."""
+    whole row tiles, never more than all the pairs nor than
+    `MAX_PASS_ROWS`."""
     tile = gmm.TILING[0]
     whole = -(-pairs // 16) * 16  # bfloat16 rows come 16 to a tile
     if whole <= tile:
         return whole
     even = -(-2 * pairs * share.held // share.n_routed // tile) * tile
-    return min(-(-whole // tile) * tile, max(tile, even))
+    return min(-(-whole // tile) * tile, max(tile, even), MAX_PASS_ROWS)
 
 
 def routed_experts(hn, idx, weights, token_real, w_gate_up, w_down,

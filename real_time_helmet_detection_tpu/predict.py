@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .config import DECODER_FAMILIES, MODEL_FAMILIES
 from .ops.decode import (CascadeDetections, Detections, confidence_summary,
                          decode_heatmap, decode_peak_scores)
 from .ops.nms import maxpool_nms_mask, nms_mask, soft_nms_mask
@@ -222,16 +223,24 @@ def make_predict_fn(model, cfg, normalize: str | None = None,
                    out_shardings=out_sh)
 
 
-# ---- the decoder family's generate program ---------------------------------------
+# ---- the decoder families' generate program --------------------------------------
 
 class Generation(NamedTuple):
     """What one generate call answers, rows first (the engine slices rows).
     `expert_tokens`: pairs routed to each held expert by expert layer, over
     every position of the row (prompt and fed-back tokens, padding excluded);
-    `keys_kept` / `keys_causal`: keys the indexer kept / keys causal, the
-    full layers summed; `q_blocks_run` / `q_blocks_total`: q blocks of prefill
-    attention the program ran (a block past the row's length is a branch not
-    taken) / that the padded row holds, the attention layers summed."""
+    `keys_kept` / `keys_causal`: keys the indexer kept / keys causal (what a
+    full layer's queries may read), the full layers summed; `q_blocks_run` /
+    `q_blocks_total`: q blocks of prefill attention the program ran (a block
+    past the row's length is a branch not taken) / that the padded row
+    holds, the attention layers summed;
+    `expert_visits`: for each prefill or step and expert layer, the experts
+    that had at least one pair, credited to the lowest row that routed there
+    (rows add up to the batch's count: what part of the experts' weights the
+    batch streamed); `cache_slots_read` / `cache_keys_real`: cache slots the
+    row's decode steps read and the real keys among them, by layer kind
+    (full, sliding). A count the family's attention does not have is zeros
+    (the latent family's caches, the grouped-query family's indexer)."""
     tokens: jax.Array          # int32 (B, N)
     logits_first: jax.Array    # float32 (B, V): at the prompt's last token
     logits_last: jax.Array     # float32 (B, V): the step that gave token N
@@ -241,6 +250,9 @@ class Generation(NamedTuple):
     prompt_len: jax.Array      # int32 (B,): the length the row stated
     q_blocks_run: jax.Array    # int32 (B,)
     q_blocks_total: jax.Array  # int32 (B,)
+    expert_visits: jax.Array     # int32 (B,)
+    cache_slots_read: jax.Array  # int32 (B, 2): full, sliding
+    cache_keys_real: jax.Array   # int32 (B, 2)
 
 
 def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
@@ -254,9 +266,11 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
     new_tokens = int(new_tokens)
     if new_tokens < 1:
         raise ValueError("new_tokens must be >= 1, got %d" % new_tokens)
-    if getattr(cfg, "family", "hourglass") != "latent_moe_decoder":
-        raise ValueError("make_generate_fn serves family latent_moe_decoder, "
-                         "got %r" % (getattr(cfg, "family", None),))
+    if getattr(cfg, "family", "hourglass") not in DECODER_FAMILIES:
+        raise ValueError("make_generate_fn serves the families %s (of %s), "
+                         "got %r" % (", ".join(DECODER_FAMILIES),
+                                     ", ".join(MODEL_FAMILIES),
+                                     getattr(cfg, "family", None)))
 
     def generate(variables, payload):
         lengths = jnp.clip(payload[:, 0], 1, payload.shape[1] - 1)
@@ -281,7 +295,12 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             expert_tokens=counts["expert_tokens"],
             keys_kept=counts["keys_kept"], keys_causal=counts["keys_causal"],
             prompt_len=lengths, q_blocks_run=counts["q_blocks_run"],
-            q_blocks_total=counts["q_blocks_total"])
+            q_blocks_total=counts["q_blocks_total"],
+            expert_visits=counts["expert_visits"],
+            cache_slots_read=jnp.stack([counts["slots_full"],
+                                        counts["slots_window"]], axis=1),
+            cache_keys_real=jnp.stack([counts["keys_full"],
+                                       counts["keys_window"]], axis=1))
 
     return jax.jit(generate)
 
@@ -300,7 +319,17 @@ def generation_counters(p_max: int) -> Callable:
                "gen.keys_causal": int(np.sum(rows.keys_causal,
                                              dtype=np.int64)),
                "gen.q_blocks_run": int(np.sum(rows.q_blocks_run)),
-               "gen.q_blocks_total": int(np.sum(rows.q_blocks_total))}
+               "gen.q_blocks_total": int(np.sum(rows.q_blocks_total)),
+               # one call is one batch: every expert layer is passed once
+               # by the prefill and once by each step after the first token
+               "gen.expert_passes": int(rows.expert_tokens.shape[1]
+                                        * rows.tokens.shape[1]),
+               "gen.expert_visits": int(np.sum(rows.expert_visits))}
+        for j, kind in enumerate(("full", "window")):
+            out["gen.cache_slots." + kind] = int(np.sum(
+                rows.cache_slots_read[:, j], dtype=np.int64))
+            out["gen.cache_keys." + kind] = int(np.sum(
+                rows.cache_keys_real[:, j], dtype=np.int64))
         by_expert = np.sum(rows.expert_tokens, axis=(0, 1), dtype=np.int64)
         for e, pairs in enumerate(by_expert):
             out["gen.expert_pairs.e%02d" % e] = int(pairs)

@@ -1,15 +1,23 @@
-"""Time the three grouped matmuls the expert layer could use, at the decoder
+"""Time the three grouped matmuls the expert layer could use, at a decoder
 cell's two shapes, on the chip: `lax.ragged_dot`, jax's shipped megablox `gmm`
-and `ops/pallas/expert_gmm.py` (PERF.md section 6, PR 29, has the readings
-behind the choice in ops/moe.py).
+and `ops/pallas/expert_gmm.py` (PERF.md section 6, PR 29 and PR 33, has the
+readings behind the choice in ops/moe.py).
 
     python scripts/gmm_bench.py [--out chiprun_out/pr29/gmm_bench.json]
+    python scripts/gmm_bench.py --config benchmark/configs/laguna-xs2-l5.json \
+        --prefill 65536,65536,256 --decode 256,256,162
 
-Prefill: 65,536 sorted rows of which 32,768 belong to the 32 held experts
-(about 1,024 an expert, uneven), hidden 5120 -> 3072 and 1536 -> 5120.
-Decode: 32 rows of which 4 belong to 4 experts. Only on the chip (the
-reference has no such tool: ref train.py:92-140 keeps per-segment meters
-only); a time from the CPU would say nothing.
+The expert width and count come from a benchmark configuration file (its
+`fields`: `hidden_size`, `moe_intermediate_size`, and the experts held,
+`n_routed_experts` or `num_experts`); a phase is `rows,valid,hit`: sorted
+rows, how many of them belong to held experts, over how many experts
+(uneven). Defaults, dots3's: prefill 65,536 rows of which 32,768 belong to
+the 32 held experts, hidden 5120 -> 3072 and 1536 -> 5120; decode 32 rows of
+which 4 belong to 4 experts. Laguna's (above): a pass of 65,536 rows over
+all 256 experts, 2048 -> 1024 and 512 -> 2048; a decode step's 256 rows over
+about 162. Only on the chip (the reference has no such tool: ref
+train.py:92-140 keeps per-segment meters only); a time from the CPU would
+say nothing.
 """
 
 from __future__ import annotations
@@ -39,7 +47,15 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="")
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--config", default=os.path.join(
+        REPO, "benchmark", "configs", "dots3-note-prev-ep8-l5.json"))
+    ap.add_argument("--prefill", default="65536,32768,32")
+    ap.add_argument("--decode", default="32,4,4")
     args = ap.parse_args(argv)
+    with open(args.config) as f:
+        fields = json.load(f)["fields"]
+    hidden, width = fields["hidden_size"], fields["moe_intermediate_size"]
+    groups = fields.get("n_routed_experts", fields.get("num_experts"))
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("gmm_bench: no TPU; a time from %r says nothing"
                          % jax.devices()[0].platform)
@@ -56,13 +72,13 @@ def main(argv=None) -> int:
         out[rng.permutation(groups)[:hit]] = part
         return out
 
-    for phase, m, valid, hit, tiling in (
-            ("prefill", 65536, 32768, 32, own.TILING),
-            ("decode", 32, 4, 4, own.TILING)):
-        sizes = jnp.asarray(sizes_of(valid, 32, hit))
-        for k, n in ((5120, 3072), (1536, 5120)):
+    for phase, shape, tiling in (("prefill", args.prefill, own.TILING),
+                                 ("decode", args.decode, own.TILING)):
+        m, valid, hit = (int(v) for v in shape.split(","))
+        sizes = jnp.asarray(sizes_of(valid, groups, hit))
+        for k, n in ((hidden, 2 * width), (width, hidden)):
             lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
-            rhs = jnp.asarray(0.02 * rng.standard_normal((32, k, n)),
+            rhs = jnp.asarray(0.02 * rng.standard_normal((groups, k, n)),
                               jnp.bfloat16)
             tm = own.row_tile(m, tiling)
             calls = {
@@ -94,7 +110,10 @@ def main(argv=None) -> int:
                     err = float(np.abs(got - want).max())
                     row = {"phase": phase, "m": m, "k": k, "n": n,
                            "impl": name, "ms": ms, "max_diff": err,
-                           "tflops": 2 * valid * k * n / ms / 1e9}
+                           "tflops": 2 * valid * k * n / ms / 1e9,
+                           # each hit expert's weights once, rows in and out
+                           "gb_per_s": 2 * (hit * k * n + valid * (k + n))
+                           / ms / 1e6}
                 except Exception as e:  # noqa: BLE001 - a refusal is a reading
                     row = {"phase": phase, "m": m, "k": k, "n": n,
                            "impl": name, "error": repr(e)[:300]}
