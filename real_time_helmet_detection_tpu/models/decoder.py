@@ -69,7 +69,8 @@ GQA_FAMILY = "gqa_moe_decoder"
 # per-row counts an attention may give (int32 (B,)); the body adds the layers
 # up and a count the family's attention does not give stays zeros
 ATTENTION_COUNTS = ("keys_kept", "keys_causal", "q_blocks_run",
-                    "slots_full", "keys_full", "slots_window", "keys_window")
+                    "q_blocks_fused", "slots_full", "keys_full",
+                    "slots_window", "keys_window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -324,12 +325,10 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
     pos = jnp.arange(total, dtype=jnp.int32)
     xn = att.rms_norm(x, p["attn_norm"], spec.eps)
     c_q, q = _queries(p, a, spec, xn)
-    q = jnp.concatenate([q[..., :a.nope],
-                         att.rotate(q[..., a.nope:], pos, a.theta)], axis=-1)
+    q_nope, q_rope = q[..., :a.nope], att.rotate(q[..., a.nope:], pos,
+                                                 a.theta)
     c_kv, k_r = _latents(p, a, spec, xn, pos)
     kv = jnp.dot(c_kv, p["w_ukv"]).reshape(total, a.heads, a.nope + a.v)
-    k = jnp.concatenate([kv[..., :a.nope], jnp.broadcast_to(
-        k_r[:, None, :], (total, a.heads, a.rope))], axis=-1)
     heads_first = lambda t: jnp.transpose(t, (1, 0, 2))  # noqa: E731
     kept = jnp.zeros((), jnp.int32)
     chosen, window, entry = None, None, {}
@@ -356,10 +355,22 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
         take = lambda t: jnp.where(  # noqa: E731
             held[:, None] >= 0, t[jnp.clip(held, 0, total - 1)], 0)
         entry = {"c_kv": take(c_kv), "k_r": take(k_r)}
-    o = att.blockwise_attention(
-        heads_first(q), heads_first(k), heads_first(kv[..., a.nope:]),
-        q_block=spec.q_block, window=window, chosen=chosen, length=length,
-        head_block=spec.head_block, scale=1.0 / math.sqrt(a.nope + a.rope))
+    how = dict(q_block=spec.q_block, window=window, chosen=chosen,
+               length=length, head_block=spec.head_block,
+               scale=1.0 / math.sqrt(a.nope + a.rope))
+    if att.runs_fused(3, total, spec.q_block, window):
+        # the rotary part apart: its keys are ONE array for every head, which
+        # the kernel fetches once a tile and the XLA path copies a head
+        o = att.blockwise_attention(
+            heads_first(q_nope), heads_first(kv[..., :a.nope]),
+            heads_first(kv[..., a.nope:]),
+            shared=(heads_first(q_rope), k_r), **how)
+    else:
+        k = jnp.concatenate([kv[..., :a.nope], jnp.broadcast_to(
+            k_r[:, None, :], (total, a.heads, a.rope))], axis=-1)
+        o = att.blockwise_attention(
+            heads_first(jnp.concatenate([q_nope, q_rope], axis=-1)),
+            heads_first(k), heads_first(kv[..., a.nope:]), **how)
     ran = sum(jnp.asarray(live, jnp.int32)
               for live in att.q_blocks_live(total, spec.q_block, length))
     return _gated_output(p, xn, heads_first(o)), entry, kept, ran
@@ -411,8 +422,11 @@ def _latent_prefill_row(p, kind, spec, x, length, slots, faults=frozenset()):
     out, entry, kept, ran = attention_prefill_row(p, kind, spec, x, length,
                                                   slots, faults)
     causal = length * (length + 1) // 2 * (kind == FULL)
+    # the q blocks the fused kernel ran: all of a layer's or none
+    fused = att.runs_fused(3, x.shape[0], spec.q_block,
+                           None if kind == FULL else spec.window)
     return out, entry, {"keys_kept": kept, "keys_causal": causal,
-                        "q_blocks_run": ran}
+                        "q_blocks_run": ran, "q_blocks_fused": ran * fused}
 
 
 def _latent_step(p, kind, spec, x, pos, entry, faults=frozenset()):

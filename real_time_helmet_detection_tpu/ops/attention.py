@@ -5,9 +5,12 @@ learned indexer, blockwise causal attention over a window or a chosen key set
 group), and one query a row against a latent cache or a k/v cache (decode).
 
 The reference has no attention of any kind (ref hourglass.py is
-convolutions only); this module is new capability. Plain XLA: the matrix
-products are large enough for the MXU as they stand, and the selection is a
-mask over dense causal blocks (skipping unchosen key blocks is a later step).
+convolutions only); this module is new capability. Plain XLA, but for
+prefill's per-head calls without a window, which are one fused kernel where
+Mosaic compiles (`runs_fused`, `ops/pallas/attention.py`): there the key
+range grows with the prompt and XLA's float32 scores go through HBM three
+times a q block. The selection is a mask over dense causal blocks (skipping
+unchosen key blocks is a later step).
 Prefill walks a sequence a q block at a time and is told the sequence's real
 `length`: a q block that starts at or past it is a branch not taken on the
 device (`lax.cond`), so padding at the end of a row costs no scores, no top-k
@@ -29,9 +32,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .moe import kernel_compiles
+from .pallas import attention as fused
+
 # masked scores: finite, so that a row with nothing allowed (a padded query)
 # gives numbers, not NaN
-NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
+NEG = fused.NEG
 
 
 def rms_norm(x, w, eps: float):
@@ -205,10 +211,31 @@ def _masked_exp(scores, allowed):
 
 # ---- prefill: blockwise causal attention ------------------------------------------
 
+def chosen_mask(chosen: List[jax.Array], total: int):
+    """The list `select_blocks` gives as ONE int8 (T, T) array (keys past a
+    block's end are 0): what the fused kernel reads a tile at a time."""
+    return jnp.concatenate([jnp.pad(c, ((0, 0), (0, total - c.shape[1])))
+                            for c in chosen]).astype(jnp.int8)
+
+
+def runs_fused(q_ndim: int, total: int, q_block: int,
+               window: Optional[int]) -> bool:
+    """Does `blockwise_attention` hand this call to the fused kernel
+    (`ops/pallas/attention.py`)? The per-head form without a window, in whole
+    q blocks, where Mosaic compiles: no window means the key range grows with
+    the prompt and the float32 scores of the XLA path leave fast memory (12 B
+    a score through HBM: PERF.md section 6, PR 34); under a window (<= 1,024
+    keys a q block) they stay there, and the grouped form has met no long
+    prompt yet."""
+    return (q_ndim == 3 and window is None and total % q_block == 0
+            and kernel_compiles())
+
+
 def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
                         window: Optional[int] = None,
                         chosen: Optional[List[jax.Array]] = None,
-                        head_block: Optional[int] = None):
+                        head_block: Optional[int] = None,
+                        shared: Optional[tuple] = None):
     """Causal attention of one sequence. q (H, T, dq), k (H, T, dq), v (H, T,
     dv) -> (H, T, dv); or the grouped form, q (G, R, T, d) against k, v (G, T,
     d) -> (G, R, T, d): the R query heads of a group read the group's one k/v
@@ -223,8 +250,21 @@ def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
     are zeros; no real query reads a padded key (s <= t < length), so rows
     below `length` are what the full length gives. The leading axis (heads,
     or k/v groups) `head_block` at a time under `lax.map`, so that the scores
-    (float32, heads x q_block x keys) stay small."""
+    (float32, heads x q_block x keys) stay small. `shared` (per-head form
+    only): a further part of the scores that every head reads from ONE key
+    array, (q_s (H, T, ds), k_s (T, ds)): the scores are q . k + q_s . k_s.
+    A call that `runs_fused` is one fused kernel instead (same mathematics,
+    a running maximum, the scores never in HBM; `head_block` is then the
+    kernel's own)."""
     heads, total = q.shape[0], q.shape[-2]
+    if runs_fused(q.ndim, total, q_block, window):
+        mask = None if chosen is None else chosen_mask(chosen, total)
+        return fused.attn_fused(q, k, v, length, mask, *(shared or ()),
+                                q_block=q_block, scale=scale)
+    if shared is not None:
+        q = jnp.concatenate([q, shared[0]], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            shared[1], k.shape[:-1] + shared[1].shape[-1:])], axis=-1)
     live = q_blocks_live(total, q_block, length)
     qk, pv = (("grqd,gkd->grqk", "grqk,gkd->grqd") if q.ndim == 4 else
               ("hqd,hkd->hqk", "hqk,hkd->hqd"))

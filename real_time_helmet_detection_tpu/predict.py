@@ -233,7 +233,9 @@ class Generation(NamedTuple):
     full layer's queries may read), the full layers summed; `q_blocks_run` /
     `q_blocks_total`: q blocks of prefill attention the program ran (a block
     past the row's length is a branch not taken) / that the padded row
-    holds, the attention layers summed;
+    holds, the attention layers summed; `q_blocks_fused`: those of
+    `q_blocks_run` that the fused attention kernel ran (`attn_fused`: the
+    window-less per-head layers where Mosaic compiles, zeros elsewhere);
     `expert_visits`: for each prefill or step and expert layer, the experts
     that had at least one pair, credited to the lowest row that routed there
     (rows add up to the batch's count: what part of the experts' weights the
@@ -250,6 +252,7 @@ class Generation(NamedTuple):
     prompt_len: jax.Array      # int32 (B,): the length the row stated
     q_blocks_run: jax.Array    # int32 (B,)
     q_blocks_total: jax.Array  # int32 (B,)
+    q_blocks_fused: jax.Array  # int32 (B,)
     expert_visits: jax.Array     # int32 (B,)
     cache_slots_read: jax.Array  # int32 (B, 2): full, sliding
     cache_keys_real: jax.Array   # int32 (B, 2)
@@ -296,6 +299,7 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             keys_kept=counts["keys_kept"], keys_causal=counts["keys_causal"],
             prompt_len=lengths, q_blocks_run=counts["q_blocks_run"],
             q_blocks_total=counts["q_blocks_total"],
+            q_blocks_fused=counts["q_blocks_fused"],
             expert_visits=counts["expert_visits"],
             cache_slots_read=jnp.stack([counts["slots_full"],
                                         counts["slots_window"]], axis=1),
@@ -320,6 +324,7 @@ def generation_counters(p_max: int) -> Callable:
                                              dtype=np.int64)),
                "gen.q_blocks_run": int(np.sum(rows.q_blocks_run)),
                "gen.q_blocks_total": int(np.sum(rows.q_blocks_total)),
+               "gen.q_blocks_fused": int(np.sum(rows.q_blocks_fused)),
                # one call is one batch: every expert layer is passed once
                # by the prefill and once by each step after the first token
                "gen.expert_passes": int(rows.expert_tokens.shape[1]

@@ -14,7 +14,8 @@ leaves under `--out`:
     <cell>.xplane.pb     the device-only trace (with --keep-trace: tens of MB)
     <cell>.scopes.json   {program: {instruction: layer}}
     <cell>.window.json   {"window_ns": [a, b], "window_s", "steps"|"images",
-                          "compile_spans_in_window"}
+                          "compile_spans_in_window", "gen_counters" (the
+                          generate cells: q blocks run / fused / held)}
     <cell>.layers.txt    scripts/trace_summary.py's table over that window
     <cell>.layers.json   the same summary whole: seconds of every layer and
                          of every instruction, not the table's top 40
@@ -100,6 +101,13 @@ def record(workload: str, seed: int, seconds: float, out: str,
     ring = default_tracer().snapshot(since=window["t0"])
     note["compile_spans_in_window"] = None if ring is None else sum(
         n == "compile" and t0 <= t1 for n, t0, _, _ in ring)
+    if hasattr(cell, "registry"):
+        # the generate cells' counters no driver's result line carries: q
+        # blocks of prefill attention run / fused / held, over the whole run
+        note["gen_counters"] = {
+            name: cell.registry.counter(name).value
+            for name in ("gen.requests", "gen.q_blocks_run",
+                         "gen.q_blocks_fused", "gen.q_blocks_total")}
     if found:
         if keep_trace:
             shutil.copyfile(found, base + ".xplane.pb")
