@@ -3,9 +3,16 @@ SwiGLU layer, sigmoid-routed experts with a shared expert as one share of an
 expert-parallel deployment or held whole, counts, head; `prefill` and `step`)
 behind which the spec selects one of two attentions (`ATTENTIONS`):
 
-`latent_moe_decoder`  latent attention: full layers behind a learned sparse
-    indexer, sliding layers behind a window, a head-wise output gate on both;
-    decode with W_uk / W_uv absorbed into the query and the output.
+`latent_moe_decoder`  latent attention; decode with W_uk / W_uv absorbed into
+    the query and the output. What the source's mapping holds decides the
+    rest: `layer_types` with sliding layers (a second size set `swa_*` behind
+    `sliding_window_size`; absent: every layer full), `index_topk` (a learned
+    sparse indexer on full layers; absent: every causal key), an
+    `attention_gate_type` (a head-wise output gate; absent: W_o alone),
+    `apply_mla_qkv_lora_rescale` (the latents times sqrt(hidden / rank)),
+    `rope_scaling` of type yarn (a frequency table, the whole score times
+    m^2), `n_group` / `topk_group` (the router's choice limited by groups),
+    `topk_method` (`noaux_tc`: a selection bias; else none).
 `gqa_moe_decoder`     grouped-query attention: 8 k/v heads shared by a
     number of query heads that differs BY LAYER, rotary positions from a table
     (YaRN on half the head on full layers, plain theta on the whole head on
@@ -32,16 +39,18 @@ cannot be entered under `lax.map`).
 
 Cache, one entry a layer (`cache["layers"][i]`):
   full layer     at absolute positions (S = prompt slots + reserved): latent
-                 c_kv (B, S, kv_rank), k_r (B, S, rope), k_i (B, S, index
-                 dim); grouped-query k, v (B, S, kv heads, head dim), rotated
-                 before they are stored;
+                 c_kv (B, S, kv_rank), k_r (B, S, rope) and, behind an
+                 indexer, k_i (B, S, index dim); grouped-query k, v (B, S,
+                 kv heads, head dim), rotated before they are stored;
   sliding layer  a ring, position p at slot p % window: latent c_kv (B,
                  window, kv_rank), k_r (B, window, rope); grouped-query k, v
                  (B, window, kv heads, head dim).
 `cache["counts"]`, per row: pairs routed to each held expert by expert layer
 (padding excluded); expert visits (for each prefill or step and expert layer,
 the experts that had at least one pair, credited to the lowest row that
-routed there, so that rows add up to the batch's count); the q blocks of
+routed there, so that rows add up to the batch's count); under a router with
+groups, the (position, expert layer) slots and those of them where a group
+this share holds was among the groups kept; the q blocks of
 prefill attention that ran and that the padded row holds, attention layers
 summed; and what one attention counts (`ATTENTION_COUNTS`; zeros for the
 other): keys the indexer kept and keys causal, full layers summed; cache
@@ -75,7 +84,11 @@ ATTENTION_COUNTS = ("keys_kept", "keys_causal", "q_blocks_run",
 
 @dataclasses.dataclass(frozen=True)
 class AttnSizes:
-    """One layer kind of the latent family."""
+    """One layer kind of the latent family. `inv_freq` (rope/2,): the rotary
+    table where the source scales its positions (None: plain `theta`),
+    `rope_scale` on cos and sin; `scale` multiplies every score (nope and
+    rope parts alike); `rescale`: the latents times sqrt(hidden / rank)
+    after their norms; `gate`: the head-wise output gate."""
     heads: int
     q_rank: int
     kv_rank: int
@@ -83,6 +96,11 @@ class AttnSizes:
     rope: int
     v: int
     theta: float
+    scale: float
+    rescale: bool
+    gate: bool
+    inv_freq: Optional[Tuple[float, ...]] = None
+    rope_scale: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,13 +125,23 @@ def rotary_table(params: Mapping, head_dim: int) -> Tuple[int, tuple, float]:
     source leaves it out). Python floats: the table does not depend on the
     sequence's length."""
     rot = int(round(head_dim * float(params.get("partial_rotary_factor", 1))))
-    theta, half = float(params["rope_theta"]), rot // 2
-    freq = [theta ** (-2.0 * j / rot) for j in range(half)]
+    theta = float(params["rope_theta"])
     kind = params.get("rope_type", "default")
     if kind == "default":
-        return rot, tuple(freq), 1.0
+        return rot, tuple(theta ** (-2.0 * j / rot)
+                          for j in range(rot // 2)), 1.0
     if kind != "yarn":
         raise ValueError("rope_type %r is not default | yarn" % (kind,))
+    factor = float(params["factor"])
+    scale = float(params.get("attention_factor")
+                  or 0.1 * math.log(factor) + 1.0)
+    return rot, yarn_frequencies(theta, rot, params), scale
+
+
+def yarn_frequencies(theta: float, rot: int, params: Mapping) -> tuple:
+    """theta^(-2j/rot), each blended with itself / `factor` along a ramp
+    between the dimensions that turn `beta_fast` and `beta_slow` times over
+    `original_max_position_embeddings`."""
     factor = float(params["factor"])
     span = float(params["original_max_position_embeddings"])
 
@@ -123,11 +151,31 @@ def rotary_table(params: Mapping, head_dim: int) -> Tuple[int, tuple, float]:
     low = max(math.floor(turns_at(float(params["beta_fast"]))), 0)
     high = min(math.ceil(turns_at(float(params["beta_slow"]))), rot - 1)
     ramp = [min(1.0, max(0.0, (j - low) / max(high - low, 1e-3)))
-            for j in range(half)]
-    scale = float(params.get("attention_factor")
-                  or 0.1 * math.log(factor) + 1.0)
-    return rot, tuple(f * ((1 - r) + r / factor)
-                      for f, r in zip(freq, ramp)), scale
+            for j in range(rot // 2)]
+    return tuple(theta ** (-2.0 * j / rot) * ((1 - r) + r / factor)
+                 for j, r in enumerate(ramp))
+
+
+def latent_rotary(scaling: Optional[Mapping], theta: float, rope: int):
+    """(inv_freq or None, scale on cos and sin, multiplier of the score) of
+    a latent attention's `rope_scaling` (the source's own form: `type`,
+    `factor`, `mscale`, `mscale_all_dim`). None: plain theta, nothing
+    scaled. yarn: the blended table; cos and sin times m(mscale) /
+    m(mscale_all_dim), the WHOLE score times m(mscale_all_dim)^2, with
+    m(x) = 0.1 x ln factor + 1."""
+    if not scaling:
+        return None, 1.0, 1.0
+    if scaling.get("type") != "yarn":
+        raise ValueError("rope_scaling type %r is not yarn"
+                         % (scaling.get("type"),))
+
+    def m(x):
+        factor = float(scaling["factor"])
+        return 0.1 * float(x) * math.log(factor) + 1.0 if factor > 1 else 1.0
+    all_dim = scaling.get("mscale_all_dim", 0)
+    return (yarn_frequencies(theta, rope, scaling),
+            m(scaling.get("mscale", 1)) / m(all_dim),
+            m(all_dim) ** 2 if all_dim else 1.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +204,10 @@ class DecoderSpec:
     swa: object
     index_heads: int = 0
     index_dim: int = 0
-    index_topk: int = 0
+    index_topk: int = 0    # 0: no indexer, a full layer reads every causal key
+    n_group: int = 0       # 0: the router's choice knows no groups
+    topk_group: int = 0
+    select_bias: bool = True
     # how the program walks a prompt (not the model's): query rows a block,
     # heads a pass of the attention and of the indexer
     q_block: int = 512
@@ -168,7 +219,8 @@ class DecoderSpec:
             raise ValueError("no decoder of family %r (have: %s)"
                              % (family, ", ".join(sorted(_SPEC_KEYS))))
         layers = int(d["num_hidden_layers"])
-        kinds = tuple(d["layer_types"])[:layers]
+        # a source without `layer_types` has one kind of layer
+        kinds = tuple(d.get("layer_types") or (FULL,) * layers)[:layers]
         if len(kinds) != layers or set(kinds) - {FULL, SLIDING}:
             raise ValueError("layer_types must name %d layers as %s or %s, "
                              "got %r" % (layers, FULL, SLIDING, kinds))
@@ -199,33 +251,53 @@ class DecoderSpec:
         return self.full if kind == FULL else self.swa
 
 
-def _share(d: Mapping, held_key: str) -> ExpertShare:
+def _share(d: Mapping, held_key: str, n_group: int = 0) -> ExpertShare:
     ep = int(d.get("ep_size", 1))
-    return expert_share(ep, int(d.get("ep_rank", 0)), int(d[held_key]) * ep)
+    return expert_share(ep, int(d.get("ep_rank", 0)), int(d[held_key]) * ep,
+                        n_group)
 
 
 def _latent_keys(d: Mapping, kinds) -> dict:
-    def sizes(prefix, heads):
+    """The latent family reads the source's keys as they stand: a key that
+    is absent (or null) selects the absent part (module docstring)."""
+    def sizes(prefix):
+        gate = d.get(prefix + "attention_gate_type")
+        if gate not in (None, "headwise"):
+            raise ValueError("%sattention_gate_type %r is not headwise"
+                             % (prefix, gate))
+        nope, rope = (int(d[prefix + "qk_nope_head_dim"]),
+                      int(d[prefix + "qk_rope_head_dim"]))
+        theta = float(d[prefix + "rope_theta"])
+        freq, rope_scale, score = latent_rotary(
+            d.get(prefix + "rope_scaling"), theta, rope)
         return AttnSizes(
-            int(d[heads]), int(d[prefix + "q_lora_rank"]),
-            int(d[prefix + "kv_lora_rank"]),
-            int(d[prefix + "qk_nope_head_dim"]),
-            int(d[prefix + "qk_rope_head_dim"]),
-            int(d[prefix + "v_head_dim"]), float(d[prefix + "rope_theta"]))
-    full = sizes("", "num_attention_heads")
-    swa = sizes("swa_", "swa_num_attention_heads")
+            int(d[prefix + "num_attention_heads"]),
+            int(d[prefix + "q_lora_rank"]), int(d[prefix + "kv_lora_rank"]),
+            nope, rope, int(d[prefix + "v_head_dim"]), theta,
+            scale=score / math.sqrt(nope + rope),
+            rescale=bool(d.get("apply_mla_qkv_lora_rescale")),
+            gate=gate is not None, inv_freq=freq, rope_scale=rope_scale)
+    full = sizes("")
+    swa = sizes("swa_") if SLIDING in kinds else None
+    n_group = int(d.get("n_group") or 0)
+    topk_group = int(d.get("topk_group") or 0)
+    if n_group and not 0 < topk_group <= n_group:
+        raise ValueError("topk_group %d is not 1..n_group %d"
+                         % (topk_group, n_group))
+    index_topk = int(d.get("index_topk") or 0)
     return dict(
         heads=tuple((full if k == FULL else swa).heads for k in kinds),
         dense_layers=int(d["first_k_dense_replace"]),
         shared_width=int(d["moe_intermediate_size"])
         * int(d["n_shared_experts"]),
-        share=_share(d, "n_routed_experts"),
+        share=_share(d, "n_routed_experts", n_group),
         norm_weights=bool(d["norm_topk_prob"]),
         routed_scale=float(d["routed_scaling_factor"]),
-        window=int(d["sliding_window_size"]),
-        index_heads=int(d["index_n_heads"]),
-        index_dim=int(d["index_head_dim"]),
-        index_topk=int(d["index_topk"]), full=full, swa=swa)
+        window=int(d["sliding_window_size"]) if swa else 0,
+        index_heads=int(d["index_n_heads"]) if index_topk else 0,
+        index_dim=int(d["index_head_dim"]) if index_topk else 0,
+        index_topk=index_topk, n_group=n_group, topk_group=topk_group,
+        select_bias=d.get("topk_method") == "noaux_tc", full=full, swa=swa)
 
 
 def _grouped_keys(d: Mapping, kinds) -> dict:
@@ -267,37 +339,59 @@ _scale_init = nn.initializers.ones
 
 # ---- the arithmetic (pure functions of the arrays) -------------------------------
 
-def _queries(p, a: AttnSizes, spec: DecoderSpec, xn):
+def _latent_norm(c, w, rank: int, a: AttnSizes, spec: DecoderSpec, faults):
+    """RMSNorm of a latent; where the source states the rank rescale, its
+    scales times sqrt(hidden / rank)."""
+    w = w.astype(jnp.float32)
+    if a.rescale or "rank_rescale_kept" in faults:
+        w = w * math.sqrt(spec.hidden / rank)
+    return att.rms_norm(c, w, spec.eps)
+
+
+def _rotary(a: AttnSizes, x, pos, faults=frozenset(), dims=None):
+    """The kind's rotary positions over the first `dims` of x's last axis
+    (all of it: None): plain theta, or the source's table."""
+    dims = dims or x.shape[-1]
+    if a.inv_freq is None or "yarn_dropped" in faults:
+        return att.rotate_leading(x, pos, a.theta, dims)
+    return att.rotate_leading_by(x, pos, jnp.asarray(a.inv_freq, jnp.float32),
+                                 a.rope_scale, dims)
+
+
+def _score_scale(a: AttnSizes, faults) -> float:
+    if "score_scale_plain" in faults:
+        return 1.0 / math.sqrt(a.nope + a.rope)
+    return a.scale
+
+
+def _queries(p, a: AttnSizes, spec: DecoderSpec, xn, faults=frozenset()):
     """xn (..., hidden) -> (c_q (..., q_rank), q (..., heads, nope + rope),
     the rope part not rotated yet)."""
-    c_q = att.rms_norm(
-        jnp.dot(xn, p["w_dq"]),
-        p["q_norm"].astype(jnp.float32) * math.sqrt(spec.hidden / a.q_rank),
-        spec.eps)
+    c_q = _latent_norm(jnp.dot(xn, p["w_dq"]), p["q_norm"], a.q_rank, a,
+                       spec, faults)
     q = jnp.dot(c_q, p["w_uq"])
     return c_q, q.reshape(q.shape[:-1] + (a.heads, a.nope + a.rope))
 
 
-def _latents(p, a: AttnSizes, spec: DecoderSpec, xn, pos):
+def _latents(p, a: AttnSizes, spec: DecoderSpec, xn, pos,
+             faults=frozenset()):
     """xn (T, hidden) at positions pos (T,) -> (c_kv (T, kv_rank), k_r (T,
     rope) rotated): what a token leaves in the cache."""
     ckr = jnp.dot(xn, p["w_dkv"])
-    c_kv = att.rms_norm(
-        ckr[..., :a.kv_rank],
-        p["kv_norm"].astype(jnp.float32) * math.sqrt(spec.hidden / a.kv_rank),
-        spec.eps)
-    return c_kv, att.rotate(ckr[..., a.kv_rank:], pos, a.theta)
+    c_kv = _latent_norm(ckr[..., :a.kv_rank], p["kv_norm"], a.kv_rank, a,
+                        spec, faults)
+    return c_kv, _rotary(a, ckr[..., a.kv_rank:], pos, faults)
 
 
 def _index_parts(pi, spec: DecoderSpec, xn, c_q, pos):
     """(qi (T, heads, dim), ki (T, dim), w (T, heads) float32)."""
-    rope, theta = spec.full.rope, spec.full.theta
+    a = spec.full
     qi = jnp.dot(c_q, pi["w_q"])
     qi = qi.reshape(qi.shape[:-1] + (spec.index_heads, spec.index_dim))
-    qi = att.rotate_leading(qi, pos, theta, rope)
+    qi = _rotary(a, qi, pos, dims=a.rope)
     ki = att.layer_norm(jnp.dot(xn, pi["w_k"]), pi["k_norm_scale"],
                         pi["k_norm_bias"], spec.eps)
-    ki = att.rotate_leading(ki, pos, theta, rope)
+    ki = _rotary(a, ki, pos, dims=a.rope)
     w = jnp.dot(xn, pi["w_w"], preferred_element_type=jnp.float32)
     return qi, ki, w
 
@@ -308,6 +402,18 @@ def _gated_output(p, xn, o):
     gate = jax.nn.sigmoid(jnp.dot(xn, p["w_g"],
                                   preferred_element_type=jnp.float32))
     o = o * gate[..., None].astype(o.dtype)
+    return jnp.dot(o.reshape(o.shape[:-2] + (-1,)), p["w_o"])
+
+
+def _latent_output(p, a: AttnSizes, xn, o, faults):
+    """o (..., heads, v) -> (..., hidden): W_o, behind the head-wise gate
+    where the source has one."""
+    if a.gate:
+        return _gated_output(p, xn, o)
+    if "gate_kept" in faults:
+        # a program that gates where the source has no gate: there is no
+        # W_g to read, so the first `heads` columns of W_dq stand in
+        return _gated_output(dict(p, w_g=p["w_dq"][:, :a.heads]), xn, o)
     return jnp.dot(o.reshape(o.shape[:-2] + (-1,)), p["w_o"])
 
 
@@ -324,31 +430,33 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
     total = x.shape[0]
     pos = jnp.arange(total, dtype=jnp.int32)
     xn = att.rms_norm(x, p["attn_norm"], spec.eps)
-    c_q, q = _queries(p, a, spec, xn)
-    q_nope, q_rope = q[..., :a.nope], att.rotate(q[..., a.nope:], pos,
-                                                 a.theta)
-    c_kv, k_r = _latents(p, a, spec, xn, pos)
+    c_q, q = _queries(p, a, spec, xn, faults)
+    q_nope, q_rope = q[..., :a.nope], _rotary(a, q[..., a.nope:], pos, faults)
+    c_kv, k_r = _latents(p, a, spec, xn, pos, faults)
     kv = jnp.dot(c_kv, p["w_ukv"]).reshape(total, a.heads, a.nope + a.v)
     heads_first = lambda t: jnp.transpose(t, (1, 0, 2))  # noqa: E731
     kept = jnp.zeros((), jnp.int32)
-    chosen, window, entry = None, None, {}
+    chosen, window = None, None
     if kind == FULL:
-        with jax.named_scope("indexer"):
-            qi, ki, w = _index_parts(p["indexer"], spec, xn, c_q, pos)
-            if "no_indexer" not in faults:
-                chosen = att.select_blocks(qi, ki, w, spec.index_topk,
-                                           spec.q_block, spec.head_block,
-                                           length=length)
-                real = pos < length
-                for i, block in enumerate(chosen):
-                    r0 = i * spec.q_block
-                    kept += jnp.sum(block & real[r0:r0 + block.shape[0], None],
-                                    dtype=jnp.int32)
-            else:
-                kept = length * (length + 1) // 2
         pad = ((0, slots - total), (0, 0))
-        entry = {"c_kv": jnp.pad(c_kv, pad), "k_r": jnp.pad(k_r, pad),
-                 "k_i": jnp.pad(ki, pad)}
+        entry = {"c_kv": jnp.pad(c_kv, pad), "k_r": jnp.pad(k_r, pad)}
+        # without an indexer every causal key is kept
+        kept = length * (length + 1) // 2
+        if spec.index_topk:
+            with jax.named_scope("indexer"):
+                qi, ki, w = _index_parts(p["indexer"], spec, xn, c_q, pos)
+                if "no_indexer" not in faults:
+                    chosen = att.select_blocks(qi, ki, w, spec.index_topk,
+                                               spec.q_block, spec.head_block,
+                                               length=length)
+                    real = pos < length
+                    kept = jnp.zeros((), jnp.int32)
+                    for i, block in enumerate(chosen):
+                        r0 = i * spec.q_block
+                        kept += jnp.sum(
+                            block & real[r0:r0 + block.shape[0], None],
+                            dtype=jnp.int32)
+            entry["k_i"] = jnp.pad(ki, pad)
     else:
         window = spec.window + ("window_off_by_one" in faults)
         held = att.ring_positions(length[None] - 1, spec.window)[0]
@@ -357,7 +465,7 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
         entry = {"c_kv": take(c_kv), "k_r": take(k_r)}
     how = dict(q_block=spec.q_block, window=window, chosen=chosen,
                length=length, head_block=spec.head_block,
-               scale=1.0 / math.sqrt(a.nope + a.rope))
+               scale=_score_scale(a, faults))
     if att.runs_fused(3, total, spec.q_block, window):
         # the rotary part apart: its keys are ONE array for every head, which
         # the kernel fetches once a tile and the XLA path copies a head
@@ -373,7 +481,7 @@ def attention_prefill_row(p, kind: str, spec: DecoderSpec, x, length,
             heads_first(k), heads_first(kv[..., a.nope:]), **how)
     ran = sum(jnp.asarray(live, jnp.int32)
               for live in att.q_blocks_live(total, spec.q_block, length))
-    return _gated_output(p, xn, heads_first(o)), entry, kept, ran
+    return _latent_output(p, a, xn, heads_first(o), faults), entry, kept, ran
 
 
 def attention_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
@@ -384,38 +492,49 @@ def attention_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
     a = spec.attn(kind)
     rows = jnp.arange(x.shape[0])
     xn = att.rms_norm(x, p["attn_norm"], spec.eps)
-    c_q, q = _queries(p, a, spec, xn)
-    q_rope = att.rotate(q[..., a.nope:], pos, a.theta)
-    c_kv, k_r = _latents(p, a, spec, xn, pos)
+    c_q, q = _queries(p, a, spec, xn, faults)
+    q_rope = _rotary(a, q[..., a.nope:], pos, faults)
+    c_kv, k_r = _latents(p, a, spec, xn, pos, faults)
     w_ukv = p["w_ukv"].reshape(a.kv_rank, a.heads, a.nope + a.v)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :a.nope], w_ukv[..., :a.nope])
+    with jax.named_scope("absorb_q"):
+        q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :a.nope],
+                           w_ukv[..., :a.nope])
     kept = jnp.zeros(x.shape[:1], jnp.int32)
     if kind == FULL:
-        qi, ki, w = _index_parts(p["indexer"], spec, xn, c_q, pos)
-        entry = {"c_kv": entry["c_kv"].at[rows, pos].set(c_kv),
-                 "k_r": entry["k_r"].at[rows, pos].set(k_r),
-                 "k_i": entry["k_i"].at[rows, pos].set(ki)}
         slots = entry["c_kv"].shape[1]
+        slot = pos
+        if "stale_cache_row" in faults:  # every 7th position's write is lost
+            slot = jnp.where(pos % 7 == 3, jnp.minimum(pos + 1, slots - 1),
+                             pos)
+        with jax.named_scope("cache_write"):
+            new = {"c_kv": entry["c_kv"].at[rows, slot].set(c_kv),
+                   "k_r": entry["k_r"].at[rows, slot].set(k_r)}
         allowed = jnp.arange(slots, dtype=jnp.int32)[None, :] <= pos[:, None]
-        if "no_indexer" not in faults:
-            with jax.named_scope("indexer"):
-                scores = att.index_scores(qi[:, None], entry["k_i"],
-                                          w[:, None])[:, 0]
-                allowed &= att.top_k_mask(
-                    jnp.where(allowed, scores, -jnp.inf), spec.index_topk)
+        if spec.index_topk:
+            qi, ki, w = _index_parts(p["indexer"], spec, xn, c_q, pos)
+            with jax.named_scope("cache_write"):
+                new["k_i"] = entry["k_i"].at[rows, pos].set(ki)
+            if "no_indexer" not in faults:
+                with jax.named_scope("indexer"):
+                    scores = att.index_scores(qi[:, None], new["k_i"],
+                                              w[:, None])[:, 0]
+                    allowed &= att.top_k_mask(
+                        jnp.where(allowed, scores, -jnp.inf), spec.index_topk)
         kept = jnp.sum(allowed, axis=-1, dtype=jnp.int32)
     else:
         slot = jnp.mod(pos, spec.window)
         if "stale_ring_row" in faults:  # the write of every 7th slot is lost
             slot = jnp.where(slot % 7 == 3, (slot + 1) % spec.window, slot)
-        entry = {"c_kv": entry["c_kv"].at[rows, slot].set(c_kv),
-                 "k_r": entry["k_r"].at[rows, slot].set(k_r)}
+        with jax.named_scope("cache_write"):
+            new = {"c_kv": entry["c_kv"].at[rows, slot].set(c_kv),
+                   "k_r": entry["k_r"].at[rows, slot].set(k_r)}
         allowed = att.ring_positions(pos, spec.window) >= 0
     o_lat = att.latent_cache_attention(
-        q_lat, q_rope, entry["c_kv"], entry["k_r"], allowed,
-        1.0 / math.sqrt(a.nope + a.rope))
-    o = jnp.einsum("bhr,rhd->bhd", o_lat, w_ukv[..., a.nope:])
-    return _gated_output(p, xn, o), entry, kept
+        q_lat, q_rope, new["c_kv"], new["k_r"], allowed,
+        _score_scale(a, faults))
+    with jax.named_scope("absorb_o"):
+        o = jnp.einsum("bhr,rhd->bhd", o_lat, w_ukv[..., a.nope:])
+    return _latent_output(p, a, xn, o, faults), new, kept
 
 
 def _latent_prefill_row(p, kind, spec, x, length, slots, faults=frozenset()):
@@ -431,8 +550,15 @@ def _latent_prefill_row(p, kind, spec, x, length, slots, faults=frozenset()):
 
 def _latent_step(p, kind, spec, x, pos, entry, faults=frozenset()):
     out, entry, kept = attention_step(p, kind, spec, x, pos, entry, faults)
-    return out, entry, {"keys_kept": kept,
-                        "keys_causal": (pos + 1) * (kind == FULL)}
+    counts = {"keys_kept": kept, "keys_causal": (pos + 1) * (kind == FULL)}
+    if kind == FULL and not spec.index_topk:
+        # a full layer without an indexer reads its whole cache and may
+        # attend the causal keys: the cache's live share is theirs over the
+        # slots (behind an indexer the keys read are `keys_kept`'s business)
+        counts.update(
+            slots_full=jnp.full(pos.shape, entry["c_kv"].shape[1], jnp.int32),
+            keys_full=pos + 1)
+    return out, entry, counts
 
 
 # ---- the grouped-query attention -------------------------------------------------
@@ -556,21 +682,37 @@ def grouped_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
         "keys_" + tag: keys, "keys_causal": keys * (kind == FULL)}
 
 
-def expert_layer(p, spec: DecoderSpec, hn, token_real, faults=frozenset()):
+def expert_layer_groups(p, spec: DecoderSpec, hn, token_real,
+                        faults=frozenset()):
     """hn (T, hidden) normed -> (y (T, hidden): the held experts' part plus
-    the shared expert, local (T, k): each pair's held expert or `held`)."""
+    the shared expert, local (T, k): each pair's held expert or `held`, hit
+    (T,) bool: under a router with groups, the real tokens for which a group
+    this share holds was among the groups kept; None without groups)."""
     with jax.named_scope("router"):
-        bias = p["b_select"] * (0.0 if "no_select_bias" in faults else 1.0)
+        bias = p.get("b_select")
+        if bias is not None and "no_select_bias" in faults:
+            bias = bias * 0.0
         scale = 1.0 if "no_routed_scale" in faults else spec.routed_scale
-        idx, weights = moe.route(hn, p["w_router"], bias, spec.per_token,
-                                 spec.norm_weights, scale)
+        groups = 0 if "no_group_limit" in faults else spec.n_group
+        idx, weights, kept = moe.route_groups(
+            hn, p["w_router"], bias, spec.per_token, spec.norm_weights, scale,
+            groups, spec.topk_group)
     with jax.named_scope("experts"):
         y, local = moe.routed_experts(hn, idx, weights, token_real,
                                       p["w_gate_up"], p["w_down"], spec.share)
     if spec.shared_width and "no_shared" not in faults:
         with jax.named_scope("shared_expert"):
             y = y + moe.swiglu(hn, p["shared_gate_up"], p["shared_down"])
-    return y, local
+    hit = None
+    if kept is not None:
+        mine = spec.share.groups()
+        hit = jnp.any(kept[:, mine.start:mine.stop], axis=-1) & token_real
+    return y, local, hit
+
+
+def expert_layer(p, spec: DecoderSpec, hn, token_real, faults=frozenset()):
+    """`expert_layer_groups` without the group hits: (y, local)."""
+    return expert_layer_groups(p, spec, hn, token_real, faults)[:2]
 
 
 def _pairs_by_expert(local, real, held: int):
@@ -611,8 +753,9 @@ class Indexer(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Both latent attentions: the layer's kind picks the size set, the
-    window or the indexer. `__call__` returns the arrays; the arithmetic is
+    """The latent attentions: the layer's kind picks the size set and the
+    window; the spec (what the source's mapping held) whether there is an
+    indexer or a gate. `__call__` returns the arrays; the arithmetic is
     `attention_prefill_row` / `attention_step`."""
     spec: DecoderSpec
     index: int
@@ -635,9 +778,10 @@ class LatentAttention(nn.Module):
             "kv_norm": self.param("kv_norm", _scale_init, (a.kv_rank,), d),
             "w_ukv": self.param("w_ukv", _INIT,
                                 (a.kv_rank, a.heads * (a.nope + a.v)), d),
-            "w_g": self.param("w_g", _INIT, (s.hidden, a.heads), d),
             "w_o": self.param("w_o", _INIT, (a.heads * a.v, s.hidden), d)}
-        if self.kind == FULL:
+        if a.gate:
+            p["w_g"] = self.param("w_g", _INIT, (s.hidden, a.heads), d)
+        if self.kind == FULL and s.index_topk:
             p["indexer"] = Indexer(s, d, name="indexer")()
         return p
 
@@ -697,11 +841,9 @@ class ExpertLayer(nn.Module):
     def __call__(self):
         s, d = self.spec, self.dtype
         f, fs, held = s.expert_width, s.shared_width, s.share.held
-        return {
+        p = {
             "w_router": self.param("w_router", _INIT,
                                    (s.hidden, s.share.n_routed), d),
-            "b_select": self.param("b_select", nn.initializers.zeros,
-                                   (s.share.n_routed,), jnp.float32),
             "w_gate_up": self.param("w_gate_up", _INIT,
                                     (held, s.hidden, 2 * f), d),
             "w_down": self.param("w_down", _INIT, (held, f, s.hidden), d),
@@ -709,6 +851,10 @@ class ExpertLayer(nn.Module):
                                          (s.hidden, 2 * fs), d),
             "shared_down": self.param("shared_down", _INIT,
                                       (fs, s.hidden), d)}
+        if s.select_bias:
+            p["b_select"] = self.param("b_select", nn.initializers.zeros,
+                                       (s.share.n_routed,), jnp.float32)
+        return p
 
 
 class DecoderLayer(nn.Module):
@@ -744,7 +890,8 @@ def _attn_scope(kind: str) -> str:
 
 
 def _feed_forward(p, spec: DecoderSpec, dense: bool, x, real, faults):
-    """x (B, T, hidden) after attention -> (x out, local (B, T, k) or None)."""
+    """x (B, T, hidden) after attention -> (x out, local (B, T, k) or None,
+    group hits (B, T) bool or None)."""
     hn = att.rms_norm(x, p["ffn_norm"], spec.eps)
     if dense:
         ffn = lambda h: moe.swiglu(h, p["ffn"]["w_gate_up"],  # noqa: E731
@@ -753,10 +900,12 @@ def _feed_forward(p, spec: DecoderSpec, dense: bool, x, real, faults):
             # prefill a row at a time: the gate and up projections of a
             # whole batch of prompts would stand as gigabytes
             y = lax.map(ffn, hn) if x.shape[1] > 1 else ffn(hn)
-        return x + y, None
+        return x + y, None, None
     flat = hn.reshape(-1, spec.hidden)
-    y, local = expert_layer(p["moe"], spec, flat, real.reshape(-1), faults)
-    return x + y.reshape(x.shape), local.reshape(x.shape[:2] + (-1,))
+    y, local, hit = expert_layer_groups(p["moe"], spec, flat,
+                                        real.reshape(-1), faults)
+    return (x + y.reshape(x.shape), local.reshape(x.shape[:2] + (-1,)),
+            None if hit is None else hit.reshape(x.shape[:2]))
 
 
 class MoEDecoder(nn.Module):
@@ -790,7 +939,7 @@ class MoEDecoder(nn.Module):
         attention's counts)`. Returns (x, the cache entries, `counts` with
         this pass added)."""
         s = self.spec
-        entries, pairs, visits = [], [], 0
+        entries, pairs, visits, hits, slots = [], [], 0, 0, 0
         tally = {name: counts[name] for name in ATTENTION_COUNTS}
         for i, block in enumerate(self.layer):
             p, kind = block(), s.kinds[i]
@@ -801,15 +950,20 @@ class MoEDecoder(nn.Module):
             for name, value in got.items():
                 tally[name] = tally[name] + value
             wide = x if x.ndim == 3 else x[:, None]
-            wide, local = _feed_forward(p, s, i < s.dense_layers, wide, real,
-                                        self.faults)
+            wide, local, hit = _feed_forward(p, s, i < s.dense_layers, wide,
+                                             real, self.faults)
             x = wide if x.ndim == 3 else wide[:, 0]
             if local is not None:
                 pairs.append(_pairs_by_expert(local, real, s.share.held))
                 visits = visits + _visits_by_row(pairs[-1])
+            if hit is not None:
+                hits = hits + jnp.sum(hit, axis=1, dtype=jnp.int32)
+                slots = slots + jnp.sum(real, axis=1, dtype=jnp.int32)
         tally["expert_tokens"] = counts["expert_tokens"] + (
             jnp.stack(pairs, axis=1) if pairs else 0)
         tally["expert_visits"] = counts["expert_visits"] + visits
+        tally["group_hits"] = counts["group_hits"] + hits
+        tally["group_slots"] = counts["group_slots"] + slots
         tally["q_blocks_total"] = counts["q_blocks_total"]
         return x, tuple(entries), tally
 
@@ -817,7 +971,8 @@ class MoEDecoder(nn.Module):
         s = self.spec
         zeros = jnp.zeros((rows,), jnp.int32)
         return dict({name: zeros for name in ATTENTION_COUNTS
-                     + ("expert_visits", "q_blocks_total")},
+                     + ("expert_visits", "q_blocks_total", "group_hits",
+                        "group_slots")},
                     expert_tokens=jnp.zeros(
                         (rows, s.expert_layers, s.share.held), jnp.int32))
 

@@ -21,7 +21,9 @@ appended where the scope path runs through `transpose(`: the backward
 pass. The decoder families': `prefill/<scope>` and `decode/<scope>` for the
 scopes `embed`, `attn_full`, `indexer`, `attn_window`, `router`, `experts`,
 `shared_expert`, `dense_ffn`, `lm_head`, and under an attention layer its
-parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`). An operation the program named but outside those (the step counter's
+parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`), the latent
+attention's `absorb_q`, `cache_write`, `absorb_o`, and under `router` the
+`group_limit`. An operation the program named but outside those (the step counter's
 `jit(step)/add`, the network's own input cast) is `other`. An instruction
 XLA made itself carries no metadata (`copy`, `bitcast`, `copy-start/done`
 from layout assignment and memory-space assignment): it takes the layer
@@ -56,8 +58,9 @@ _DECODER_SCOPES = ("embed", "attn_full", "indexer", "attn_window", "router",
                    "experts", "shared_expert", "dense_ffn", "lm_head")
 _DECODER_PHASES = ("prefill", "decode")
 # parts of an attention layer, named under the layer that holds them
-# (`decode/attn_full/kv_write`)
-_DECODER_PARTS = ("rope", "kv_write", "gate")
+# (`decode/attn_full/kv_write`), or of the router (`decode/router/group_limit`)
+_DECODER_PARTS = ("rope", "kv_write", "gate", "absorb_q", "cache_write",
+                  "absorb_o", "group_limit")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")

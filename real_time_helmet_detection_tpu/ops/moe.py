@@ -1,7 +1,8 @@
 """The expert layer of the decoder family: sigmoid router with a selection
-bias, the choice, the (token, choice) pairs sorted by expert, the pairs whose
-expert is not held here dropped, a grouped matmul over the experts held, the
-weighted way back to the tokens.
+bias (or none), the choice (over all the experts, or limited to the best few
+of the router's groups), the (token, choice) pairs sorted by expert, the
+pairs whose expert is not held here dropped, a grouped matmul over the
+experts held, the weighted way back to the tokens.
 
 The reference has no experts (ref hourglass.py is convolutions only); this
 module is new capability. No token is dropped for capacity: the sorted pairs
@@ -35,18 +36,49 @@ def kernel_compiles() -> bool:
     return select.on_chip()
 
 
-def route(hn, w_router, b_select, per_token: int, norm_weights: bool,
-          routed_scale: float):
+def kept_groups(choice, n_group: int, topk_group: int):
+    """choice (T, experts) float32, the scores the choice is made on ->
+    bool (T, n_group): the `topk_group` groups (consecutive experts,
+    experts / n_group each) whose two best scores sum highest, ties to the
+    lower group."""
+    tokens, experts = choice.shape
+    best_two, _ = lax.top_k(choice.reshape(tokens, n_group,
+                                           experts // n_group), 2)
+    _, kept = lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    return jnp.zeros((tokens, n_group), bool).at[
+        jnp.arange(tokens)[:, None], kept].set(True)
+
+
+def route_groups(hn, w_router, b_select, per_token: int, norm_weights: bool,
+                 routed_scale: float, n_group: int = 0, topk_group: int = 0):
     """hn (T, hidden) -> (chosen expert ids (T, per_token) int32, their
-    weights (T, per_token) float32). Scores in float32; the bias enters the
-    choice only."""
+    weights (T, per_token) float32, the groups kept (T, n_group) bool or
+    None). Scores in float32; the bias (None: a choice on the scores
+    themselves) enters the choice only. With `n_group` the choice is limited
+    to the experts of the `topk_group` groups `kept_groups` keeps."""
     scores = jax.nn.sigmoid(jnp.dot(hn, w_router,
                                     preferred_element_type=jnp.float32))
-    _, idx = lax.top_k(scores + b_select.astype(jnp.float32), per_token)
+    choice = scores if b_select is None else (
+        scores + b_select.astype(jnp.float32))
+    kept = None
+    if n_group:
+        with jax.named_scope("group_limit"):
+            kept = kept_groups(choice, n_group, topk_group)
+            choice = jnp.where(
+                jnp.repeat(kept, scores.shape[-1] // n_group, axis=-1),
+                choice, -jnp.inf)
+    _, idx = lax.top_k(choice, per_token)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_weights:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weights * routed_scale
+    return idx.astype(jnp.int32), weights * routed_scale, kept
+
+
+def route(hn, w_router, b_select, per_token: int, norm_weights: bool,
+          routed_scale: float, n_group: int = 0, topk_group: int = 0):
+    """`route_groups` without the groups kept: (ids, weights)."""
+    return route_groups(hn, w_router, b_select, per_token, norm_weights,
+                        routed_scale, n_group, topk_group)[:2]
 
 
 def swiglu(x, w_gate_up, w_down):

@@ -16,10 +16,13 @@ from typing import NamedTuple
 
 class ExpertShare(NamedTuple):
     """`n_routed` experts in the whole layer, divided evenly and in order
-    over `ep_size` chips; this is share `ep_rank`."""
+    over `ep_size` chips; this is share `ep_rank`. `n_group`: the routing
+    groups the router limits a token's choice by (consecutive experts,
+    `n_routed / n_group` each; 0: the router knows no groups)."""
     ep_size: int
     ep_rank: int
     n_routed: int
+    n_group: int = 0
 
     @property
     def held(self) -> int:
@@ -32,11 +35,33 @@ class ExpertShare(NamedTuple):
     def ids(self) -> range:
         return range(self.first, self.first + self.held)
 
+    def groups(self) -> range:
+        """The routing groups this share holds experts of (whole groups, or
+        a part of one): `expert_share` lets nothing else be stated."""
+        if not self.n_group:
+            return range(0)
+        size = self.n_routed // self.n_group
+        return range(self.first // size, (self.first + self.held - 1) // size
+                     + 1)
 
-def expert_share(ep_size: int, ep_rank: int, n_routed: int) -> ExpertShare:
+
+def expert_share(ep_size: int, ep_rank: int, n_routed: int,
+                 n_group: int = 0) -> ExpertShare:
     if ep_size < 1 or n_routed % ep_size:
         raise ValueError("ep_size %d must divide the %d routed experts"
                          % (ep_size, n_routed))
     if not 0 <= ep_rank < ep_size:
         raise ValueError("ep_rank %d is not a share of %d" % (ep_rank, ep_size))
-    return ExpertShare(int(ep_size), int(ep_rank), int(n_routed))
+    if n_group:
+        # a share is whole groups or a whole fraction of one, so that "a
+        # token's experts lie on the holders of at most `topk_group` groups"
+        # is a statement about chips
+        held = n_routed // ep_size
+        if n_routed % n_group or (held % (n_routed // n_group)
+                                  and (n_routed // n_group) % held):
+            raise ValueError(
+                "a share of %d experts (%d over %d chips) is neither whole "
+                "routing groups nor a whole fraction of one (%d groups)"
+                % (held, n_routed, ep_size, n_group))
+    return ExpertShare(int(ep_size), int(ep_rank), int(n_routed),
+                       int(n_group))

@@ -241,8 +241,13 @@ class Generation(NamedTuple):
     (rows add up to the batch's count: what part of the experts' weights the
     batch streamed); `cache_slots_read` / `cache_keys_real`: cache slots the
     row's decode steps read and the real keys among them, by layer kind
-    (full, sliding). A count the family's attention does not have is zeros
-    (the latent family's caches, the grouped-query family's indexer)."""
+    (full, sliding); `group_hits` / `group_slots`: under a router whose
+    choice is limited by groups, the (position, expert layer) slots where a
+    group this share holds was among the groups kept / all such slots. A
+    count the program does not have is zeros (the grouped-query family's
+    indexer, a router without groups; a latent family's caches behind an
+    indexer or a window: only its full layers WITHOUT an indexer, which read
+    every slot and may attend every causal key, count slots and keys)."""
     tokens: jax.Array          # int32 (B, N)
     logits_first: jax.Array    # float32 (B, V): at the prompt's last token
     logits_last: jax.Array     # float32 (B, V): the step that gave token N
@@ -256,6 +261,8 @@ class Generation(NamedTuple):
     expert_visits: jax.Array     # int32 (B,)
     cache_slots_read: jax.Array  # int32 (B, 2): full, sliding
     cache_keys_real: jax.Array   # int32 (B, 2)
+    group_hits: jax.Array        # int32 (B,)
+    group_slots: jax.Array       # int32 (B,)
 
 
 def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
@@ -304,7 +311,9 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             cache_slots_read=jnp.stack([counts["slots_full"],
                                         counts["slots_window"]], axis=1),
             cache_keys_real=jnp.stack([counts["keys_full"],
-                                       counts["keys_window"]], axis=1))
+                                       counts["keys_window"]], axis=1),
+            group_hits=counts["group_hits"],
+            group_slots=counts["group_slots"])
 
     return jax.jit(generate)
 
@@ -329,7 +338,9 @@ def generation_counters(p_max: int) -> Callable:
                # by the prefill and once by each step after the first token
                "gen.expert_passes": int(rows.expert_tokens.shape[1]
                                         * rows.tokens.shape[1]),
-               "gen.expert_visits": int(np.sum(rows.expert_visits))}
+               "gen.expert_visits": int(np.sum(rows.expert_visits)),
+               "gen.group_hits": int(np.sum(rows.group_hits)),
+               "gen.group_slots": int(np.sum(rows.group_slots))}
         for j, kind in enumerate(("full", "window")):
             out["gen.cache_slots." + kind] = int(np.sum(
                 rows.cache_slots_read[:, j], dtype=np.int64))

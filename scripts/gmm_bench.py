@@ -6,6 +6,8 @@ readings behind the choice in ops/moe.py).
     python scripts/gmm_bench.py [--out chiprun_out/pr29/gmm_bench.json]
     python scripts/gmm_bench.py --config benchmark/configs/laguna-xs2-l5.json \
         --prefill 65536,65536,256 --decode 256,256,162
+    python scripts/gmm_bench.py --config benchmark/configs/axk1-ep8-l5.json \
+        --prefill 32768,16384,24 --decode 256,32,18
 
 The expert width and count come from a benchmark configuration file (its
 `fields`: `hidden_size`, `moe_intermediate_size`, and the experts held,
@@ -15,7 +17,10 @@ rows, how many of them belong to held experts, over how many experts
 the 32 held experts, hidden 5120 -> 3072 and 1536 -> 5120; decode 32 rows of
 which 4 belong to 4 experts. Laguna's (above): a pass of 65,536 rows over
 all 256 experts, 2048 -> 1024 and 512 -> 2048; a decode step's 256 rows over
-about 162. Only on the chip (the reference has no such tool: ref
+about 162. A.X-K1's share (above): a pass of 32,768 rows of which 16,384
+belong to the 24 held experts, 7168 -> 4096 and 2048 -> 7168 (88 MB an
+expert); a decode step's pass of 256 rows of which 32 belong to about 18.
+Only on the chip (the reference has no such tool: ref
 train.py:92-140 keeps per-segment meters only); a time from the CPU would
 say nothing.
 """
