@@ -1,8 +1,11 @@
 """Heatmap -> boxes decoding, fully jit-able with static shapes.
 
 Capability parity with the reference decoder (/root/reference/transform.py:73-110
-`hm2box`): 3x3 max-pool peak test, flat top-k over (C, H, W), offset/size
-gather, un-normalization, box reconstruction, confidence thresholding.
+`hm2box`): 3x3 max-pool peak test, top-k over the flat (C, H, W) scores,
+offset/size gather, un-normalization, box reconstruction, confidence
+thresholding. The top-k is the reference's flat one in values, indices and
+tie order, selected in two levels (`top_k_exact`) so that the device never
+sorts the whole map.
 
 TPU-first differences:
   * channels-last `(H, W, C)` inputs;
@@ -104,6 +107,46 @@ def peak_mask(heatmap: jax.Array, pool_size: int = 3) -> jax.Array:
     return pooled == heatmap
 
 
+def chunk_length(n: int, k: int) -> int:
+    """Chunk length of `top_k_exact`'s two levels, from the shape alone.
+
+    The levels sort `n / L + k * L` keys, least near `L = sqrt(n / k)`: the
+    largest power of two not above it that divides `n`. 0 (the direct call)
+    where that is under 4: the two levels would sort more than half of `n`.
+    """
+    if n < 16 * k:
+        return 0
+    chunk = 1 << ((n // k).bit_length() - 1) // 2
+    while n % chunk:
+        chunk //= 2
+    return chunk if chunk >= 4 else 0
+
+
+def top_k_exact(flat: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """`jax.lax.top_k(flat, k)` of a 1-D array, values and indices, from two
+    small selections instead of one sort of every key (which is what
+    `lax.top_k` is on the TPU).
+
+    The `k` chunks of `chunk_length` contiguous scores with the largest
+    maxima are picked, put back in index order, and the top-k taken among
+    their `k * L` scores. Exact, ties included: `top_k` orders by (value
+    descending, index ascending); were a top-k element's chunk not picked,
+    the `k` chunks before it by (maximum descending, chunk ascending) would
+    each hold an element at or above it with a lower index where equal, `k`
+    elements before it. Candidates stand in index order, so the second
+    stable `top_k` breaks ties as the first would. Shapes too small for two
+    levels (`chunk_length` 0: the toy maps) take the direct call.
+    """
+    n = flat.shape[0]
+    chunk = chunk_length(n, k)
+    if not chunk:
+        return jax.lax.top_k(flat, k)
+    chunks = flat.reshape(n // chunk, chunk)
+    picked = jnp.sort(jax.lax.top_k(chunks.max(axis=1), k)[1])
+    scores, at = jax.lax.top_k(chunks[picked].reshape(-1), k)
+    return scores, picked[at // chunk] * chunk + at % chunk
+
+
 @partial(jax.jit, static_argnames=("scale_factor", "topk", "normalized"))
 def decode_peak_scores(peaks: jax.Array, offset: jax.Array, wh: jax.Array,
                        scale_factor: int = 4, topk: int = 100,
@@ -112,15 +155,20 @@ def decode_peak_scores(peaks: jax.Array, offset: jax.Array, wh: jax.Array,
 
     `peaks` is the (H, W, C) map where non-peak cells are already zeroed
     (e.g. the output of the fused Pallas kernel `ops.pallas.fused_peak_scores`
-    or the XLA peak test in `decode_heatmap`). Remaining steps: flat top-k,
-    gather, un-normalize, box reconstruction (ref transform.py:81-110).
+    or the XLA peak test in `decode_heatmap`). Remaining steps: top-k of the
+    flat class-major scores, gather, un-normalize, box reconstruction (ref
+    transform.py:81-110). The top-k is `top_k_exact`: the `topk` chunks with
+    the largest maxima, then the top-k among their scores, which is
+    `lax.top_k`'s answer in values, indices and tie order (zeros among
+    fewer than `topk` peaks are ties like any other) without a sort of the
+    whole map; maps under 16 scores a kept one take `lax.top_k` itself.
     """
     height, width, num_cls = peaks.shape
 
     # Flatten class-major (C, H, W) to match the reference's index layout
     # (class = idx // (H*W)), keeping tie-break ordering identical.
     flat = peaks.transpose(2, 0, 1).reshape(-1)
-    scores, indices = jax.lax.top_k(flat, topk)
+    scores, indices = top_k_exact(flat, topk)
 
     clss = indices // (height * width)
     inds = indices % (height * width)

@@ -7,11 +7,15 @@ on-device encoder vs the host encoder, and fixed-shape decode semantics.
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from real_time_helmet_detection_tpu.ops import (
-    encode_boxes, encode_boxes_batch, encode_boxes_jax, decode_heatmap, peak_mask)
+    encode_boxes, encode_boxes_batch, encode_boxes_jax, decode_heatmap,
+    decode_peak_scores, peak_mask)
+from real_time_helmet_detection_tpu.analysis.trace_audit import _walk_jaxprs
+from real_time_helmet_detection_tpu.ops.decode import chunk_length, top_k_exact
 
 
 def test_encode_shapes_channels_last():
@@ -191,3 +195,80 @@ def test_decode_conf_above_all_scores_fixed_shape():
                           conf_th=0.99, normalized=False)
     assert dets.boxes.shape == (10, 4)
     assert not bool(np.asarray(dets.valid).any())
+
+
+# -- the two-level top-k against lax.top_k, values AND indices ---------------
+
+PUBLISHED = 128 * 128 * 2  # one stack's flat map at 512^2: 32,768 scores
+
+
+def _scores(case: str, n: int) -> np.ndarray:
+    rng = np.random.default_rng(sum(map(ord, case)) + n)
+    if case == "distinct":
+        return rng.permutation(n).astype(np.float32) / n
+    if case in ("ties4", "ties8"):
+        return rng.integers(0, int(case[4:]), n).astype(np.float32) / 8
+    flat = np.zeros(n, np.float32)
+    if case == "few_nonzero":  # fewer peaks than k: zeros fill, lowest index first
+        flat[rng.choice(n, 37, replace=False)] = rng.random(37, np.float32)
+    elif case == "max_across_chunks":  # one maximum repeated over a chunk boundary
+        edge = 5 * chunk_length(PUBLISHED, 100)
+        flat[rng.choice(n, 300, replace=False)] = 0.5
+        flat[edge - 3:edge + 3] = 0.9
+    else:
+        assert case == "all_zero"
+    return flat
+
+
+@pytest.mark.parametrize("case,n,k", [
+    ("distinct", PUBLISHED, 100),
+    ("ties4", PUBLISHED, 100),
+    ("ties8", PUBLISHED, 100),
+    ("few_nonzero", PUBLISHED, 100),
+    ("all_zero", PUBLISHED, 100),
+    ("max_across_chunks", PUBLISHED, 100),
+    ("ties8", 64 * 64 * 2, 100),   # a 256^2 image's map: chunks of 8
+    ("ties4", 3 * 4096, 7),        # a chunk count that is no power of two
+    ("ties4", 16 * 16 * 2, 100),   # the suite's toy map: the direct call
+    ("distinct", 16 * 16 * 2, 100),
+])
+def test_top_k_exact_is_lax_top_k(case, n, k):
+    flat = jnp.asarray(_scores(case, n))
+    want_scores, want_at = jax.lax.top_k(flat, k)
+    scores, at = top_k_exact(flat, k)
+    np.testing.assert_array_equal(np.asarray(scores), np.asarray(want_scores))
+    np.testing.assert_array_equal(np.asarray(at), np.asarray(want_at))
+
+
+def test_top_k_exact_under_vmap_vmap_as_predict_calls_it():
+    flat = jnp.asarray(np.stack([
+        _scores(case, PUBLISHED) for case in
+        ("distinct", "ties4", "few_nonzero", "all_zero", "ties8",
+         "max_across_chunks")]).reshape(3, 2, PUBLISHED))
+    want = jax.vmap(jax.vmap(lambda f: jax.lax.top_k(f, 100)))(flat)
+    got = jax.vmap(jax.vmap(lambda f: top_k_exact(f, 100)))(flat)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("n,k,chunk", [
+    (PUBLISHED, 100, 16), (64 * 64 * 2, 100, 8), (32 * 32 * 2, 100, 4),
+    (16 * 16 * 2, 100, 0), (PUBLISHED, 200, 8), (1599, 10, 0), (50, 100, 0)])
+def test_chunk_length_follows_the_shape(n, k, chunk):
+    assert chunk_length(n, k) == chunk
+
+
+def test_decode_holds_no_whole_map_sort():
+    """What keeps a later edit from bringing the 32,768-key sort back unseen
+    on the CPU suite: the published map selects through sorts of at most
+    2,048 keys, the toy map through exactly the one direct call."""
+    def selections(side):
+        maps = [jax.ShapeDtypeStruct((side, side, 2), jnp.float32)] * 3
+        closed = jax.make_jaxpr(
+            lambda p, o, w: decode_peak_scores(p, o, w, topk=100))(*maps)
+        return sorted((e.primitive.name, e.invars[0].aval.shape[-1])
+                      for j in _walk_jaxprs(closed.jaxpr) for e in j.eqns
+                      if e.primitive.name in ("top_k", "sort"))
+
+    assert selections(128) == [("sort", 100), ("top_k", 1600), ("top_k", 2048)]
+    assert selections(16) == [("top_k", 512)]
