@@ -78,8 +78,8 @@ GQA_FAMILY = "gqa_moe_decoder"
 # per-row counts an attention may give (int32 (B,)); the body adds the layers
 # up and a count the family's attention does not give stays zeros
 ATTENTION_COUNTS = ("keys_kept", "keys_causal", "q_blocks_run",
-                    "q_blocks_fused", "slots_full", "keys_full",
-                    "slots_window", "keys_window")
+                    "q_blocks_fused", "attn_fused_visits", "slots_full",
+                    "keys_full", "slots_window", "keys_window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -541,11 +541,15 @@ def _latent_prefill_row(p, kind, spec, x, length, slots, faults=frozenset()):
     out, entry, kept, ran = attention_prefill_row(p, kind, spec, x, length,
                                                   slots, faults)
     causal = length * (length + 1) // 2 * (kind == FULL)
-    # the q blocks the fused kernel ran: all of a layer's or none
+    # the q blocks the fused kernel ran, all of a layer's or none, and the
+    # (q block, key block) visits it did work in for them
     fused = att.runs_fused(3, x.shape[0], spec.q_block,
                            None if kind == FULL else spec.window)
+    visits = (att.fused_visits(x.shape[0], spec.q_block, ran) if fused
+              else jnp.zeros_like(ran))
     return out, entry, {"keys_kept": kept, "keys_causal": causal,
-                        "q_blocks_run": ran, "q_blocks_fused": ran * fused}
+                        "q_blocks_run": ran, "q_blocks_fused": ran * fused,
+                        "attn_fused_visits": visits}
 
 
 def _latent_step(p, kind, spec, x, pos, entry, faults=frozenset()):

@@ -12,7 +12,8 @@ all — its loop prints averaged meters, ref train.py:140-160):
   clock, `snapshot(since=)`), plus the crash-safe JSONL span log when a
   path is configured (loader-wait/h2d/dispatch/step/fetch/checkpoint/
   compile/serve:lower|compile|queue-wait|batch-form|h2d|dispatch|
-  inflight-wait|device-wait|d2h|e2e/...).
+  device-wait|d2h|deliver|e2e/...); `with_ring` tees a tracer handed in
+  from outside into the ring.
 * `obs.hlo_scopes` (stdlib): compiled HLO text -> {instruction: layer};
   `ServingEngine.scope_maps()` and the step runner's `scope_map()` hand
   it their executables' text so a device-only trace reads by layer.

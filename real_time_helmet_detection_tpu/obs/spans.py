@@ -21,7 +21,10 @@ Design rules, each load-bearing:
   a partial list) when the ring has overwritten part of what was asked
   for; `dropped` counts what it overwrote. With no file configured a span
   costs two clock reads and one tuple append: no record dict, no json, no
-  wall-clock read, no lock beyond the deque's own.
+  wall-clock read, no lock beyond the deque's own. A component handed a
+  tracer of someone else's (the benchmark hands the serving engine a
+  narrow one) wraps it in `with_ring`: that tracer sees what it saw, and
+  the ring sees it too, with meta and a start.
 * **Every span has a place on the clock.** `record(name, dur_s)` stamps
   `t0 = now - dur_s` (the caller measured an interval that just ended);
   an explicit `t0=` wins.
@@ -40,7 +43,13 @@ Design rules, each load-bearing:
 Span taxonomy (docs/ARCHITECTURE.md "Observability & flight recorder"):
 `loader-wait`, `h2d`, `dispatch`, `step`, `fetch`, `checkpoint`, `compile`
 (one per jax compile stage, obs/telemetry.py), `calibrate`, `serve:*`
-(serving/engine.py), `bench:*` section spans, `heartbeat` events (the
+(serving/engine.py: `serve:lower` / `serve:compile` a bucket;
+`serve:queue-wait` / `serve:e2e` a request; a batch `serve:batch-form` /
+`serve:h2d` / `serve:dispatch` on the dispatcher thread and
+`serve:device-wait` / `serve:d2h` / `serve:deliver` on the fetcher, the
+last one record a batch, meta `b`, `n` and the batch's `row_counters`
+dict, written before any of its answers resolves; events `serve:shed`,
+`serve:state`, ...), `bench:*` section spans, `heartbeat` events (the
 runtime heartbeat mirrors every beat here when tracing is on),
 `recompile` events and `context` records (host loadavg).
 
@@ -62,6 +71,7 @@ and links go to the file only; the ring keeps times.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import json
 import os
@@ -334,6 +344,46 @@ def default_tracer() -> SpanTracer:
     if _DEFAULT is None:
         _DEFAULT = SpanTracer(None)
     return _DEFAULT
+
+
+class _RingTee:
+    """A tracer that does not write the process ring, and the ring beside
+    it (`with_ring`): every span, record and event goes to `tracer` as it
+    went before and to the ring with its meta and a start."""
+
+    def __init__(self, tracer):
+        self._tracer = tracer
+        self._ring = default_tracer()
+
+    @property
+    def enabled(self) -> bool:
+        return self._tracer.enabled
+
+    @contextlib.contextmanager
+    def span(self, name: str, ctx=None, links=None, **meta):
+        with self._tracer.span(name, ctx=ctx, links=links, **meta) as sp, \
+                self._ring.span(name, **meta):
+            yield sp
+
+    def record(self, name: str, dur_s: float, ctx=None, links=None,
+               t0: Optional[float] = None, **meta) -> None:
+        self._tracer.record(name, dur_s, ctx=ctx, links=links,
+                            **({} if t0 is None else {"t0": t0}), **meta)
+        self._ring.record(name, dur_s, t0=t0, **meta)
+
+    def event(self, name: str, ctx=None, links=None, **meta) -> None:
+        self._tracer.event(name, ctx=ctx, links=links, **meta)
+        self._ring.event(name, **meta)
+
+
+def with_ring(tracer):
+    """`tracer` if it writes the process ring already (a `SpanTracer` over
+    it), else a tee of it and the ring: whatever tracer a component is
+    handed (the benchmark hands the serving engine a narrow one of its
+    own), the ring stays the one flight recorder of the process."""
+    if isinstance(tracer, SpanTracer) and tracer._ring is _RING:
+        return tracer
+    return _RingTee(tracer)
 
 
 def maybe_tracer(path: Optional[str] = None,

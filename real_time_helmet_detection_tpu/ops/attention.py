@@ -231,6 +231,14 @@ def runs_fused(q_ndim: int, total: int, q_block: int,
             and kernel_compiles())
 
 
+def fused_visits(total: int, q_block: int, ran):
+    """The (q block, key block) visits the fused kernel does work in for a
+    sequence of `total` rows whose first `ran` (int32 scalar) q blocks are
+    live: its own visit table (`visits_run`), at the key block it takes."""
+    table = fused.visits_run(total, q_block, fused.key_block(total))
+    return jnp.asarray(table)[ran]
+
+
 def blockwise_attention(q, k, v, *, q_block: int, scale: float, length,
                         window: Optional[int] = None,
                         chosen: Optional[List[jax.Array]] = None,

@@ -60,6 +60,21 @@ def visits(total: int, bq: int, bk: int):
     return tuple(np.asarray(col, np.int32) for col in zip(*pairs))
 
 
+def key_block(total: int, k_block: int = KEY_BLOCK) -> int:
+    """The key block a call of `total` rows runs: `k_block` cut to what it
+    shares with `total`."""
+    return math.gcd(total, k_block)
+
+
+def visits_run(total: int, bq: int, bk: int) -> np.ndarray:
+    """int32 (total // bq + 1,): entry r is the visits that do work for a
+    sequence whose first r q blocks are live (a visit of a q block past
+    `length` does nothing), counted from `visits`' own tables."""
+    q_of, _ = visits(total, bq, bk)
+    per_block = np.bincount(q_of, minlength=total // bq)
+    return np.concatenate([[0], np.cumsum(per_block)]).astype(np.int32)
+
+
 def _kernel(length, q_of, k_of, *refs, bq: int, bk: int, scale: float,
             shared: bool, masked: bool):
     refs = list(refs)
@@ -129,7 +144,8 @@ def attn_fused(q, k, v, length, mask=None, q_shared=None, k_shared=None, *,
                          % (total, q_block))
     if interpret is None:
         interpret = not select.on_chip()
-    bq, bk, hb = q_block, math.gcd(total, k_block), math.gcd(heads, head_tile)
+    bq, bk, hb = q_block, key_block(total, k_block), math.gcd(heads,
+                                                              head_tile)
     q_of, k_of = visits(total, bq, bk)
     shared, masked = q_shared is not None, mask is not None
 

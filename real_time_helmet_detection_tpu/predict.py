@@ -236,6 +236,10 @@ class Generation(NamedTuple):
     holds, the attention layers summed; `q_blocks_fused`: those of
     `q_blocks_run` that the fused attention kernel ran (`attn_fused`: the
     window-less per-head layers where Mosaic compiles, zeros elsewhere);
+    `attn_fused_visits`: the (q block, key block) visits the kernel did
+    work in for those blocks (its own visit table, key blocks of up to
+    1,024: a q block's visits follow where it sits), the fused layers
+    summed;
     `expert_visits`: for each prefill or step and expert layer, the experts
     that had at least one pair, credited to the lowest row that routed there
     (rows add up to the batch's count: what part of the experts' weights the
@@ -258,6 +262,7 @@ class Generation(NamedTuple):
     q_blocks_run: jax.Array    # int32 (B,)
     q_blocks_total: jax.Array  # int32 (B,)
     q_blocks_fused: jax.Array  # int32 (B,)
+    attn_fused_visits: jax.Array  # int32 (B,)
     expert_visits: jax.Array     # int32 (B,)
     cache_slots_read: jax.Array  # int32 (B, 2): full, sliding
     cache_keys_real: jax.Array   # int32 (B, 2)
@@ -307,6 +312,7 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             prompt_len=lengths, q_blocks_run=counts["q_blocks_run"],
             q_blocks_total=counts["q_blocks_total"],
             q_blocks_fused=counts["q_blocks_fused"],
+            attn_fused_visits=counts["attn_fused_visits"],
             expert_visits=counts["expert_visits"],
             cache_slots_read=jnp.stack([counts["slots_full"],
                                         counts["slots_window"]], axis=1),
@@ -334,6 +340,7 @@ def generation_counters(p_max: int) -> Callable:
                "gen.q_blocks_run": int(np.sum(rows.q_blocks_run)),
                "gen.q_blocks_total": int(np.sum(rows.q_blocks_total)),
                "gen.q_blocks_fused": int(np.sum(rows.q_blocks_fused)),
+               "gen.attn_fused_visits": int(np.sum(rows.attn_fused_visits)),
                # one call is one batch: every expert layer is passed once
                # by the prefill and once by each step after the first token
                "gen.expert_passes": int(rows.expert_tokens.shape[1]

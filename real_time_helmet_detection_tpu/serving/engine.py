@@ -110,13 +110,18 @@ Design rules, each load-bearing:
   at construction; `serve:queue-wait` per request; per batch, on the
   dispatcher thread `serve:batch-form` / `serve:h2d` / `serve:dispatch`
   (the ASYNCHRONOUS call of the compiled bucket: host time, not device
-  time), on the fetcher `serve:inflight-wait` / `serve:device-wait`
-  (`block_until_ready`: the batch period as the host sees it) /
-  `serve:d2h` (the `device_get` of a finished batch alone);
-  `serve:e2e` per request. They go to the process's in-memory ring
+  time), on the fetcher `serve:device-wait` (`block_until_ready`: the
+  batch period as the host sees it) / `serve:d2h` (the `device_get` of a
+  finished batch alone) / `serve:deliver` (the hand-out: the batch's
+  `row_counters` into the registry, THEN its futures resolved; meta `b`,
+  `n` real rows and the `counters` dict as computed, so every count of a
+  batch is in the registry before any of its answers is visible, and a
+  window's batches are the `serve:deliver` records that START inside
+  it); `serve:e2e` per request. They go to the process's in-memory ring
   (obs/spans.py) and, when `$OBS_SPAN_LOG` or `tracer=` names a file,
   to the span log. The engine calls nothing of its tracer but
-  `span`/`record`/`event`/`enabled`: the benchmark hands in its own.
+  `span`/`record`/`event`/`enabled`: the benchmark hands in its own,
+  and the engine writes the ring beside it (`obs.spans.with_ring`).
 * **Trace contexts (ISSUE 14).** With tracing enabled, every request
   carries a `TraceContext` (obs/trace.py): `submit(ctx=...)` accepts
   one from the FleetRouter, else the engine mints a root itself.
@@ -339,7 +344,9 @@ class ServingEngine:
     sharding : optional `jax.sharding` for the image batch (the meshed
         eval path); variables are replicated when a sharding is given.
     tracer : `obs.spans.SpanTracer`; default `maybe_tracer()` honors
-        $OBS_SPAN_LOG.
+        $OBS_SPAN_LOG. Any other tracer (`span`/`record`/`event`/
+        `enabled`) gets what it always got, and the process ring gets
+        the same (`with_ring`).
     start : tests may construct paused (`start=False`) to exercise
         admission control deterministically, then call `.start()`.
     max_retries : per-REQUEST retry budget after a batch failure/hang
@@ -357,11 +364,12 @@ class ServingEngine:
     watchdog : optional `obs.slo.SloWatchdog`, poked after every batch
         outcome; serving alerts degrade THIS engine.
     row_counters : optional `rows -> {counter name: increment}`, called on
-        the fetch thread once a batch's answers are delivered, with the
-        fetched answer cut to its real rows; the increments go into
-        `metrics` (how a program's answer feeds counters:
-        `predict.generation_counters`). A callback that raises is counted
-        (`serve.row_counter_errors`) and the engine serves on.
+        the fetch thread once a batch is on the host and BEFORE any of its
+        answers is delivered, with the fetched answer cut to its real
+        rows; the increments go into `metrics` (how a program's answer
+        feeds counters: `predict.generation_counters`) and the dict into
+        the batch's `serve:deliver` record. A callback that raises is
+        counted (`serve.row_counter_errors`) and the engine serves on.
     """
 
     def __init__(self, predict, variables, payload_shape: Sequence[int],
@@ -375,7 +383,7 @@ class ServingEngine:
         import jax
 
         from ..obs import metrics as metrics_mod
-        from ..obs.spans import maybe_tracer
+        from ..obs.spans import maybe_tracer, with_ring
         from ..obs.telemetry import install_compile_listener
 
         self._buckets = tuple(sorted({int(b) for b in buckets}))
@@ -387,7 +395,8 @@ class ServingEngine:
         self._max_wait_s = max(0.0, float(max_wait_ms)) / 1e3
         self._depth = max(1, int(depth))
         self._sharding = sharding
-        self._tracer = tracer if tracer is not None else maybe_tracer()
+        self._tracer = with_ring(tracer if tracer is not None
+                                 else maybe_tracer())
         self._max_retries = max(0, int(max_retries))
         self._hang_timeout_s = (None if hang_timeout_s is None
                                 else max(1e-3, float(hang_timeout_s)))
@@ -410,8 +419,6 @@ class ServingEngine:
         self._mg_retry = mm.gauge("serve.retry_depth")
         self._mg_inflight = mm.gauge("serve.inflight_batches")
         self._mh_e2e = mm.histogram("serve.e2e_ms")
-        self._mg_fill = {b: mm.gauge("serve.fill.b%d" % b)
-                         for b in self._buckets}
 
         self._variables = self._commit_variables(variables)
         # AOT: one compile per bucket, at construction, from the SAME
@@ -1019,14 +1026,9 @@ class ServingEngine:
                 self._mc["batches_total"].inc()
                 self._mc["batch_slots"].inc(b)
                 self._mc["padded_slots"].inc(b - len(live))
-                self._mg_fill[b].set(len(live) / b)
                 self._mg_inflight.set(inflight)
                 self._mg_queue.set(self._q.qsize())
-            # the monotonic stamp feeds the serve:inflight-wait span (the
-            # dispatch-done -> fetch-start gap: where a deep pipeline
-            # parks a batch behind its predecessors' D2H — without it the
-            # waterfall cannot attribute a loaded p99, ISSUE 14)
-            self._inflight.put((out, live, b, time.monotonic(), st))
+            self._inflight.put((out, live, b, st))
             # depth-bounded: blocks at `depth` in-flight batches — the
             # pipelining backpressure
         self._inflight.put(_SENTINEL)
@@ -1094,35 +1096,31 @@ class ServingEngine:
         except Exception:  # noqa: BLE001 — it has ended
             pass
 
-    def _fetch_loop(self) -> None:
-        while True:
-            item = self._inflight.get()
-            if item is _SENTINEL:
-                return
-            out, live, b, t_inq, st = item
-            flinks = links_of([r.ctx for r in live]) or None
-            self._tracer.record("serve:inflight-wait",
-                                time.monotonic() - t_inq, b=b,
-                                links=flinks)
-            try:
-                self._fetch_meta = (len(live), flinks)
-                host = self._fetch(out, b)
-            except Exception as e:  # noqa: BLE001 — requeue, serve on
-                if not isinstance(e, FetchHungError):
-                    self._await_end(out)
-                    self._staging_free.put(st)
-                # (an abandoned batch keeps its buffer: the device may still
-                # be reading it; the ring makes another)
-                self._requeue_or_fail(live, e, stage="fetch", b=b)
-                with self._lock:
-                    self._inflight_batches -= 1
-                continue
-            # the answer is on the host (after the D2H, not the wait: an
-            # output may alias its input): the staging buffer is free
-            self._staging_free.put(st)
+    def _deliver(self, host, live, b: int, links) -> None:
+        """Hand a fetched batch out, as one `serve:deliver` span: first
+        its counts (the registry's `completed` and whatever `row_counters`
+        gives, the dict also the span's `counters`), then its answers. A
+        batch's counts are thus in the registry before any of its answers
+        is visible, and the span's start precedes every answer's."""
+        n = len(live)
+        counts: Optional[dict] = {} if self._row_counters is not None \
+            else None
+        with self._tracer.span("serve:deliver", b=b, n=n, counters=counts,
+                               links=links):
             with self._lock:
-                self._stats["completed"] += len(live)
-            self._mc["completed"].inc(len(live))
+                self._stats["completed"] += n
+            self._mc["completed"].inc(n)
+            if self._row_counters is not None:
+                # never the fetch thread's death: a callback that raises
+                # costs its batch's increments only (and the record's)
+                try:
+                    got = self._row_counters(
+                        type(host)(*(leaf[:n] for leaf in host)))
+                    for name, by in got.items():
+                        self._metrics.counter(name).inc(by)
+                    counts.update(got)
+                except Exception:  # noqa: BLE001 — counted, serve on
+                    self._mc["row_counter_errors"].inc()
             # The cyclic collector stays out of the delivery loop: the
             # loop allocates a result per request, so a full collection
             # (60-130 ms beside a process that keeps 10^4 futures: PERF.md
@@ -1147,15 +1145,31 @@ class ServingEngine:
             finally:
                 if collecting:
                     gc.enable()
-            if self._row_counters is not None:
-                # after delivery, and never the fetch thread's death: a
-                # callback that raises costs its batch's increments only
-                try:
-                    rows = type(host)(*(leaf[:len(live)] for leaf in host))
-                    for name, by in self._row_counters(rows).items():
-                        self._metrics.counter(name).inc(by)
-                except Exception:  # noqa: BLE001 — counted, serve on
-                    self._mc["row_counter_errors"].inc()
+
+    def _fetch_loop(self) -> None:
+        while True:
+            item = self._inflight.get()
+            if item is _SENTINEL:
+                return
+            out, live, b, st = item
+            flinks = links_of([r.ctx for r in live]) or None
+            try:
+                self._fetch_meta = (len(live), flinks)
+                host = self._fetch(out, b)
+            except Exception as e:  # noqa: BLE001 — requeue, serve on
+                if not isinstance(e, FetchHungError):
+                    self._await_end(out)
+                    self._staging_free.put(st)
+                # (an abandoned batch keeps its buffer: the device may still
+                # be reading it; the ring makes another)
+                self._requeue_or_fail(live, e, stage="fetch", b=b)
+                with self._lock:
+                    self._inflight_batches -= 1
+                continue
+            # the answer is on the host (after the D2H, not the wait: an
+            # output may alias its input): the staging buffer is free
+            self._staging_free.put(st)
+            self._deliver(host, live, b, flinks)
             with self._lock:
                 self._inflight_batches -= 1
                 inflight = self._inflight_batches

@@ -313,12 +313,14 @@ def test_engine_serves_through_a_narrow_tracer(serve_parts):
     assert len(got) == 2
     assert {"serve:lower", "serve:compile", "serve:queue-wait",
             "serve:batch-form", "serve:h2d", "serve:dispatch",
-            "serve:inflight-wait", "serve:device-wait", "serve:d2h",
+            "serve:device-wait", "serve:d2h", "serve:deliver",
             "serve:e2e"} <= set(tracer.names)
     assert "serve:compute" not in tracer.names
+    assert "serve:inflight-wait" not in tracer.names
     # the wait for the device comes before the copy of a finished batch
     assert tracer.names.index("serve:device-wait") \
-        < tracer.names.index("serve:d2h")
+        < tracer.names.index("serve:d2h") \
+        < tracer.names.index("serve:deliver")
     # one histogram left: the five per-stage ones repeated the spans
     snap = registry.snapshot()
     assert list(snap["histograms"]) == ["serve.e2e_ms"]
@@ -387,16 +389,17 @@ def test_compile_listener_is_installed_once(serve_parts):
     t_start = time.monotonic()
 
     @jax.jit
-    def fresh(v):
+    def listener_probe(v):  # a name no other test compiles
         return v * 5.0 - 2.0
 
-    fresh(x).block_until_ready()
+    listener_probe(x).block_until_ready()
     second = install_recompile_counter()
     assert first.count >= 1 and second.count == 0
     assert first.last_dur_s is not None and second.last_dur_s is None
     spans = [(s, d, m) for n, s, d, m in
              default_tracer().snapshot(since=t_start - 60.0)
-             if n == "compile" and m and "fresh" in str(m.get("fun"))]
+             if n == "compile" and m
+             and "listener_probe" in str(m.get("fun"))]
     stages = [m["stage"] for _, _, m in spans]
     assert stages.count("backend") == 1
     assert {"trace", "lower", "backend"} <= set(stages)
