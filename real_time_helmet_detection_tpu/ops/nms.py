@@ -7,6 +7,9 @@ Capability parity with the reference NMS suite:
   * `soft_nms_mask` — Gaussian-decay Soft-NMS, the fixed-iteration masked
     reformulation of the reference's O(N^2) python loop with data-dependent
     swaps (/root/reference/evaluate.py:184-243);
+    each round selects its box by a one-hot mask and computes that box's
+    IoU row from coordinates: under `vmap`, an index that differs per
+    image would make every round a gather and two scatters;
   * `maxpool_nms_mask` — PSRR-MaxpoolNMS-style suppression (PAPERS.md:
     "accelerator-friendly NMS without sorting or sequential dependencies"):
     boxes scatter onto a (position x scale x ratio) score grid and a box
@@ -79,7 +82,7 @@ def nms_mask(boxes: jax.Array, scores: jax.Array, valid: jax.Array,
     return keep
 
 
-@partial(jax.jit, static_argnames=())
+@partial(jax.jit, static_argnames=("plus_one",))
 def soft_nms_mask(boxes: jax.Array, scores: jax.Array, valid: jax.Array,
                   sigma: float = 0.5, score_th: float = 0.001,
                   plus_one: bool = True):
@@ -90,23 +93,42 @@ def soft_nms_mask(boxes: jax.Array, scores: jax.Array, valid: jax.Array,
     same recurrence as the reference's swap-based loop, without any
     data-dependent control flow.
 
+    The round finds, reads and updates the selected box through a one-hot
+    mask and computes its IoU row from the coordinates: indexed by the
+    round's argmax, which differs per image, the body became a batched
+    gather of a row of an (N, N) IoU matrix and two scatters under `vmap`
+    (predict's 256 images on a TPU v5e: 24.7 ms a batch, 8.8% of its
+    device time; by mask 0.54 ms).
+
     Returns: (keep mask (N,) bool, decayed scores (N,) float32), original order.
     `plus_one=True` matches the reference's inclusive-coordinate IoU.
     """
     n = boxes.shape[0]
-    iou = _iou_matrix(boxes, plus_one=plus_one)
+    e = 1.0 if plus_one else 0.0
+    x1, y1, x2, y2 = boxes[:, 0], boxes[:, 1], boxes[:, 2], boxes[:, 3]
+    area = (x2 - x1 + e) * (y2 - y1 + e)
+    cols = jnp.stack([x1, y1, x2, y2, area])
 
     def body(_, state):
         cur_scores, processed = state
         cand = jnp.where(processed | ~valid, _NEG, cur_scores)
-        i = jnp.argmax(cand)
-        has_cand = cand[i] > _NEG / 2
-        weight = jnp.exp(-(iou[i] ** 2) / sigma)
+        sel = jnp.arange(n) == jnp.argmax(cand)  # first index among ties
+        has_cand = jnp.max(cand) > _NEG / 2
+        # the selected box's coordinates and area, exactly (sign of zero
+        # included, which a masked sum would not keep)
+        sx1, sy1, sx2, sy2, sarea = jnp.max(
+            jnp.where(sel, cols, -jnp.inf), axis=1)
+        # `_iou_matrix`'s row of the selected box, expression for
+        # expression, so the row is bit for bit the same
+        w = jnp.maximum(0.0, jnp.minimum(sx2, x2) - jnp.maximum(sx1, x1) + e)
+        h = jnp.maximum(0.0, jnp.minimum(sy2, y2) - jnp.maximum(sy1, y1) + e)
+        inter = w * h
+        iou = inter / (sarea + area - inter)
+        weight = jnp.exp(-(iou ** 2) / sigma)
         decayed = jnp.where(processed | ~valid, cur_scores, cur_scores * weight)
-        decayed = decayed.at[i].set(cur_scores[i])  # selected box keeps its score
+        decayed = jnp.where(sel, cur_scores, decayed)  # selected: as it was
         cur_scores = jnp.where(has_cand, decayed, cur_scores)
-        processed = processed.at[i].set(True) | processed
-        return cur_scores, processed
+        return cur_scores, processed | sel
 
     final_scores, _ = jax.lax.fori_loop(0, n, body, (scores, jnp.zeros((n,), bool)))
     keep = (final_scores > score_th) & valid

@@ -3,12 +3,17 @@ decay semantics, masked fixed-shape behavior, and the PSRR-style maxpool
 NMS's agreement rate vs the greedy chain (approximate by design — ISSUE 5
 satellite)."""
 
+from functools import partial
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
+from real_time_helmet_detection_tpu.analysis.trace_audit import _walk_jaxprs
 from real_time_helmet_detection_tpu.ops import (maxpool_nms_mask, nms_mask,
                                                 soft_nms_mask)
+from real_time_helmet_detection_tpu.ops.nms import _NEG, _iou_matrix
 
 
 def _np_greedy_nms(boxes, scores, iou_th):
@@ -179,6 +184,149 @@ def test_soft_nms_invalid_entries_ignored_vs_oracle():
     # invalid entries keep their input scores (decay never touches them)
     np.testing.assert_allclose(np.asarray(new_scores)[~valid],
                                scores[~valid], rtol=1e-6)
+
+
+def _iou_matrix_np(boxes, plus_one):
+    """`_iou_matrix`'s arithmetic in numpy's float32, one rounding an
+    operation. XLA's CPU backend contracts `area_i + area_j` inside that
+    (N, N) fusion into a fused multiply-add, one rounding fewer in some
+    entries; the TPU's does not (there the index form over the package's
+    matrix and the mask form read bit for bit alike)."""
+    b = np.asarray(boxes, np.float32)
+    e = np.float32(1.0 if plus_one else 0.0)
+    x1, y1, x2, y2 = (b[..., k] for k in range(4))
+    area = (x2 - x1 + e) * (y2 - y1 + e)
+    w = np.maximum(np.float32(0), np.minimum(x2[..., :, None], x2[..., None, :])
+                   - np.maximum(x1[..., :, None], x1[..., None, :]) + e)
+    h = np.maximum(np.float32(0), np.minimum(y2[..., :, None], y2[..., None, :])
+                   - np.maximum(y1[..., :, None], y1[..., None, :]) + e)
+    inter = w * h
+    with np.errstate(invalid="ignore", divide="ignore"):
+        return inter / (area[..., :, None] + area[..., None, :] - inter)
+
+
+@jax.jit
+def _gather_soft_nms(iou, scores, valid, sigma=0.5, score_th=0.001):
+    """The index-form body `soft_nms_mask` had before it selected by mask:
+    a precomputed (N, N) IoU matrix read at the round's argmax, the
+    selected entries set by index (under `vmap`, a gather and two scatters
+    a round). Kept as the oracle its answers must equal bit for bit."""
+    n = iou.shape[0]
+
+    def body(_, state):
+        cur_scores, processed = state
+        cand = jnp.where(processed | ~valid, _NEG, cur_scores)
+        i = jnp.argmax(cand)
+        has_cand = cand[i] > _NEG / 2
+        weight = jnp.exp(-(iou[i] ** 2) / sigma)
+        decayed = jnp.where(processed | ~valid, cur_scores, cur_scores * weight)
+        decayed = decayed.at[i].set(cur_scores[i])
+        cur_scores = jnp.where(has_cand, decayed, cur_scores)
+        processed = processed.at[i].set(True) | processed
+        return cur_scores, processed
+
+    final_scores, _ = jax.lax.fori_loop(0, n, body,
+                                        (scores, jnp.zeros((n,), bool)))
+    return (final_scores > score_th) & valid, final_scores
+
+
+def _soft_nms_rows(seed, b=8, n=200):
+    """A batch of rows shaped as predict hands soft-NMS (2 stacks x top-100),
+    each row a hard case for the selection: scores on a grid of 1/32, so
+    exact ties everywhere and at the maximum; duplicates; invalid entries
+    scattered (with the highest scores); one row with nothing valid."""
+    rng = np.random.RandomState(seed)
+    centers = rng.uniform(40, 470, (12, 2))
+    xy = centers[rng.randint(0, 12, (b, n))] + rng.uniform(-10, 10, (b, n, 2))
+    wh = rng.uniform(8, 80, (b, n, 2))
+    boxes = np.concatenate([xy - wh / 2, xy + wh / 2], -1)
+    scores = rng.randint(1, 33, (b, n)) / 32.0
+    valid = np.ones((b, n), bool)
+    scores[0, rng.choice(n, 6, replace=False)] = 1.25      # ties at the top
+    src, dst = rng.choice(n, 60), rng.choice(n, 60)
+    boxes[1, dst] = boxes[1, src]                          # IoU 1 ...
+    scores[1, dst[:30]] = scores[1, src[:30]]              # ... tied scores
+    valid[2] = rng.rand(n) < 0.6                           # scattered invalid,
+    scores[2, ~valid[2]] = 1.0                             # outscoring valid
+    valid[3] = False                                       # nothing valid
+    scores[4] = 0.5                                        # every score tied
+    boxes[5] = boxes[5, 0]                                 # every box the same
+    scores[6] = rng.uniform(0.01, 1.0, n)                  # mixed, continuous
+    valid[6] = rng.rand(n) < 0.8
+    boxes[6, :50] = boxes[6, 50:100]
+    boxes[7, :20, 2:] = boxes[7, :20, :2]                  # zero area
+    boxes[7, 20:40] -= 60.0                                # off the image
+    boxes[7, 40:60] = 0.0                                  # zeros
+    return (boxes.astype(np.float32), scores.astype(np.float32), valid)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("plus_one", [True, False])
+@pytest.mark.parametrize("seed,score_th", [(0, 0.0), (1, 0.001), (2, 0.3)])
+def test_soft_nms_masked_body_equals_index_body_bit_for_bit(seed, score_th,
+                                                            plus_one):
+    """Selecting by mask is the same arithmetic in another loop order: the
+    batched and the unbatched call answer exactly what the index form
+    answered, ties, duplicates and invalid entries included."""
+    boxes, scores, valid = (jnp.asarray(a) for a in _soft_nms_rows(seed))
+    kw = dict(sigma=0.5, score_th=score_th)
+    iou = jnp.asarray(_iou_matrix_np(boxes, plus_one))
+    oracle = jax.vmap(partial(_gather_soft_nms, **kw))
+    want_keep, want = oracle(iou, scores, valid)
+    kw["plus_one"] = plus_one
+    keep, got = jax.vmap(partial(soft_nms_mask, **kw))(boxes, scores, valid)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(want_keep))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    # the rows do exercise the recurrence: scores decayed, some not kept
+    assert (np.asarray(got) != np.asarray(scores)).any(axis=1)[
+        [0, 1, 2, 4, 5, 6, 7]].all()
+    assert not np.asarray(keep)[3].any()
+    for r in (0, 2, 3, 5):
+        k1, s1 = soft_nms_mask(boxes[r], scores[r], valid[r], **kw)
+        np.testing.assert_array_equal(np.asarray(k1), np.asarray(want_keep[r]))
+        np.testing.assert_array_equal(_bits(s1), _bits(want[r]))
+    # over the package's own matrix, as the index form ran: on the CPU its
+    # contracted entries move a score by a few units in the last place
+    old_keep, old = oracle(
+        jax.vmap(partial(_iou_matrix, plus_one=plus_one))(boxes), scores, valid)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(old_keep))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(old), rtol=1e-5)
+
+
+_INDEXED = ("gather", "scatter", "dynamic_slice", "dynamic_update_slice")
+
+
+def _index_form(boxes, scores, valid):
+    return _gather_soft_nms(_iou_matrix(boxes, plus_one=True), scores, valid)
+
+
+@pytest.mark.parametrize("fn,indexes", [(soft_nms_mask, False),
+                                        (_index_form, True)])
+def test_soft_nms_loop_body_indexes_nothing_under_vmap(fn, indexes):
+    """What keeps a later edit from bringing the per-image gather back
+    unseen on the CPU: at predict's shape (256 images x 200 boxes) the
+    loop body of the vmapped call holds no gather, scatter or dynamic
+    slice, and no (N, N) IoU matrix is formed. The index form is the
+    check's own control: it must be caught."""
+    b, n = 256, 200
+    closed = jax.make_jaxpr(jax.vmap(fn))(
+        jax.ShapeDtypeStruct((b, n, 4), jnp.float32),
+        jax.ShapeDtypeStruct((b, n), jnp.float32),
+        jax.ShapeDtypeStruct((b, n), jnp.bool_))
+    jaxprs = _walk_jaxprs(closed.jaxpr)
+    loops = [e for j in jaxprs for e in j.eqns
+             if e.primitive.name in ("while", "scan")]
+    assert len(loops) == 1
+    body = loops[0].params.get("body_jaxpr") or loops[0].params["jaxpr"]
+    found = {e.primitive.name for j in _walk_jaxprs(body.jaxpr)
+             for e in j.eqns if e.primitive.name.startswith(_INDEXED)}
+    square = [v.aval.shape for j in jaxprs for e in j.eqns for v in e.outvars
+              if v.aval.shape[-2:] == (n, n)]
+    assert bool(found) == indexes, found
+    assert bool(square) == indexes, square
 
 
 def _clustered_boxes(seed, n, ncl, jitter, wlo, whi, extent=512.0):
