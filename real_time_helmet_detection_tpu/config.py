@@ -57,9 +57,10 @@ ARCHITECTURE_FIELDS = (
 MODEL_VARIANTS = ("residual", "depthwise", "ghost")
 # model families `models.build_model` dispatches on: the reference's stacked
 # hourglass, and the decoder of models/decoder.py (no reference analogue)
-# `DECODER_FAMILIES`: one body, the attention the family names
-# (models/decoder.py `ATTENTIONS`), served by predict.make_generate_fn
-DECODER_FAMILIES = ("latent_moe_decoder", "gqa_moe_decoder")
+# `DECODER_FAMILIES`: one body, the attention each layer's kind names in its
+# family (models/decoder.py `ATTENTIONS`), served by predict.make_generate_fn
+DECODER_FAMILIES = ("latent_moe_decoder", "gqa_moe_decoder",
+                    "hybrid_moe_decoder")
 MODEL_FAMILIES = ("hourglass",) + DECODER_FAMILIES
 
 # Latency-tier presets (ISSUE 13): named architecture+serving bundles —

@@ -1,7 +1,8 @@
-"""The decoder families: ONE body (embedding, layer loop, a leading dense
-SwiGLU layer, sigmoid-routed experts with a shared expert as one share of an
+"""The decoder families: ONE body (embedding, layer loop, leading dense
+SwiGLU layers, sigmoid-routed experts with a shared expert as one share of an
 expert-parallel deployment or held whole, counts, head; `prefill` and `step`)
-behind which the spec selects one of two attentions (`ATTENTIONS`):
+in which each layer's KIND selects its attention within the family
+(`ATTENTIONS[family][kind]`, `attention_of`), so that one model may mix two:
 
 `latent_moe_decoder`  latent attention; decode with W_uk / W_uv absorbed into
     the query and the output. What the source's mapping holds decides the
@@ -17,21 +18,36 @@ behind which the spec selects one of two attentions (`ATTENTIONS`):
     number of query heads that differs BY LAYER, rotary positions from a table
     (YaRN on half the head on full layers, plain theta on the whole head on
     sliding ones), the same head-wise gate.
+`hybrid_moe_decoder`  `layer_group_size` n: layer i is latent attention
+    (`full_attention`) where (i + 1) % n == 0, KDA linear attention
+    (`linear_attention`) otherwise. The latent kind reads `q_lora_rank` null
+    as one W_q (no query latent) and `gated_attention_proj_granularity_type`
+    as the head-wise gate; the linear kind is the gated delta rule with a
+    decay a channel on a float32 state a head (`ops/linear_attention.py`,
+    the `kda_step` kernel in decode). Router: selection bias and group limit.
+    A setting whose other value this program does not compute (a SwiGLU
+    clamp in a held layer, a low-rank W_f, ...) is refused by its key.
 
 An attention is three things: the flax module that declares its parameters,
 `prefill_row(p, kind, spec, x, length, slots, faults) -> (out, cache entry,
 counts)` and `step(p, kind, spec, x, pos, entry, faults) -> (out, entry,
-counts)`.
+counts)`; the linear kind's prefill takes a pass of rows together
+(`Attention.rows`: 16 where the batch allows). The passes are a stopgap: a
+pass runs to its longest row, which costs the benchmark's hybrid cell about
+a tenth of its rate, and holds a batch's time to a level whatever its rows'
+lengths (ROADMAP R12 (i)).
 
 The reference has no language model (ref hourglass.py is the only network);
 this module is new capability. The equations are stated once a family, in
-benchmark/reference/latent_moe_decoder.py and
-benchmark/reference/gqa_moe_decoder.py (the plain references the tests and
-the benchmark hold this program to); this file is how the program computes
+benchmark/reference/latent_moe_decoder.py,
+benchmark/reference/gqa_moe_decoder.py and
+benchmark/reference/hybrid_moe_decoder.py (the plain references the tests
+and the benchmark hold this program to); this file is how the program computes
 them: bfloat16 parameters and activations, float32 in norms, softmax, router
 scores and the indexer's score sum; prefill a sequence at a time under
 `lax.map` (so that one row's q, k, v and scores are what stands in memory),
-blockwise over queries; decode one token a row against two kinds of cache.
+blockwise over queries, the linear layers a pass of rows at a time; decode
+one token a row against three kinds of cache.
 
 The flax modules declare parameters and hold no arithmetic of their own: a
 module reads its arrays, then calls the pure functions below (a flax module
@@ -44,7 +60,13 @@ Cache, one entry a layer (`cache["layers"][i]`):
                  kv heads, head dim), rotated before they are stored;
   sliding layer  a ring, position p at slot p % window: latent c_kv (B,
                  window, kv_rank), k_r (B, window, rope); grouped-query k, v
-                 (B, window, kv heads, head dim).
+                 (B, window, kv heads, head dim);
+  linear layer   a state that does not grow with positions: `state` (B,
+                 heads, head dim, head dim) float32 after the row's last
+                 token, `conv` (B, taps - 1, 3 heads x head dim): the row's
+                 last real inputs to the short convolution. Prefill writes
+                 both at each row's own length (padding writes nothing);
+                 each step reads and rewrites them.
 `cache["counts"]`, per row: pairs routed to each held expert by expert layer
 (padding excluded); expert visits (for each prefill or step and expert layer,
 the experts that had at least one pair, credited to the lowest row that
@@ -54,7 +76,10 @@ this share holds was among the groups kept; the q blocks of
 prefill attention that ran and that the padded row holds, attention layers
 summed; and what one attention counts (`ATTENTION_COUNTS`; zeros for the
 other): keys the indexer kept and keys causal, full layers summed; cache
-slots the decode steps read and real keys among them, by layer kind.
+slots the decode steps read and real keys among them, by layer kind; the
+linear layers' state read-modify-writes (steps x layers) and the chunks of
+their prefill that ran for the row (its pass's: the pass runs to its longest
+row), that the row's own tokens fill, and that the padded row holds.
 """
 
 from __future__ import annotations
@@ -69,17 +94,22 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops import attention as att
+from ..ops import linear_attention as la
 from ..ops import moe
 from ..parallel.experts import ExpertShare, expert_share
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+LINEAR = "linear_attention"
 FAMILY = "latent_moe_decoder"
 GQA_FAMILY = "gqa_moe_decoder"
+HYBRID_FAMILY = "hybrid_moe_decoder"
 # per-row counts an attention may give (int32 (B,)); the body adds the layers
-# up and a count the family's attention does not give stays zeros
+# up and a count the layer's attention does not give stays zeros
 ATTENTION_COUNTS = ("keys_kept", "keys_causal", "q_blocks_run",
                     "q_blocks_fused", "attn_fused_visits", "slots_full",
-                    "keys_full", "slots_window", "keys_window")
+                    "keys_full", "slots_window", "keys_window",
+                    "linear_state_steps", "linear_chunks_run",
+                    "linear_chunks_live", "linear_chunks_total")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +131,17 @@ class AttnSizes:
     gate: bool
     inv_freq: Optional[Tuple[float, ...]] = None
     rope_scale: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearSizes:
+    """The linear-attention kind (KDA): heads of `head_dim` keys and values,
+    the short convolution's taps, the decay's lower bound (g in (lower,
+    0))."""
+    heads: int
+    head_dim: int
+    conv: int
+    lower: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,7 +225,8 @@ class DecoderSpec:
     `config.json` keys as they stand, plus `ep_size` / `ep_rank`, and the
     source's count of routed experts / `vocab_size` counting what is held
     HERE). `full` / `swa`: the attention's sizes by layer kind, an
-    `AttnSizes` or a `GroupedSizes` by the family."""
+    `AttnSizes` or a `GroupedSizes` by the family; `linear`: the
+    linear-attention kind's `LinearSizes` (None where no layer has it)."""
     family: str
     hidden: int
     vocab: int
@@ -212,6 +254,8 @@ class DecoderSpec:
     # heads a pass of the attention and of the indexer
     q_block: int = 512
     head_block: int = 32
+    linear: object = None
+    linear_chunk: int = la.CHUNK  # positions a chunk of the linear prefill
 
     @classmethod
     def from_mapping(cls, d: Mapping, family: str = FAMILY) -> "DecoderSpec":
@@ -219,11 +263,11 @@ class DecoderSpec:
             raise ValueError("no decoder of family %r (have: %s)"
                              % (family, ", ".join(sorted(_SPEC_KEYS))))
         layers = int(d["num_hidden_layers"])
-        # a source without `layer_types` has one kind of layer
-        kinds = tuple(d.get("layer_types") or (FULL,) * layers)[:layers]
-        if len(kinds) != layers or set(kinds) - {FULL, SLIDING}:
-            raise ValueError("layer_types must name %d layers as %s or %s, "
-                             "got %r" % (layers, FULL, SLIDING, kinds))
+        kinds = _layer_kinds(d, layers)
+        allowed = sorted(ATTENTIONS[family])
+        if len(kinds) != layers or set(kinds) - set(allowed):
+            raise ValueError("layer_types must name %d layers as %s, got %r"
+                             % (layers, " or ".join(allowed), kinds))
         return cls(
             family=family, hidden=int(d["hidden_size"]),
             vocab=int(d["vocab_size"]), kinds=kinds,
@@ -233,6 +277,7 @@ class DecoderSpec:
             eps=float(d["rms_norm_eps"]),
             q_block=int(d.get("attn_q_block", 512)),
             head_block=int(d.get("attn_head_block", 32)),
+            linear_chunk=int(d.get("linear_chunk", la.CHUNK)),
             **_SPEC_KEYS[family](d, kinds))
 
     @property
@@ -247,14 +292,57 @@ class DecoderSpec:
     def full_layers(self) -> int:
         return sum(kind == FULL for kind in self.kinds)
 
+    @property
+    def linear_layers(self) -> int:
+        return sum(kind == LINEAR for kind in self.kinds)
+
     def attn(self, kind: str):
-        return self.full if kind == FULL else self.swa
+        return {FULL: self.full, SLIDING: self.swa, LINEAR: self.linear}[kind]
+
+
+def _layer_kinds(d: Mapping, layers: int) -> tuple:
+    """The source's `layer_types`; else, where it states `layer_group_size`
+    n, layer i is full attention when (i + 1) % n == 0 and linear attention
+    otherwise (the linear-hybrid sources' rule); else one kind of layer."""
+    if d.get("layer_types"):
+        return tuple(d["layer_types"])[:layers]
+    group = int(d.get("layer_group_size") or 0)
+    if group:
+        return tuple(FULL if (i + 1) % group == 0 else LINEAR
+                     for i in range(layers))
+    return (FULL,) * layers
 
 
 def _share(d: Mapping, held_key: str, n_group: int = 0) -> ExpertShare:
     ep = int(d.get("ep_size", 1))
     return expert_share(ep, int(d.get("ep_rank", 0)), int(d[held_key]) * ep,
                         n_group)
+
+
+def _latent_sizes(d: Mapping, prefix: str, gate: bool) -> AttnSizes:
+    """A latent attention's sizes from the source's keys (`prefix`: swa_ for
+    the sliding kind); a null `q_lora_rank` is one W_q, no query latent."""
+    nope, rope = (int(d[prefix + "qk_nope_head_dim"]),
+                  int(d[prefix + "qk_rope_head_dim"]))
+    theta = float(d[prefix + "rope_theta"])
+    freq, rope_scale, score = latent_rotary(
+        d.get(prefix + "rope_scaling"), theta, rope)
+    return AttnSizes(
+        int(d[prefix + "num_attention_heads"]),
+        int(d[prefix + "q_lora_rank"] or 0), int(d[prefix + "kv_lora_rank"]),
+        nope, rope, int(d[prefix + "v_head_dim"]), theta,
+        scale=score / math.sqrt(nope + rope),
+        rescale=bool(d.get("apply_mla_qkv_lora_rescale")),
+        gate=gate, inv_freq=freq, rope_scale=rope_scale)
+
+
+def _groups(d: Mapping) -> Tuple[int, int]:
+    n_group = int(d.get("n_group") or 0)
+    topk_group = int(d.get("topk_group") or 0)
+    if n_group and not 0 < topk_group <= n_group:
+        raise ValueError("topk_group %d is not 1..n_group %d"
+                         % (topk_group, n_group))
+    return n_group, topk_group
 
 
 def _latent_keys(d: Mapping, kinds) -> dict:
@@ -265,25 +353,10 @@ def _latent_keys(d: Mapping, kinds) -> dict:
         if gate not in (None, "headwise"):
             raise ValueError("%sattention_gate_type %r is not headwise"
                              % (prefix, gate))
-        nope, rope = (int(d[prefix + "qk_nope_head_dim"]),
-                      int(d[prefix + "qk_rope_head_dim"]))
-        theta = float(d[prefix + "rope_theta"])
-        freq, rope_scale, score = latent_rotary(
-            d.get(prefix + "rope_scaling"), theta, rope)
-        return AttnSizes(
-            int(d[prefix + "num_attention_heads"]),
-            int(d[prefix + "q_lora_rank"]), int(d[prefix + "kv_lora_rank"]),
-            nope, rope, int(d[prefix + "v_head_dim"]), theta,
-            scale=score / math.sqrt(nope + rope),
-            rescale=bool(d.get("apply_mla_qkv_lora_rescale")),
-            gate=gate is not None, inv_freq=freq, rope_scale=rope_scale)
+        return _latent_sizes(d, prefix, gate is not None)
     full = sizes("")
     swa = sizes("swa_") if SLIDING in kinds else None
-    n_group = int(d.get("n_group") or 0)
-    topk_group = int(d.get("topk_group") or 0)
-    if n_group and not 0 < topk_group <= n_group:
-        raise ValueError("topk_group %d is not 1..n_group %d"
-                         % (topk_group, n_group))
+    n_group, topk_group = _groups(d)
     index_topk = int(d.get("index_topk") or 0)
     return dict(
         heads=tuple((full if k == FULL else swa).heads for k in kinds),
@@ -328,9 +401,58 @@ def _grouped_keys(d: Mapping, kinds) -> dict:
            for name, kind in (("full", FULL), ("swa", SLIDING))})
 
 
+# switches of a linear-hybrid source whose other values would change the
+# function this program computes: a value not listed is refused by name
+_HYBRID_SWITCHES = {
+    "hidden_act": ("silu",), "scoring_func": ("sigmoid",),
+    "kda_safe_gate": (True,), "linear_silu": (True,), "group_norm_size": (1,),
+    "use_kda_lora": (False, None), "no_kda_lora": (True, None),
+    "use_mla_nope": (False, None), "use_nGPT": (False, None),
+    "value_norm": (False, None), "up_proj_norm": (False, None),
+    "use_qkv_bias": (False, None), "use_bias": (False, None),
+    "scale_router_input": (False, None),
+    "gated_attention_proj_granularity_type": ("head_wise", None)}
+
+
+def _hybrid_keys(d: Mapping, kinds) -> dict:
+    """A linear-hybrid source (`layer_group_size`): KDA linear attention
+    beside latent attention whose query has no latent (`q_lora_rank` null)
+    and whose output the head-wise gate scales, sigmoid-routed experts with
+    a selection bias and group-limited choice. A SwiGLU clamp in a layer
+    held here is refused: its convention is not settled."""
+    for key, allowed in _HYBRID_SWITCHES.items():
+        if d.get(key) not in allowed:
+            raise ValueError("%s %r is not %s" % (key, d.get(key), " or ".join(
+                repr(v) for v in allowed)))
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        held = list(d.get(key) or ())[:len(kinds)]
+        if any(held):
+            raise ValueError("%s: a SwiGLU clamp in a held layer (%r) has no "
+                             "settled convention" % (key, held))
+    full = _latent_sizes(d, "", d.get("gated_attention_proj_granularity_type")
+                         is not None)
+    linear = LinearSizes(
+        int(d.get("num_kv_heads_for_linear_attn") or d["num_attention_heads"]),
+        int(d["head_dim"]), int(d["short_conv_kernel_size"]),
+        float(d["kda_lower_bound"]))
+    n_group, topk_group = _groups(d)
+    return dict(
+        heads=tuple(full.heads if k == FULL else linear.heads for k in kinds),
+        dense_layers=int(d["first_k_dense_replace"]),
+        shared_width=int(d["moe_shared_expert_intermediate_size"])
+        * int(d["num_shared_experts"]),
+        share=_share(d, "num_experts", n_group),
+        norm_weights=bool(d["norm_topk_prob"]),
+        routed_scale=float(d["routed_scaling_factor"]), window=0,
+        n_group=n_group, topk_group=topk_group,
+        select_bias=d.get("topk_method") == "noaux_tc",
+        full=full, swa=None, linear=linear)
+
+
 # what each family's source calls its sizes (ROADMAP D11/D13: the source's
 # keys as they stand)
-_SPEC_KEYS = {FAMILY: _latent_keys, GQA_FAMILY: _grouped_keys}
+_SPEC_KEYS = {FAMILY: _latent_keys, GQA_FAMILY: _grouped_keys,
+              HYBRID_FAMILY: _hybrid_keys}
 
 
 _INIT = nn.initializers.normal(0.02)
@@ -365,8 +487,11 @@ def _score_scale(a: AttnSizes, faults) -> float:
 
 
 def _queries(p, a: AttnSizes, spec: DecoderSpec, xn, faults=frozenset()):
-    """xn (..., hidden) -> (c_q (..., q_rank), q (..., heads, nope + rope),
-    the rope part not rotated yet)."""
+    """xn (..., hidden) -> (c_q (..., q_rank) or None where the query has no
+    latent, q (..., heads, nope + rope), the rope part not rotated yet)."""
+    if not a.q_rank:
+        q = jnp.dot(xn, p["w_q"])
+        return None, q.reshape(q.shape[:-1] + (a.heads, a.nope + a.rope))
     c_q = _latent_norm(jnp.dot(xn, p["w_dq"]), p["q_norm"], a.q_rank, a,
                        spec, faults)
     q = jnp.dot(c_q, p["w_uq"])
@@ -686,6 +811,130 @@ def grouped_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
         "keys_" + tag: keys, "keys_causal": keys * (kind == FULL)}
 
 
+# ---- the linear attention (KDA) -------------------------------------------------
+
+def _linear_inputs(p, xn):
+    """xn (..., hidden) -> what the short convolution takes: the q, k and v
+    projections side by side (..., 3 H d), in xn's dtype."""
+    return jnp.concatenate([jnp.dot(xn, p["w_q"]), jnp.dot(xn, p["w_k"]),
+                            jnp.dot(xn, p["w_v"])], axis=-1)
+
+
+def _linear_qkv(l: LinearSizes, c, faults):
+    """c (..., 3 H d) float32 after the convolution -> q (scaled by d^-1/2),
+    k (..., H, d) normalised a head, v (..., H, d)."""
+    c = jax.nn.silu(c)
+    lead, width = c.shape[:-1], l.heads * l.head_dim
+    q, k, v = (c[..., i * width:(i + 1) * width].reshape(
+        lead + (l.heads, l.head_dim)) for i in range(3))
+    if "no_l2norm" not in faults:
+        q, k = la.l2_normalize(q), la.l2_normalize(k)
+    return q * l.head_dim ** -0.5, k, v
+
+
+def _linear_gates(p, l: LinearSizes, xn, faults):
+    """xn (..., hidden) -> (g (..., H, d) float32 in (lower, 0): the decay
+    of each channel in log space, beta (..., H) float32: the write's
+    strength)."""
+    f = (jnp.dot(xn, p["w_f"], preferred_element_type=jnp.float32)
+         + p["dt_bias"].astype(jnp.float32))
+    f = f.reshape(f.shape[:-1] + (l.heads, l.head_dim))
+    g = l.lower * jax.nn.sigmoid(
+        jnp.exp(p["a_log"].astype(jnp.float32))[:, None] * f)
+    beta = jax.nn.sigmoid(jnp.dot(xn, p["w_beta"],
+                                  preferred_element_type=jnp.float32))
+    if "decay_dropped" in faults:
+        g = jnp.zeros_like(g)
+    if "beta_one" in faults:
+        beta = jnp.ones_like(beta)
+    return g, beta
+
+
+def _linear_output(p, spec: DecoderSpec, xn, o, faults):
+    """o (..., H, d) float32 -> (..., hidden): RMSNorm a head (one scale
+    shared by the heads), the full-rank sigmoid gate, W_o."""
+    o = att.rms_norm(o, p["o_norm"], spec.eps)
+    if "out_gate_dropped" not in faults:
+        gate = jax.nn.sigmoid(jnp.dot(xn, p["w_g"],
+                                      preferred_element_type=jnp.float32))
+        o = o * gate.reshape(o.shape)
+    return jnp.dot(o.reshape(o.shape[:-2] + (-1,)).astype(xn.dtype),
+                   p["w_o"])
+
+
+def _state_kept(state, faults):
+    if "state_bf16" in faults:  # a state held in bfloat16 between tokens
+        # (reduce_precision: a cast there and back the compiler may drop)
+        return lax.reduce_precision(state, 8, 7)
+    return state
+
+
+def linear_prefill_rows(p, kind: str, spec: DecoderSpec, x, lengths,
+                        slots: int, faults=frozenset()):
+    """A pass of R sequences (`Attention.rows`). x (R, P, hidden) raw,
+    `lengths` (R,) their real rows. Returns (attention output (R, P,
+    hidden), the rows' cache entries: `state` (R, H, d, d) float32 after
+    each row's last real token, `conv` (R, K-1, 3 H d) its last K-1 real
+    inputs to the convolution; counts (R,): the chunks of the delta rule the
+    pass ran (its longest row's), that the row's own tokens fill, and that a
+    padded row holds). A padded position writes nothing: beta and g are 0
+    there."""
+    l = spec.linear
+    total = x.shape[1]
+    xn = att.rms_norm(x, p["attn_norm"], spec.eps)
+    with jax.named_scope("conv"):
+        u = _linear_inputs(p, xn)
+        c = (u.astype(jnp.float32) if "conv_dropped" in faults
+             else la.causal_conv(u, p["conv"]))
+        q, k, v = _linear_qkv(l, c, faults)
+        tail = la.conv_tail(u, lengths, l.conv)
+    with jax.named_scope("gate"):
+        g, beta = _linear_gates(p, l, xn, faults)
+        if "padding_updates_state" not in faults:
+            real = (jnp.arange(total, dtype=jnp.int32)[None, :]
+                    < lengths[:, None])
+            g = jnp.where(real[..., None, None], g, 0.0)
+            beta = jnp.where(real[..., None], beta, 0.0)
+    with jax.named_scope("scan"):
+        o, state, ran = la.chunked_prefill(q, k, v, g, beta, lengths,
+                                           spec.linear_chunk)
+    with jax.named_scope("out_norm"):
+        out = _linear_output(p, spec, xn, o, faults)
+    return out, {"state": _state_kept(state, faults), "conv": tail}, {
+        "linear_chunks_run": ran,
+        "linear_chunks_live": -(-lengths // spec.linear_chunk),
+        "linear_chunks_total": jnp.full(lengths.shape,
+                                        -(-total // spec.linear_chunk),
+                                        jnp.int32)}
+
+
+def linear_step(p, kind: str, spec: DecoderSpec, x, pos, entry,
+                faults=frozenset()):
+    """One token a row. x (B, hidden) raw, `entry` the layer's state and
+    convolution tail. Returns (attention output (B, hidden), the entry after
+    the token, counts: one state read-modify-write a row)."""
+    l = spec.linear
+    xn = att.rms_norm(x, p["attn_norm"], spec.eps)
+    with jax.named_scope("conv"):
+        u = _linear_inputs(p, xn)
+        if "conv_dropped" in faults:
+            c, tail = u.astype(jnp.float32), entry["conv"]
+        else:
+            c, tail = la.conv_step(u, p["conv"], entry["conv"])
+        q, k, v = _linear_qkv(l, c, faults)
+    with jax.named_scope("gate"):
+        g, beta = _linear_gates(p, l, xn, faults)
+    state = entry["state"]
+    if "state_reset_at_decode" in faults:  # every step from an empty state
+        state = jnp.zeros_like(state)
+    with jax.named_scope("state"):
+        o, state = la.state_step(state, q, k, v, g, beta)
+    with jax.named_scope("out_norm"):
+        out = _linear_output(p, spec, xn, o, faults)
+    return out, {"state": _state_kept(state, faults), "conv": tail}, {
+        "linear_state_steps": jnp.ones(pos.shape, jnp.int32)}
+
+
 def expert_layer_groups(p, spec: DecoderSpec, hn, token_real,
                         faults=frozenset()):
     """hn (T, hidden) normed -> (y (T, hidden): the held experts' part plus
@@ -772,17 +1021,20 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self):
         s, a, d = self.spec, self.spec.attn(self.kind), self.dtype
-        p = {
-            "w_dq": self.param("w_dq", _INIT, (s.hidden, a.q_rank), d),
-            "q_norm": self.param("q_norm", _scale_init, (a.q_rank,), d),
-            "w_uq": self.param("w_uq", _INIT,
-                               (a.q_rank, a.heads * (a.nope + a.rope)), d),
+        q_width = a.heads * (a.nope + a.rope)
+        if a.q_rank:
+            p = {"w_dq": self.param("w_dq", _INIT, (s.hidden, a.q_rank), d),
+                 "q_norm": self.param("q_norm", _scale_init, (a.q_rank,), d),
+                 "w_uq": self.param("w_uq", _INIT, (a.q_rank, q_width), d)}
+        else:  # no query latent: one W_q
+            p = {"w_q": self.param("w_q", _INIT, (s.hidden, q_width), d)}
+        p.update({
             "w_dkv": self.param("w_dkv", _INIT,
                                 (s.hidden, a.kv_rank + a.rope), d),
             "kv_norm": self.param("kv_norm", _scale_init, (a.kv_rank,), d),
             "w_ukv": self.param("w_ukv", _INIT,
                                 (a.kv_rank, a.heads * (a.nope + a.v)), d),
-            "w_o": self.param("w_o", _INIT, (a.heads * a.v, s.hidden), d)}
+            "w_o": self.param("w_o", _INIT, (a.heads * a.v, s.hidden), d)})
         if a.gate:
             p["w_g"] = self.param("w_g", _INIT, (s.hidden, a.heads), d)
         if self.kind == FULL and s.index_topk:
@@ -811,17 +1063,56 @@ class GroupedAttention(nn.Module):
             "w_o": self.param("w_o", _INIT, (heads * g.head_dim, s.hidden), d)}
 
 
+class LinearAttention(nn.Module):
+    """The linear-attention kind (KDA) of layer `index`: W_q, W_k, W_v, the
+    decay's W_f (full rank), A_log (a head) and dt_bias (a channel), the
+    short convolution's taps, W_beta, the output's norm, gate and W_o. The
+    arithmetic is `linear_prefill_rows` / `linear_step`."""
+    spec: DecoderSpec
+    index: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        s, d, l = self.spec, self.dtype, self.spec.linear
+        width = l.heads * l.head_dim
+        p = {name: self.param(name, _INIT, (s.hidden, width), d)
+             for name in ("w_q", "w_k", "w_v", "w_f", "w_g")}
+        p.update(
+            w_beta=self.param("w_beta", _INIT, (s.hidden, l.heads), d),
+            a_log=self.param("a_log", nn.initializers.zeros, (l.heads,),
+                             jnp.float32),
+            dt_bias=self.param("dt_bias", nn.initializers.zeros, (width,),
+                               jnp.float32),
+            conv=self.param("conv", _INIT, (l.conv, 3 * width), d),
+            o_norm=self.param("o_norm", _scale_init, (l.head_dim,), d),
+            w_o=self.param("w_o", _INIT, (width, s.hidden), d))
+        return p
+
+
 class Attention(NamedTuple):
-    """What the spec's family selects (module docstring)."""
+    """What a layer's kind selects (module docstring). `rows`: the rows a
+    prefill pass takes together; with 1, `prefill_row` takes one row (x (P,
+    hidden), a scalar length), else a leading axis of as many rows as divide
+    both the batch and `rows`."""
     module: Callable
     prefill_row: Callable
     step: Callable
+    rows: int = 1
 
 
+_LATENT = Attention(LatentAttention, _latent_prefill_row, _latent_step)
+_GROUPED = Attention(GroupedAttention, grouped_prefill_row, grouped_step)
+# the attention of each layer kind, by family
 ATTENTIONS = {
-    FAMILY: Attention(LatentAttention, _latent_prefill_row, _latent_step),
-    GQA_FAMILY: Attention(GroupedAttention, grouped_prefill_row,
-                          grouped_step)}
+    FAMILY: {FULL: _LATENT, SLIDING: _LATENT},
+    GQA_FAMILY: {FULL: _GROUPED, SLIDING: _GROUPED},
+    HYBRID_FAMILY: {FULL: _LATENT, LINEAR: Attention(
+        LinearAttention, linear_prefill_rows, linear_step, la.ROWS)}}
+
+
+def attention_of(spec: DecoderSpec, kind: str) -> Attention:
+    return ATTENTIONS[spec.family][kind]
 
 
 class DenseFFN(nn.Module):
@@ -877,7 +1168,7 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self):
         s, d = self.spec, self.dtype
-        attention = ATTENTIONS[s.family].module
+        attention = attention_of(s, self.kind).module
         p = {"attn": dict(attention(s, self.index, d, name="attn")(),
                           attn_norm=self.param("attn_norm", _scale_init,
                                                (s.hidden,), d)),
@@ -890,7 +1181,8 @@ class DecoderLayer(nn.Module):
 
 
 def _attn_scope(kind: str) -> str:
-    return "attn_full" if kind == FULL else "attn_window"
+    return {FULL: "attn_full", SLIDING: "attn_window",
+            LINEAR: "attn_linear"}[kind]
 
 
 def _feed_forward(p, spec: DecoderSpec, dense: bool, x, real, faults):
@@ -989,12 +1281,19 @@ class MoEDecoder(nn.Module):
         slots = total + reserve
         lengths = jnp.clip(lengths, 1, total)
         real = jnp.arange(total, dtype=jnp.int32)[None, :] < lengths[:, None]
-        prefill_row = ATTENTIONS[s.family].prefill_row
 
         def attend(i, p, kind, x):
-            return lax.map(lambda xl: prefill_row(
-                p["attn"], kind, s, xl[0], xl[1], slots, self.faults),
-                (x, lengths))
+            attention = attention_of(s, kind)
+            run = lambda xl: attention.prefill_row(  # noqa: E731
+                p["attn"], kind, s, xl[0], xl[1], slots, self.faults)
+            if attention.rows == 1:
+                return lax.map(run, (x, lengths))
+            together = math.gcd(rows, attention.rows)
+            passes = lax.map(run, (
+                x.reshape((rows // together, together) + x.shape[1:]),
+                lengths.reshape(rows // together, together)))
+            return jax.tree.map(
+                lambda a: a.reshape((rows,) + a.shape[2:]), passes)
         with jax.named_scope("prefill"):
             with jax.named_scope("embed"):
                 x = self.embed[tokens]
@@ -1003,19 +1302,20 @@ class MoEDecoder(nn.Module):
             last = x[jnp.arange(rows), lengths - 1]
             logits = self._logits(last)
         counts["q_blocks_total"] = jnp.full(
-            (rows,), s.layers * -(-total // s.q_block), jnp.int32)
+            (rows,), (s.layers - s.linear_layers) * -(-total // s.q_block),
+            jnp.int32)
         return logits, {"layers": entries, "counts": counts}
 
     def step(self, token, positions, cache):
         """token int32 (B,) at `positions` (B,) -> (float32 logits (B,
         vocab), the cache with the token written and counted)."""
         s = self.spec
-        step = ATTENTIONS[s.family].step
         real = jnp.ones(token.shape + (1,), bool)
 
         def attend(i, p, kind, x):
-            return step(p["attn"], kind, s, x, positions, cache["layers"][i],
-                        self.faults)
+            return attention_of(s, kind).step(
+                p["attn"], kind, s, x, positions, cache["layers"][i],
+                self.faults)
         with jax.named_scope("decode"):
             with jax.named_scope("embed"):
                 x = self.embed[token]

@@ -19,11 +19,12 @@ Layers (PERF.md section 3): `stem` (PreLayer), `hourglass`, `neck`,
 `normalize`, `loss`, `optimizer`, `peak`, `decode`, `nms`, with `/bwd`
 appended where the scope path runs through `transpose(`: the backward
 pass. The decoder families': `prefill/<scope>` and `decode/<scope>` for the
-scopes `embed`, `attn_full`, `indexer`, `attn_window`, `router`, `experts`,
-`shared_expert`, `dense_ffn`, `lm_head`, and under an attention layer its
-parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`), the latent
-attention's `absorb_q`, `cache_write`, `absorb_o`, and under `router` the
-`group_limit`. An operation the program named but outside those (the step counter's
+scopes `embed`, `attn_full`, `indexer`, `attn_window`, `attn_linear`,
+`router`, `experts`, `shared_expert`, `dense_ffn`, `lm_head`, and under an
+attention layer its parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`),
+the latent attention's `absorb_q`, `cache_write`, `absorb_o`, the linear
+attention's `conv`, `scan`, `state`, `out_norm` (`decode/attn_linear/state`:
+the `kda_step` kernel), and under `router` the `group_limit`. An operation the program named but outside those (the step counter's
 `jit(step)/add`, the network's own input cast) is `other`. An instruction
 XLA made itself carries no metadata (`copy`, `bitcast`, `copy-start/done`
 from layout assignment and memory-space assignment): it takes the layer
@@ -54,13 +55,15 @@ _LAYER_OF_SCOPE = (
 _LOOKED_THROUGH = ("StackedHourglass",)
 # the decoder family (models/decoder.py): its `jax.named_scope`s, the
 # innermost of which is the layer, under the phase that ran it
-_DECODER_SCOPES = ("embed", "attn_full", "indexer", "attn_window", "router",
-                   "experts", "shared_expert", "dense_ffn", "lm_head")
+_DECODER_SCOPES = ("embed", "attn_full", "indexer", "attn_window",
+                   "attn_linear", "router", "experts", "shared_expert",
+                   "dense_ffn", "lm_head")
 _DECODER_PHASES = ("prefill", "decode")
 # parts of an attention layer, named under the layer that holds them
 # (`decode/attn_full/kv_write`), or of the router (`decode/router/group_limit`)
 _DECODER_PARTS = ("rope", "kv_write", "gate", "absorb_q", "cache_write",
-                  "absorb_o", "group_limit")
+                  "absorb_o", "conv", "scan", "state", "out_norm",
+                  "group_limit")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")

@@ -247,11 +247,18 @@ class Generation(NamedTuple):
     row's decode steps read and the real keys among them, by layer kind
     (full, sliding); `group_hits` / `group_slots`: under a router whose
     choice is limited by groups, the (position, expert layer) slots where a
-    group this share holds was among the groups kept / all such slots. A
-    count the program does not have is zeros (the grouped-query family's
-    indexer, a router without groups; a latent family's caches behind an
-    indexer or a window: only its full layers WITHOUT an indexer, which read
-    every slot and may attend every causal key, count slots and keys)."""
+    group this share holds was among the groups kept / all such slots;
+    `linear_state_steps`: the linear-attention layers' state
+    read-modify-writes (decode steps x such layers); `linear_chunks_run` /
+    `linear_chunks_live` / `linear_chunks_total`: chunks of their prefill
+    that ran for the row (a pass of rows runs to its longest row; a chunk
+    past that is a branch not taken) / that the row's own tokens fill / that
+    the padded row holds, such layers summed. A count the
+    program does not have is zeros (the
+    grouped-query family's indexer, a router without groups, a family
+    without linear attention; a latent family's caches behind an indexer or
+    a window: only its full layers WITHOUT an indexer, which read every slot
+    and may attend every causal key, count slots and keys)."""
     tokens: jax.Array          # int32 (B, N)
     logits_first: jax.Array    # float32 (B, V): at the prompt's last token
     logits_last: jax.Array     # float32 (B, V): the step that gave token N
@@ -268,6 +275,10 @@ class Generation(NamedTuple):
     cache_keys_real: jax.Array   # int32 (B, 2)
     group_hits: jax.Array        # int32 (B,)
     group_slots: jax.Array       # int32 (B,)
+    linear_state_steps: jax.Array   # int32 (B,)
+    linear_chunks_run: jax.Array    # int32 (B,)
+    linear_chunks_live: jax.Array   # int32 (B,)
+    linear_chunks_total: jax.Array  # int32 (B,)
 
 
 def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
@@ -319,7 +330,11 @@ def make_generate_fn(model, cfg, new_tokens: int) -> Callable:
             cache_keys_real=jnp.stack([counts["keys_full"],
                                        counts["keys_window"]], axis=1),
             group_hits=counts["group_hits"],
-            group_slots=counts["group_slots"])
+            group_slots=counts["group_slots"],
+            linear_state_steps=counts["linear_state_steps"],
+            linear_chunks_run=counts["linear_chunks_run"],
+            linear_chunks_live=counts["linear_chunks_live"],
+            linear_chunks_total=counts["linear_chunks_total"])
 
     return jax.jit(generate)
 
@@ -347,7 +362,12 @@ def generation_counters(p_max: int) -> Callable:
                                         * rows.tokens.shape[1]),
                "gen.expert_visits": int(np.sum(rows.expert_visits)),
                "gen.group_hits": int(np.sum(rows.group_hits)),
-               "gen.group_slots": int(np.sum(rows.group_slots))}
+               "gen.group_slots": int(np.sum(rows.group_slots)),
+               "gen.linear_state_steps": int(np.sum(rows.linear_state_steps)),
+               "gen.linear_chunks_run": int(np.sum(rows.linear_chunks_run)),
+               "gen.linear_chunks_live": int(np.sum(rows.linear_chunks_live)),
+               "gen.linear_chunks_total": int(np.sum(
+                   rows.linear_chunks_total))}
         for j, kind in enumerate(("full", "window")):
             out["gen.cache_slots." + kind] = int(np.sum(
                 rows.cache_slots_read[:, j], dtype=np.int64))
