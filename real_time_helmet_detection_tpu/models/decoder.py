@@ -24,9 +24,10 @@ in which each layer's KIND selects its attention within the family
     as one W_q (no query latent) and `gated_attention_proj_granularity_type`
     as the head-wise gate; the linear kind is the gated delta rule with a
     decay a channel on a float32 state a head (`ops/linear_attention.py`,
-    the `kda_step` kernel in decode). Router: selection bias and group limit.
-    A setting whose other value this program does not compute (a SwiGLU
-    clamp in a held layer, a low-rank W_f, ...) is refused by its key.
+    the `kda_prefill` kernel in prefill, `kda_step` in decode). Router:
+    selection bias and group limit. A setting whose other value this
+    program does not compute (a SwiGLU clamp in a held layer, a low-rank
+    W_f, ...) is refused by its key.
 
 An attention is three things: the flax module that declares its parameters,
 `prefill_row(p, kind, spec, x, length, slots, faults) -> (out, cache entry,
@@ -896,8 +897,8 @@ def linear_prefill_rows(p, kind: str, spec: DecoderSpec, x, lengths,
             g = jnp.where(real[..., None, None], g, 0.0)
             beta = jnp.where(real[..., None], beta, 0.0)
     with jax.named_scope("scan"):
-        o, state, ran = la.chunked_prefill(q, k, v, g, beta, lengths,
-                                           spec.linear_chunk)
+        o, state, ran = la.prefill_pass(q, k, v, g, beta, lengths,
+                                        spec.linear_chunk)
     with jax.named_scope("out_norm"):
         out = _linear_output(p, spec, xn, o, faults)
     return out, {"state": _state_kept(state, faults), "conv": tail}, {

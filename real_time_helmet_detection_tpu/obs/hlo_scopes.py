@@ -23,9 +23,11 @@ scopes `embed`, `attn_full`, `indexer`, `attn_window`, `attn_linear`,
 `router`, `experts`, `shared_expert`, `dense_ffn`, `lm_head`, and under an
 attention layer its parts `rope`, `kv_write`, `gate` (`decode/attn_full/kv_write`),
 the latent attention's `absorb_q`, `cache_write`, `absorb_o`, the linear
-attention's `conv`, `scan`, `state`, `out_norm` (`decode/attn_linear/state`:
-the `kda_step` kernel), and under `router` the `group_limit`. An operation the program named but outside those (the step counter's
-`jit(step)/add`, the network's own input cast) is `other`. An instruction
+attention's `conv`, `scan`, `state`, `out_norm` (`prefill/attn_linear/scan`:
+the `kda_prefill` kernel on the chip; `decode/attn_linear/state`: the
+`kda_step` kernel), and under `router` the `group_limit`. An operation the
+program named but outside those (the step counter's `jit(step)/add`, the
+network's own input cast) is `other`. An instruction
 XLA made itself carries no metadata (`copy`, `bitcast`, `copy-start/done`
 from layout assignment and memory-space assignment): it takes the layer
 of the instructions it feeds when they agree, else of the instructions it

@@ -12,10 +12,12 @@ with g_t <= 0 the log decay of each of the d_k channels and beta_t in (0, 1)
 the write's strength (benchmark/reference/hybrid_moe_decoder.py states the
 whole layer and runs this recurrence token by token).
 
-Prefill (`chunked_prefill`) takes ROWS = 16 rows together and walks them
-CHUNK = 32 positions at a time. Within a chunk the writes w_r = beta_r (v_r -
-S~_r^T k_r) of all its positions solve one unit lower triangular system (the
-WY / UT form):
+Prefill (`prefill_pass`) takes ROWS = 16 rows together and walks them
+CHUNK = 32 positions at a time: `ops/pallas/kda.py`'s `kda_prefill` where
+Mosaic compiles, its XLA twin `chunked_prefill` elsewhere (`select.on_chip`),
+the same arithmetic and the same contract. Within a chunk the writes w_r =
+beta_r (v_r - S~_r^T k_r) of all its positions solve one unit lower
+triangular system (the WY / UT form):
 
     (I + Diag(beta) A) W = Diag(beta) (V - K_G S_0),
     A[r, s] = sum_c k_r[c] k_s[c] exp(G_r[c] - G_s[c])   (s < r),
@@ -25,19 +27,25 @@ from; the outputs are O = Q_G S_0 + B W (B as A, with q_r, s <= r), and the
 state is handed to the next chunk once. Every decay is applied as a
 difference of cumulative log decays with the later position first
 (G_r - G_s, G_r - "the chunk's start", G_last - G_s): never exp(+G), which
-at g down to -5 a token overflows float32 within a chunk of 18. The pairwise
-differences make A and B elementwise work of C x C x d_k a chunk and head;
-32 keeps that a small share of a prefill and the chunks a pass walks in turn
-at 16 for 512 slots. A chunk that starts at or past the longest row's
-length is a branch not taken on the device (`lax.cond`, as
-`ops/attention.py`'s q blocks); a chunk some row of the pass still holds
-runs for every row of it, and a position past a row's length writes nothing
-(beta = g = 0 there: the caller's business), so the state a row hands to
-decode is its real tokens' alone. Rows a pass: one chunk of one row is
-small work (32 heads of 32 x 32), so a row at a time the chunks are as many
-dependent steps as the batch's rows hold chunks; 16 rows a pass cut those
-16-fold, and a pass's chunks follow its longest row, so a batch's prefill
-no longer follows the sum of its rows' lengths.
+at g down to -5 a token overflows float32 within a chunk of 18. The twin
+forms A and B from the pairwise differences, elementwise work of C x C x d_k
+a chunk and head; the kernel cuts the chunk at sub-chunks of 8 and, for r
+in sub-chunk i and s before it, with p the last position before sub-chunk
+i, factors exp(G_r - G_s) = exp(G_r - G_p) exp(G_p - G_s) (both exponents
+<= 0), so that those blocks are products on the MXU and only the 8 x 8
+diagonal blocks keep pairwise decays (a quarter of the twin's elementwise
+work); its state stays in VMEM from a row's first chunk to its last. A
+chunk that starts at or past the longest row's length is not run (a branch
+not taken on the device in the twin, `lax.cond`, as `ops/attention.py`'s q
+blocks; a grid step that fetches nothing and writes zeros in the kernel); a
+chunk some row of the pass still holds runs for every row of it, and a
+position past a row's length writes nothing (beta = g = 0 there: the
+caller's business), so the state a row hands to decode is its real tokens'
+alone. Rows a pass: one chunk of one row is small work (32 heads of 32 x
+32), so a row at a time the chunks are as many dependent steps as the
+batch's rows hold chunks; 16 rows a pass cut those 16-fold, and a pass's
+chunks follow its longest row, so a batch's prefill no longer follows the
+sum of its rows' lengths.
 
 Decode (`state_step`) is one token a row: `ops/pallas/kda.py`'s `kda_step`
 where Mosaic compiles, its XLA twin elsewhere (`select.on_chip`).
@@ -158,6 +166,22 @@ def chunked_prefill(q, k, v, g, beta, lengths, chunk: int = CHUNK):
     o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 3, 2, 4).reshape(
         rows, n * chunk, heads, dv)[:, :total]
     return o, state, jnp.full((rows,), -(-longest // chunk), jnp.int32)
+
+
+def prefill_pass(q, k, v, g, beta, lengths, chunk: int = CHUNK,
+                 interpret: bool = False):
+    """`chunked_prefill`'s contract: the kernel where Mosaic compiles (or
+    under the interpreter: tests), the XLA twin elsewhere."""
+    if not (interpret or select.on_chip()):
+        return chunked_prefill(q, k, v, g, beta, lengths, chunk)
+    rows, total = q.shape[:2]
+    pad = -total % chunk
+    live = -(-jnp.max(lengths) // chunk)
+    o, state = kda.kda_prefill(
+        *(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+          for x in (q, k, v, g, beta)),
+        live, chunk=chunk, interpret=interpret or None)
+    return o[:, :total], state, jnp.full((rows,), live, jnp.int32)
 
 
 # ---- decode: one token a row -------------------------------------------------------

@@ -2,14 +2,16 @@
 Ling-3.0-flash keys: KDA linear attention on most layers, latent attention
 whose query has no latent on every third here, group-limited routing with a
 selection bias) at toy sizes on the CPU, held to the plain reference
-(benchmark/reference/hybrid_moe_decoder.py); the chunked delta rule against
-its recurrence; `kda_step` under the Pallas interpreter against its XLA twin
-and the reference's one step. The toy sizes are the benchmark
-configuration's own `toy` block: 4 layers (KDA dense, KDA sparse, MLA
-sparse, KDA sparse), 4 heads of 16, 16 experts in 4 groups of which 2 are
-kept, 4 shares, chunks of 8. (The reference repository has no language
-model: no analogue.)"""
+(benchmark/reference/hybrid_moe_decoder.py); the chunked delta rule, the
+XLA twin and `kda_prefill` under the Pallas interpreter, against its
+recurrence, and the kernel's path traced as on the chip; `kda_step` under
+the Pallas interpreter against its XLA twin and the reference's one step.
+The toy sizes are the benchmark configuration's own `toy` block: 4
+layers (KDA dense, KDA sparse, MLA sparse, KDA sparse), 4 heads of 16, 16
+experts in 4 groups of which 2 are kept, 4 shares, chunks of 8. (The
+reference repository has no language model: no analogue.)"""
 
+import functools
 import json
 import os
 import sys
@@ -195,16 +197,20 @@ def test_prefill_and_every_decode_step_match_the_full_forward_in_float32(
     assert s.cache_slots_read.tolist() == [(NEW - 1) * (P_MAX + NEW - 1), 0]
 
 
-@pytest.mark.parametrize("length", [1, 5, 8, 13, 16, 23])
-def test_the_chunked_prefill_is_the_recurrence(length):
-    """Chunks of 8 over 24 slots, a pass of three rows: the longest of
-    `length` (one token, ending mid-chunk, at a chunk's end), the others
-    shorter, so that they walk chunks past their own length; every decay
-    down to -5 a token (exp(+G) would overflow in a chunk of 18); padding
-    writes nothing; the chunks past the longest row are not run."""
-    rng = np.random.default_rng(length)
-    rows, total, heads, d = 3, 24, 3, 16
-    lengths = np.array([length, max(length - 7, 1), max(length // 2, 1)])
+# the chunked delta rule's two forms: the XLA twin and the kernel under the
+# Pallas interpreter (`prefill_pass` picks the kernel on the chip)
+PREFILLS = {"xla": la.chunked_prefill,
+            "kda_prefill": functools.partial(la.prefill_pass, interpret=True)}
+
+
+def _held_to_the_recurrence(prefill, lengths, total, heads, d, chunk, seed):
+    """A pass of rows of `lengths` through `prefill`, every decay down to -5
+    a token, padding writing nothing; its outputs and states against the
+    token-by-token recurrence in float64, the chunks past the longest row
+    not run."""
+    rng = np.random.default_rng(seed)
+    rows = len(lengths)
+    lengths = np.asarray(lengths)
     q = la.l2_normalize(jnp.asarray(rng.standard_normal(
         (rows, total, heads, d)), jnp.float32)) * d ** -0.5
     k = la.l2_normalize(jnp.asarray(rng.standard_normal(
@@ -215,9 +221,8 @@ def test_the_chunked_prefill_is_the_recurrence(length):
     beta = jnp.asarray(rng.uniform(0, 1, (rows, total, heads)), jnp.float32)
     real = np.arange(total)[None, :] < lengths[:, None]
     g, beta = g * real[..., None, None], beta * real[..., None]
-    o, state, ran = la.chunked_prefill(q, k, v, g, beta,
-                                       jnp.asarray(lengths, jnp.int32),
-                                       chunk=8)
+    o, state, ran = prefill(q, k, v, g, beta, jnp.asarray(lengths, jnp.int32),
+                            chunk=chunk)
     for row, n in enumerate(lengths):
         s = np.zeros((heads, d, d))
         for t in range(n):
@@ -229,9 +234,67 @@ def test_the_chunked_prefill_is_the_recurrence(length):
             assert np.allclose(o[row, t], np.einsum(
                 "hkv,hk->hv", s, np.asarray(q[row, t])), atol=2e-5), (row, t)
         assert np.allclose(state[row], s, atol=2e-5), row
+    live = -(-max(lengths) // chunk)
     assert np.isfinite(np.asarray(o)).all()
-    assert not np.asarray(o)[:, -(-length // 8) * 8:].any()
-    assert ran.tolist() == [-(-length // 8)] * rows
+    assert not np.asarray(o)[:, live * chunk:].any()
+    assert ran.tolist() == [live] * rows
+
+
+@pytest.mark.parametrize("prefill", PREFILLS.values(), ids=PREFILLS.keys())
+@pytest.mark.parametrize("length", [1, 5, 8, 13, 16, 23])
+def test_the_chunked_prefill_is_the_recurrence(length, prefill):
+    """Chunks of 8 over 24 slots, a pass of three rows: the longest of
+    `length` (one token, ending mid-chunk, at a chunk's end), the others
+    shorter, so that they walk chunks past their own length; every decay
+    down to -5 a token (exp(+G) would overflow in a chunk of 18); padding
+    writes nothing; the chunks past the longest row are not run. Both
+    forms: the XLA twin and the kernel."""
+    _held_to_the_recurrence(
+        prefill, [length, max(length - 7, 1), max(length // 2, 1)],
+        total=24, heads=3, d=16, chunk=8, seed=length)
+
+
+@pytest.mark.parametrize("prefill", PREFILLS.values(), ids=PREFILLS.keys())
+def test_the_chunked_prefill_at_the_published_geometry_is_the_recurrence(
+        prefill):
+    """Chunks of 32 (four sub-chunks of 8: the kernel's products below the
+    diagonal blocks and its pairwise diagonal blocks both run), heads of
+    128, two rows and two heads over 128 slots: the longest row ends mid-
+    chunk in the third chunk, so the fourth is not run and stays zeros; the
+    other row ends in the second. Decays down to -5 a token: G reaches -160
+    within a chunk, where exp(+G) would overflow float32."""
+    _held_to_the_recurrence(prefill, [70, 45], total=128, heads=2, d=128,
+                            chunk=32, seed=42)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kda_prefill", "xla"])
+def test_the_linear_prefill_is_one_kernel_call_and_no_pairwise_tensor(
+        fields, monkeypatch, kernel):
+    """What keeps a later edit from bringing the elementwise pairwise decays
+    back unseen on the CPU: a linear layer's prefill of a pass, traced at
+    the toy spec with the kernel's path taken (as on the chip), holds one
+    `kda_prefill` call and no intermediate of shape (..., C, C, d_k). The
+    XLA twin is the check's own control: it must be caught."""
+    from real_time_helmet_detection_tpu.analysis.trace_audit import (
+        _walk_jaxprs)
+    from real_time_helmet_detection_tpu.ops.pallas import select
+    monkeypatch.setattr(select, "on_chip", lambda: kernel)
+    model, shapes = _shapes(fields)
+    spec, layer = model.spec, shapes["params"]["layer_0"]
+    p = dict(layer["attn"], attn_norm=layer["attn_norm"])
+    rows, total = 4, 24
+    closed = jax.make_jaxpr(lambda p, x, n: dec.linear_prefill_rows(
+        p, dec.LINEAR, spec, x, n, total))(
+        p, jax.ShapeDtypeStruct((rows, total, spec.hidden), jnp.bfloat16),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    eqns = [e for j in _walk_jaxprs(closed.jaxpr) for e in j.eqns]
+    calls = [e.params["name"] for e in eqns
+             if e.primitive.name == "pallas_call"]
+    size, dk = spec.linear_chunk, spec.linear.head_dim
+    pairwise = [v.aval.shape for e in eqns for v in e.outvars
+                if v.aval.shape[-3:] == (size, size, dk)]
+    assert calls == (["kda_prefill"] if kernel else []), calls
+    assert bool(pairwise) != kernel, pairwise
 
 
 def test_kda_step_is_its_xla_twin_and_the_references_step():
